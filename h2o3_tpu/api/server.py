@@ -458,6 +458,9 @@ class Endpoints:
             "version": info.get("version", "0.1.0"),
             "cloud_name": info.get("cloud_name", "h2o3_tpu"),
             "cloud_size": info.get("cloud_size", 1),
+            # the backend the devices belong to, as jax reports it — a cloud
+            # that silently came up on the CPU must be visible from outside
+            "platform": info.get("platform"),
             "cloud_healthy": bool(info.get("cloud_healthy", True)),
             # fail-stop latch reason (cluster_info sets it after a dead-member
             # collective failure) — the diagnostic operators need

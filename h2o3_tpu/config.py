@@ -42,26 +42,25 @@ _KNOBS: dict[str, tuple[str, str]] = {
                 "passes), the cross-device reduce-scatter ships whole column "
                 "tiles, and a Pallas split-scan kernel consumes the tiles "
                 "block-by-block in VMEM so only per-(node,col) winner "
-                "candidates reach HBM. 'auto' = on for non-CPU backends; "
-                "'1' forces it on any backend (CPU runs the kernels in the "
-                "Pallas interpreter — the CI/parity lane); '0' = the "
-                "unfused path (dense histogram + XLA split scan). Monotone "
-                "builds and categorical columns on sharded meshes fuse too "
-                "(ISSUE 15), and uplift trees run their 4-lane scan through "
-                "the whole-tree fused program (ISSUE 16) — "
-                "tree_fused_fallbacks_total only tallies on the legacy "
-                "per-level uplift loop (H2O3_TPU_WHOLE_TREE=0); see the "
-                "docs/MIGRATION.md fallback matrix"),
+                "candidates reach HBM. The split kernel is INTERPRET-ONLY: "
+                "Mosaic refuses it (cumsum, N-D gather — "
+                "tests/test_tpu_lowering.py), so 'auto' = OFF on every "
+                "backend and the default lane is the dense histogram "
+                "(Pallas on TPU, scatter on CPU) + the XLA split scan, "
+                "column-sharded on >1 device. '1' forces the fused pipeline "
+                "where the kernels can run — the Pallas interpreter on CPU, "
+                "the CI/parity lane; on a TPU it fails at trace time. '0' = "
+                "off. See the docs/MIGRATION.md fallback matrix"),
     "H2O3_TPU_PALLAS_TILES": (
         "", "Pallas histogram/split kernel tile sizes as 'ROW,COL,NODE' "
             "(e.g. '512,8,64' — the built-in defaults). Tiles are a static "
             "compile key: every setting gets its own executable, so the "
-            "tile sweep (tools/bench_kernel_sweep.py, run_tpu_backlog.sh) "
-            "varies them via the environment with no monkeypatching. "
+            "tile sweep (tools/bench_kernel_sweep.py) varies them via the "
+            "environment with no monkeypatching. "
             "'auto' = the tile AUTOTUNER: a first-build micro-sweep over a "
-            "small tile grid, cached per (shape-bucket, mesh) in the "
-            "persistent compile-cache dir — same-bucket rebuilds (and "
-            "later processes) perform zero new sweeps "
+            "small tile grid, cached per (shape-bucket, mesh) beside the "
+            "compile cache (config.compile_cache_dir) — same-bucket "
+            "rebuilds (and later processes) perform zero new sweeps "
             "(pallas_tile_sweeps_total); explicit values bypass the sweep "
             "unchanged. '' = built-in defaults"),
     "H2O3_TPU_SPLIT_SHARD": (
@@ -291,7 +290,6 @@ _KNOBS: dict[str, tuple[str, str]] = {
              "near-identical shapes reuse one compiled program instead of "
              "recompiling per shape. Padding is masked out and proven inert "
              "(bucketed builds score identically); 0 = exact shapes"),
-    "H2O3_TPU_COMPILE_CACHE": ("", "XLA compile-cache dir ('' = <pkg>/.jax_cache)"),
     "H2O3_TPU_NPS_DIR": (
         "", "NodePersistentStorage root (saved Flow notebooks; '' = "
         "~/.h2o3tpu/nps)"),
@@ -606,6 +604,18 @@ _KNOBS: dict[str, tuple[str, str]] = {
               "via predictions_frame are never auto-evicted. 0 = keep all "
               "(the pre-retention behavior)"),
 }
+
+
+def compile_cache_dir() -> str:
+    """THE persistent compile-cache directory, and the home of everything
+    kept beside the executables (the Pallas tile store): wherever
+    ``JAX_COMPILATION_CACHE_DIR`` places it — jax reads that variable
+    itself, so the program then sets no directory in code — else
+    ``<checkout>/.jax_cache``. The path is part of the cache key: it must
+    not move between processes."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
 
 
 def get(name: str) -> str:

@@ -5,7 +5,8 @@ component the rebuild must replace with a TPU kernel).
 Why the plain-XLA matmul path (``histogram._hist_matmul_local``) is slow: it
 materializes a (row_chunk, C·B) one-hot indicator — ~235 MB at C=28, B=256 —
 which cannot live in VMEM, so every chunk round-trips the indicator through
-HBM and the pass is bandwidth-crippled (~1-3% MFU measured, BENCH_r02).
+HBM and the pass is bandwidth-crippled (~1-3% MFU measured on a v5e,
+round 2).
 
 This kernel never materializes that transient:
 
@@ -64,8 +65,8 @@ def _cdiv(a: int, b: int) -> int:
 def _tiles() -> tuple[int, int, int]:
     """(ROW_TILE, COL_TILE, NODE_TILE), overridable via the
     ``H2O3_TPU_PALLAS_TILES`` knob ("row,col,node" — the tile-sweep hook:
-    ``tools/bench_kernel_sweep.py`` and ``run_tpu_backlog.sh`` vary tiles
-    through the environment instead of monkeypatching module globals).
+    ``tools/bench_kernel_sweep.py`` varies tiles through the environment
+    instead of monkeypatching module globals).
     Callers pass the resolved tuple into :func:`hist_pallas_local` /
     :func:`plan_layout` as a static argument, so every tile choice gets its
     own jit cache entry — no stale-executable footgun.
@@ -91,10 +92,10 @@ def _tiles() -> tuple[int, int, int]:
 # ---------------------------------------------------------------------------
 # tile autotuner (H2O3_TPU_PALLAS_TILES=auto, ISSUE 15 / ROADMAP 4b): a
 # first-build micro-sweep over a small tile grid, cached per
-# (shape-bucket, mesh) in the persistent compile-cache dir so the queued
-# TPU window tunes itself and same-bucket rebuilds (and later processes)
-# perform ZERO new sweeps. Explicit "ROW,COL,NODE" values bypass the sweep
-# unchanged; '' keeps the built-in defaults.
+# (shape-bucket, mesh) beside the persistent compile cache so same-bucket
+# rebuilds (and later processes) perform ZERO new sweeps. Explicit
+# "ROW,COL,NODE" values bypass the sweep unchanged; '' keeps the built-in
+# defaults.
 
 from h2o3_tpu.utils import metrics as _mx
 
@@ -107,21 +108,13 @@ _SWEEP_ROWS = 4096  # rows of synthetic data per sweep candidate
 
 
 def _tile_cache_path() -> str:
-    """The persistent winner store, colocated with the XLA compile cache
-    (H2O3_TPU_COMPILE_CACHE, same default as cluster/cloud.py) so one warm
-    volume carries both the executables and the tile choices."""
+    """The persistent winner store, beside the XLA compile cache so one
+    warm volume carries both the executables and the tile choices."""
     import os
 
     from h2o3_tpu import config
 
-    d = config.get("H2O3_TPU_COMPILE_CACHE")
-    if not d:
-        import h2o3_tpu
-
-        d = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(
-                h2o3_tpu.__file__))), ".jax_cache")
-    return os.path.join(d, "pallas_tiles.json")
+    return os.path.join(config.compile_cache_dir(), "pallas_tiles.json")
 
 
 def _tile_bucket(c: int, n_nodes: int, n_bins: int, ns: int) -> tuple:
@@ -148,10 +141,19 @@ def _sweep_grid(c: int, n_nodes: int) -> list:
 def _run_tile_sweep(c, n_nodes, n_bins, ns, interpret: bool) -> tuple:
     """Time each candidate on synthetic data of the real geometry; return
     the fastest triple. Runs eagerly (concrete arrays) — safe to call from
-    inside an outer trace, where it executes at trace time exactly once."""
+    inside an outer trace, where it executes at trace time exactly once.
+
+    A candidate whose tiles do not fit VMEM (the compiler's
+    RESOURCE_EXHAUSTED — on a v5e 4 of the 12 candidates at 256 bins, 64
+    nodes) is a measured outcome of the sweep: it is logged with the
+    compiler's words and cannot win. Any other refusal raises — it is a
+    defect in the kernel, not a property of the candidate."""
     import time
 
     import numpy as np
+
+    from h2o3_tpu.utils.log import Log
+    from h2o3_tpu.utils.overload import is_oom
 
     rng = np.random.default_rng(0)
     n = _SWEEP_ROWS
@@ -160,24 +162,32 @@ def _run_tile_sweep(c, n_nodes, n_bins, ns, interpret: bool) -> tuple:
     stats = jnp.asarray(rng.normal(size=(n, ns)).astype(np.float32))
     best, best_t = None, None
     for tiles in _sweep_grid(c, n_nodes):
+        fn = lambda: hist_pallas_local(
+            bins, nid, stats, n_nodes, n_bins, interpret=interpret,
+            blocked=True, tiles=tiles,
+        )
         try:
-            fn = lambda: hist_pallas_local(
-                bins, nid, stats, n_nodes, n_bins, interpret=interpret,
-                blocked=True, tiles=tiles,
-            )
             jax.block_until_ready(fn())  # compile
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            dt = time.perf_counter() - t0
-        except Exception:  # a candidate the backend rejects: skip it
+        except jax.errors.JaxRuntimeError as e:
+            if not is_oom(e):
+                raise
+            Log.warn(f"Pallas tile autotuner: tiles {tiles} do not fit: "
+                     f"{str(e).splitlines()[0][:300]}")
             continue
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn())
+        dt = time.perf_counter() - t0
         if best_t is None or dt < best_t:
             best, best_t = tiles, dt
+    if best is None:
+        raise RuntimeError(
+            f"Pallas tile autotuner: no candidate of {_sweep_grid(c, n_nodes)}"
+            f" fits VMEM at c={c} n_nodes={n_nodes} n_bins={n_bins} ns={ns}")
     # the candidate executables are one-shot — drop them (the winner
     # recompiles once inside the real program; keeping 11 losers loaded
     # per bucket would only grow the process's executable footprint)
     hist_pallas_local.clear_cache()
-    return best or (ROW_TILE, COL_TILE, NODE_TILE)
+    return best
 
 
 def tiles_for(c: int, n_nodes: int, n_bins: int, ns: int) -> tuple:

@@ -1,6 +1,13 @@
 """Pallas split-scan kernel — the second half of the fused histogram→split
 tree pipeline (``H2O3_TPU_SPLIT_FUSE``).
 
+STATUS: interpret-only. Mosaic refuses this kernel body (jax 0.9.0 / libtpu
+0.0.34, TPU v5e: ``Unimplemented primitive in Pallas TPU lowering for
+KernelType.TC: cumsum``, then ``Only 2D gather is supported``), so no
+default selects it (``shared_tree._split_fuse_on``) and it runs only under
+``H2O3_TPU_SPLIT_FUSE=1`` in the Pallas interpreter — the CPU parity lane.
+Compiling it needs 2-D lane/sublane operations throughout (ROADMAP A3b).
+
 The unfused pipeline materializes the full (C, N·B, S) histogram in HBM
 (via two unscramble transpose passes over the Pallas kernel's scrambled
 output), then the XLA split scan streams the whole tensor back. The r5

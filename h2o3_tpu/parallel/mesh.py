@@ -43,24 +43,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 ROWS_AXIS = "rows"
 COLS_AXIS = "cols"
 
-# jax moved shard_map to the top level (and renamed check_rep -> check_vma)
-# after 0.4.x; every shard_map in this codebase goes through this one shim so
-# the whole stack runs on either API generation.
-if hasattr(jax, "shard_map"):
 
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-else:  # jax 0.4.x: experimental module, kwarg named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_exp(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
+def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
+    """``jax.shard_map`` with the mesh positional — the call shape every
+    shard_map site in this codebase uses."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
 
 
 _mesh: Mesh | None = None

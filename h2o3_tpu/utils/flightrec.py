@@ -52,9 +52,10 @@ _DISPATCH_SECONDS = _mx.histogram(
     "wall seconds inside hot device-dispatch sites, by site (tree = fused "
     "tree/level programs, irls_chunk = fused GLM chunk, dl_chunk = DL "
     "epoch-chunk program, serving_batch = batched scorer dispatch, "
-    "stream_block = out-of-core per-block compute). Host wall of the "
-    "dispatch call: on the synchronous proxy/tunnel paths this IS device "
-    "time; async residue attributes to the site that syncs")
+    "stream_block = out-of-core per-block compute). Host wall INSIDE the "
+    "dispatch call: device time only where the site blocks on its result "
+    "— on an asynchronous backend a site that does not block records "
+    "enqueue time, and the residue attributes to the site that syncs")
 _INCIDENTS = _mx.counter(
     "incident_bundles_total",
     "incident bundles written (ring dump + metrics + devmem + log tail), "

@@ -4,16 +4,16 @@ and the ``/3/Profiler`` stack sampler [UNVERIFIED upstream paths, SURVEY.md
 
 On TPU, XLA compile time IS the dominant hidden cost (AutoML builds many
 small programs), so the timeline's first-class events are compilations:
-``install()`` hooks jax's compile logging into a ring buffer. ``profiler``
-wraps ``jax.profiler.trace`` (xplane dumps viewable in TensorBoard/XProf) —
-the JProfile/stack-sampling analog for a compiled runtime.
+``install()`` hooks jax's compile monitoring events into a ring buffer.
+``profiler`` wraps ``jax.profiler.trace`` (xplane dumps viewable in
+TensorBoard/XProf) — the JProfile/stack-sampling analog for a compiled
+runtime.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
-import logging
 import threading
 import time
 
@@ -32,31 +32,28 @@ def events(n: int = 200) -> list[dict]:
         return list(_EVENTS)[-n:]
 
 
-class _CompileHandler(logging.Handler):
-    def emit(self, rec: logging.LogRecord) -> None:
-        m = rec.getMessage()
-        if "compil" in m.lower():
-            record("compile", m)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        record("compile", f"XLA compilation of {kw.get('fun_name', '?')} "
+                          f"in {duration:.3f} s")
 
 
 def install() -> None:
-    """Capture XLA compile events into the timeline (idempotent)."""
+    """Capture XLA compile events into the timeline (idempotent) — one per
+    program compiled OR loaded from the persistent cache (jax times both
+    under the one event; a load is just much quicker), through jax's
+    monitoring hooks. No logger is touched: scraping jax's compile log
+    meant enabling it, one stderr line of argument shapes per traced
+    function."""
     global _INSTALLED
     if _INSTALLED:
         return
     import jax
 
-    try:
-        jax.config.update("jax_log_compiles", True)
-    except Exception:
-        return
-    h = _CompileHandler()
-    h.setLevel(logging.DEBUG)
-    for name in ("jax._src.dispatch", "jax._src.interpreters.pxla"):
-        lg = logging.getLogger(name)
-        lg.addHandler(h)
-        if lg.level > logging.DEBUG or lg.level == logging.NOTSET:
-            lg.setLevel(logging.DEBUG)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
     _INSTALLED = True
     record("telemetry", "compile-event capture installed")
 

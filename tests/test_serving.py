@@ -337,12 +337,11 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 jax.config.update("jax_platforms", "cpu")
-# the jax_compilation_cache_dir hook (cluster/cloud.py wires this for
-# accelerator backends; CPU sets it explicitly here — same machine, so the
-# AOT feature-mismatch hazard that disables it by default does not apply)
-jax.config.update("jax_compilation_cache_dir", sys.argv[1])
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+# the cache is placed from OUTSIDE (JAX_COMPILATION_CACHE_DIR in the env —
+# same machine, so the AOT feature-mismatch hazard that keeps CPU runs off
+# the default <checkout>/.jax_cache does not apply): no directory is set in
+# code here or in init()
+assert jax.config.jax_compilation_cache_dir == sys.argv[1]
 import numpy as np, pandas as pd
 import h2o3_tpu
 h2o3_tpu.init()
@@ -373,14 +372,15 @@ def _cache_files(d):
 
 def test_compile_cache_cross_process(tmp_path):
     """A second process training + scoring the SAME shape bucket compiles
-    zero new programs: the persistent XLA cache (the
-    ``jax_compilation_cache_dir`` hook at cluster/cloud.py) serves every
-    program, proven by the cache dir gaining no new entries while the run
-    still produces identical predictions."""
+    zero new programs: the persistent XLA cache, placed by
+    ``JAX_COMPILATION_CACHE_DIR``, serves every program, proven by the
+    cache dir gaining no new entries while the run still produces identical
+    predictions."""
     cache = str(tmp_path / "xla_cache")
     os.makedirs(cache)
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    env["JAX_COMPILATION_CACHE_DIR"] = cache
 
     def run():
         p = subprocess.run(
@@ -398,6 +398,5 @@ def test_compile_cache_cross_process(tmp_path):
     assert not new, f"second process compiled {len(new)} new programs"
     # identical predictions from the cache-served programs
     assert second["p_q"] == first["p_q"]
-    # the registry surfaces cache effectiveness (jax monitoring bridge);
-    # soft on jax versions without the event, hard on this container's
-    assert second["cache_hits"] >= first["cache_hits"]
+    # the registry surfaces cache effectiveness (jax monitoring bridge)
+    assert second["cache_hits"] > first["cache_hits"]

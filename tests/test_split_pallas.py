@@ -388,7 +388,7 @@ def test_tile_autotuner_sweeps_once_per_bucket(tmp_path, monkeypatch):
     monkeypatch.setattr(
         hp, "_sweep_grid", lambda c, n: [(256, 4, 32), (512, 8, 64)])
     with _env(H2O3_TPU_PALLAS_TILES="auto",
-              H2O3_TPU_COMPILE_CACHE=str(tmp_path)):
+              JAX_COMPILATION_CACHE_DIR=str(tmp_path)):
         s0 = mx.counter_value("pallas_tile_sweeps_total")
         tiles = hp.tiles_for(12, 64, 32, 3)
         assert mx.counter_value("pallas_tile_sweeps_total") == s0 + 1

@@ -1,9 +1,8 @@
 """Exit 0 iff the newest BENCH_builder_*.json captured a real headline value
 AND at least one post-headline phase.
 
-Used by tunnel_watch.sh as the 'did the backlog actually measure anything'
-signal — the backlog script's own exit code cannot carry it (tee pipelines,
-error-JSON-by-design). Requiring a post-headline phase matters: round 4's
+The 'did the bench run actually measure anything' signal for committed
+artifacts. Requiring a post-headline phase matters: round 4's
 failure mode was exactly 'headline measured, every scale phase dead in a
 RESOURCE_EXHAUSTED cascade', and standing down on a headline alone would
 forfeit the later windows this round exists to use.
@@ -719,8 +718,8 @@ def _ledger_sane(led: dict) -> bool:
 
 
 def _trace_ok(here: str, now: float):
-    """Sanity-check the newest recent TRACE_*.json (the run_tpu_backlog
-    traced-headline-GBM capture, ISSUE 18). Returns None when no recent
+    """Sanity-check the newest recent TRACE_*.json (the traced-headline-GBM
+    capture, ISSUE 18). Returns None when no recent
     artifact exists (no opinion), else True/False. Checks the acceptance
     pins: the Perfetto export carries a span for EVERY site the job's
     ledger says it dispatched (a missing site means the trace plane lost a

@@ -18,8 +18,7 @@ Artifact (one JSON line on stdout, also written to --out): per-step
 latency/shed/occupancy numbers plus a summary with each mode's sustained
 QPS (highest offered rate with shed+error rate <= 1% and achieved >= 90% of
 offered), the p99 at that rate, and a batched-vs-control byte-parity probe.
-``tools/latest_bench_ok.py`` sanity-checks the newest artifact; the A/B is
-queued for real-TPU windows in ``tools/run_tpu_backlog.sh``.
+``tools/latest_bench_ok.py`` sanity-checks the newest artifact.
 
 Usage::
 
@@ -89,6 +88,28 @@ def _row_pool(n: int = 512, seed: int = 123) -> list[dict]:
     return pool
 
 
+def _serve_forever() -> None:
+    """Park the server subprocess until the parent's SIGTERM; leave through
+    SystemExit so interpreter shutdown (the jax backend's teardown, which
+    releases the chip) runs — the default SIGTERM action skips it."""
+    import signal
+
+    signal.signal(signal.SIGTERM, lambda *_: sys.exit(0))
+    while True:
+        time.sleep(3600)
+
+
+def _stop_server(proc) -> None:
+    """End a server subprocess so the chip is free for the next one:
+    SIGTERM + wait; SIGKILL only for one that does not leave."""
+    proc.terminate()
+    try:
+        proc.wait(timeout=30)
+    except subprocess.TimeoutExpired:
+        proc.kill()
+        proc.wait(timeout=30)
+
+
 def _serve(args) -> None:
     """Server-subprocess mode: boot a cloud, train the workload model,
     serve REST, print the READY line the parent parses."""
@@ -109,8 +130,7 @@ def _serve(args) -> None:
     serving.score_rows(model, [_row_pool(1)[0]])
     srv = start_server(port=args.port)
     print(f"READY {srv.url} {model.key}", flush=True)
-    while True:
-        time.sleep(3600)
+    _serve_forever()
 
 
 def _serve_fleet(args) -> None:
@@ -157,8 +177,7 @@ def _serve_fleet(args) -> None:
     print(f"READY {srv.url} {','.join(keys)} total_bytes={total} "
           f"budget={os.environ.get('H2O3_TPU_SERVE_HBM_BYTES', '0')}",
           flush=True)
-    while True:
-        time.sleep(3600)
+    _serve_forever()
 
 
 # ---------------------------------------------------------------------------
@@ -351,6 +370,9 @@ def _run_step(url: str, model_key: str, qps: float, duration: float,
 
 
 def _spawn_server(mode: str, window_ms: str | None) -> tuple:
+    # One process for each chip: this parent never imports jax, so the
+    # device is free for the server child, and children run one at a time —
+    # each mode's server is stopped (_stop_server) before the next spawns.
     env = dict(os.environ)
     env.setdefault("H2O3_TPU_LOG_LEVEL", "WARN")
     if mode == "control":
@@ -370,13 +392,15 @@ def _spawn_server(mode: str, window_ms: str | None) -> tuple:
             _, url, model_key = line.split()
             _log(f"{mode} server up at {url} (model {model_key})")
             return p, url, model_key
-    p.kill()
+    _stop_server(p)
     raise RuntimeError(f"{mode} server never became ready")
 
 
 def _spawn_fleet_server(mode: str, args, watch_dir: str) -> tuple:
     """mode 'oversub' bounds HBM to total/oversub; 'resident' leaves the
     budget unbounded (the all-resident control)."""
+    # one process for each chip, as in _spawn_server: the parent stays off
+    # jax and the two fleet servers run in turn, never together
     env = dict(os.environ)
     env.setdefault("H2O3_TPU_LOG_LEVEL", "WARN")
     env["H2O3_TPU_SERVE_WATCH_DIR"] = watch_dir
@@ -400,7 +424,7 @@ def _spawn_fleet_server(mode: str, args, watch_dir: str) -> tuple:
                  f"total_bytes={extra.get('total_bytes')} "
                  f"budget={extra.get('budget')}")
             return p, url, keys, extra
-    p.kill()
+    _stop_server(p)
     raise RuntimeError(f"fleet {mode} server never became ready")
 
 
@@ -475,8 +499,7 @@ def _run_fleet(args, stamp: str) -> int:
                             "stable": before == after}
             registry_stats[mode] = _scrape_registry(url)
         finally:
-            proc.kill()
-            proc.wait(timeout=30)
+            _stop_server(proc)
 
     summary: dict = {}
     for mode in ("oversub", "resident"):
@@ -625,8 +648,7 @@ def main(argv=None) -> int:
                      f"{step['mean_batch_occupancy']}")
         finally:
             if proc is not None:
-                proc.kill()
-                proc.wait(timeout=30)
+                _stop_server(proc)
 
     summary: dict = {}
     for mode in modes:

@@ -30,8 +30,8 @@ Scenarios:
    at its own exit and the supervisor reforms + resumes from the latest
    snapshot to a model within 1e-6 of the uninterrupted reference.
 
-Queued in tools/run_tpu_backlog.sh; runs on the CPU proxy too (CI's
-tests/test_overload.py is the assert-only version of the same drill).
+Runs on the CPU proxy too (CI's tests/test_overload.py is the assert-only
+version of the same drill).
 """
 
 from __future__ import annotations
