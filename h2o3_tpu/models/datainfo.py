@@ -213,6 +213,7 @@ class DataInfo:
             names.append("Intercept")
         return names
 
+    @jax.named_scope("ph_std")  # names the operations where a caller traces it
     def transform(self, frame: Frame):
         """Build the (npad, p) float32 design matrix on device, plus a row
         validity mask folding in padding and (if skip-handling) NA rows."""

@@ -187,14 +187,16 @@ def _irls_weights(fam, X, y, w, offset, beta):
     weights W, working response z, and the deviance — shared op-for-op by
     the per-iteration pass and the fused while_loop body so the two lanes
     compute identical iterations."""
-    eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
-    mu = fam.link.inv(eta)
-    d = fam.link.dinv(eta)
-    d = jnp.where(d == 0, 1e-10, jnp.sign(d) * jnp.maximum(jnp.abs(d), 1e-10))
-    var = fam.variance(mu)
-    z = (eta - offset) + (y - mu) / d
-    W = w * d * d / var
-    dev = fam.deviance(y, mu, w)
+    with jax.named_scope("ph_gram"):  # the Gram pass's row math
+        eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
+        mu = fam.link.inv(eta)
+        d = fam.link.dinv(eta)
+        d = jnp.where(d == 0, 1e-10, jnp.sign(d) * jnp.maximum(jnp.abs(d), 1e-10))
+        var = fam.variance(mu)
+        z = (eta - offset) + (y - mu) / d
+        W = w * d * d / var
+    with jax.named_scope("ph_dev"):
+        dev = fam.deviance(y, mu, w)
     return W, z, dev
 
 
@@ -255,9 +257,10 @@ def _fused_chunk_program(npad, p_pad, family_key, fam_args, l1_on,
             from h2o3_tpu.ops import collectives
 
             W, z, dev = _irls_weights(fam, Xl, yl, wl, ol, beta)
-            Xw = Xl * W[:, None]
-            G_l = jnp.einsum("np,nq->pq", Xw, Xl, precision=_HI)
-            b_l = jnp.einsum("np,n->p", Xw, z, precision=_HI)
+            with jax.named_scope("ph_gram"):
+                Xw = Xl * W[:, None]
+                G_l = jnp.einsum("np,nq->pq", Xw, Xl, precision=_HI)
+                b_l = jnp.einsum("np,n->p", Xw, z, precision=_HI)
             # the bulk G reduce rides the collective lane (quantized with a
             # residual-correction pass when on — the solve consumes G, so
             # it keeps ~14 effective mantissa bits); the small packed
@@ -293,21 +296,23 @@ def _fused_chunk_program(npad, p_pad, family_key, fam_args, l1_on,
             else:
                 W, z, dev = _irls_weights(fam, X, y, w, offset, beta)
                 G, b, _sw = weighted_gram(X, W, z)
-            if l1_on:
-                beta_new, ok = admm_elastic_net_device(
-                    G, b, l1, l2, icpt, pad_diag, real_p,
-                    non_negative=non_negative,
-                )
-            else:
-                # Gp = G + l2*I with the intercept unpenalized (the host
-                # path's Gp[icpt, icpt] -= l2), plus the unit diagonal that
-                # keeps padded bucket columns invertible at exactly zero
-                extra = l2 * jnp.where(ar == icpt, 0.0, 1.0) + pad_diag
-                beta_new, ok = cho_solve_jitter_device(G, b, extra)
-                if non_negative:
-                    beta_new = jnp.where(
-                        (ar != icpt) & (beta_new < 0), 0.0, beta_new
+            with jax.named_scope("ph_solve"):
+                if l1_on:
+                    beta_new, ok = admm_elastic_net_device(
+                        G, b, l1, l2, icpt, pad_diag, real_p,
+                        non_negative=non_negative,
                     )
+                else:
+                    # Gp = G + l2*I with the intercept unpenalized (the host
+                    # path's Gp[icpt, icpt] -= l2), plus the unit diagonal
+                    # that keeps padded bucket columns invertible at exactly
+                    # zero
+                    extra = l2 * jnp.where(ar == icpt, 0.0, 1.0) + pad_diag
+                    beta_new, ok = cho_solve_jitter_device(G, b, extra)
+                    if non_negative:
+                        beta_new = jnp.where(
+                            (ar != icpt) & (beta_new < 0), 0.0, beta_new
+                        )
             bad = ~ok | ~jnp.all(jnp.isfinite(beta_new))
             delta = jnp.max(jnp.abs(beta_new - beta))
             stop = ~bad & (
@@ -486,9 +491,10 @@ def _glm_dev_grad(X, y, w, offset, beta, family_key, fam_args):
 @partial(jax.jit, static_argnames=("family_key", "fam_args"))
 def _deviance_pass(X, y, w, offset, beta, family_key, fam_args):
     fam = get_family(family_key, *fam_args)
-    eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
-    mu = fam.link.inv(eta)
-    return fam.deviance(y, mu, w)
+    with jax.named_scope("ph_dev"):
+        eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
+        mu = fam.link.inv(eta)
+        return fam.deviance(y, mu, w)
 
 
 @partial(jax.jit, static_argnames=("K",))
@@ -507,6 +513,7 @@ def _multinomial_pass(X, Y1h, w, Beta, K, k):
 
 
 @partial(jax.jit, static_argnames=())
+@jax.named_scope("ph_score")
 def _softmax_probs(X, Beta):
     Eta = jnp.einsum("np,pk->nk", X, Beta, precision=_HI)
     return jax.nn.softmax(Eta, axis=1)
@@ -619,11 +626,14 @@ class GLMModel(Model):
             return probs
         beta = jnp.asarray(self.output["beta_std"], jnp.float32)
         offset = _offset_col(self.params, frame)
-        eta = np.asarray(
-            jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
-        )[: frame.nrow]
         fam = self.output["family_obj"]
-        mu = np.asarray(fam.link.inv(jnp.asarray(eta)))
+        # issued op by op, so the scope names these operations only where a
+        # caller traces this method into a program of its own
+        with jax.named_scope("ph_score"):
+            eta = np.asarray(
+                jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
+            )[: frame.nrow]
+            mu = np.asarray(fam.link.inv(jnp.asarray(eta)))
         if self.is_classifier:
             return np.stack([1 - mu, mu], axis=1)
         return mu
@@ -678,52 +688,55 @@ class GLM(ModelBuilder):
             pairs += list(_it.combinations([str(c) for c in p.interactions], 2))
         if p.interaction_pairs:
             pairs += [(str(a), str(b)) for a, b in p.interaction_pairs]
-        di = DataInfo.fit(
-            train,
-            self._x,
-            standardize=p.standardize,
-            use_all_factor_levels=False,
-            missing_handling=p.missing_values_handling,
-            # ordinal: the K-1 ordered cuts ARE the intercepts
-            add_intercept=p.intercept and family != "ordinal",
-            interaction_pairs=pairs or None,
-            hash_buckets=int(p.hash_buckets) if p.hash_buckets else None,
-        )
+        # DataInfo.fit, the design matrix, the response and weight lanes;
+        # ends in the `nobs` pull, which waits for the transform
+        with _mx.span("glm.datainfo"):
+            di = DataInfo.fit(
+                train,
+                self._x,
+                standardize=p.standardize,
+                use_all_factor_levels=False,
+                missing_handling=p.missing_values_handling,
+                # ordinal: the K-1 ordered cuts ARE the intercepts
+                add_intercept=p.intercept and family != "ordinal",
+                interaction_pairs=pairs or None,
+                hash_buckets=int(p.hash_buckets) if p.hash_buckets else None,
+            )
 
-        y_np = yv.to_numpy()
-        if yv.is_categorical():
-            y_np = y_np.astype(np.float32)
-            y_np[y_np < 0] = np.nan
-        ybuf = np.zeros(train.npad, np.float32)
-        ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
-        yna = np.zeros(train.npad, np.float32)
-        yna[: train.nrow] = np.isnan(y_np)
+            y_np = yv.to_numpy()
+            if yv.is_categorical():
+                y_np = y_np.astype(np.float32)
+                y_np[y_np < 0] = np.nan
+            ybuf = np.zeros(train.npad, np.float32)
+            ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
+            yna = np.zeros(train.npad, np.float32)
+            yna[: train.nrow] = np.isnan(y_np)
 
-        # out-of-core streaming (ISSUE 11, frame/chunkstore.py): a design
-        # matrix past the HBM window streams as row-block chunks through
-        # the per-iteration Gram accumulation (the IRLS Gram is a sum over
-        # row blocks). Fallback matrix (docs/MIGRATION.md): multinomial /
-        # ordinal / L-BFGS / compute_p_values stay resident.
-        stream = None
-        if (family not in ("multinomial", "ordinal")
-                and p.solver.upper().replace("-", "_") not in ("L_BFGS", "LBFGS")
-                and not p.compute_p_values):
-            stream = self._plan_streamed(train, di, p, ybuf, yna)
-        if stream is not None:
-            X = stream
-            w = stream.lane("w")
-            y = ybuf
-            offset = stream.lane("offset")
-        else:
-            X, valid_mask = di.transform(train)
-            w = valid_mask
-            if p.weights_column:
-                w = w * jnp.nan_to_num(train.vec(p.weights_column).data)
-            offset = _offset_col(p, train)
-            w = w * (1.0 - jnp.asarray(yna))  # NA-response rows get weight 0
-            y = jnp.asarray(ybuf)
+            # out-of-core streaming (ISSUE 11, frame/chunkstore.py): a design
+            # matrix past the HBM window streams as row-block chunks through
+            # the per-iteration Gram accumulation (the IRLS Gram is a sum over
+            # row blocks). Fallback matrix (docs/MIGRATION.md): multinomial /
+            # ordinal / L-BFGS / compute_p_values stay resident.
+            stream = None
+            if (family not in ("multinomial", "ordinal")
+                    and p.solver.upper().replace("-", "_") not in ("L_BFGS", "LBFGS")
+                    and not p.compute_p_values):
+                stream = self._plan_streamed(train, di, p, ybuf, yna)
+            if stream is not None:
+                X = stream
+                w = stream.lane("w")
+                y = ybuf
+                offset = stream.lane("offset")
+            else:
+                X, valid_mask = di.transform(train)
+                w = valid_mask
+                if p.weights_column:
+                    w = w * jnp.nan_to_num(train.vec(p.weights_column).data)
+                offset = _offset_col(p, train)
+                w = w * (1.0 - jnp.asarray(yna))  # NA-response rows get weight 0
+                y = jnp.asarray(ybuf)
 
-        nobs = float(np.asarray(w.sum()))
+            nobs = float(np.asarray(w.sum()))
         job.update(0.05)
 
         from h2o3_tpu.models.model_base import (
@@ -764,16 +777,20 @@ class GLM(ModelBuilder):
             elif len(st["beta"]) != di.ncols_expanded:
                 raise ValueError("checkpoint design-matrix width differs")
 
-        if family == "multinomial":
-            out = self._fit_multinomial(job, X, y, w, di, yv, p, nobs,
-                                        prior=prior)
-        elif family == "ordinal":
-            out = self._fit_ordinal(job, X, y, w, di, yv, p)
-        elif p.solver.upper().replace("-", "_") in ("L_BFGS", "LBFGS"):
-            out = self._fit_lbfgs(job, X, y, w, offset, di, p, family, nobs)
-        else:
-            out = self._fit_irls(job, X, y, w, offset, di, p, family, nobs,
-                                 prior=prior, response_domain=response_domain)
+        # the solver: its device programs are the `dispatch:irls_chunk`
+        # children, each ending in the pull of its iteration count
+        with _mx.span("glm.fit", family=family):
+            if family == "multinomial":
+                out = self._fit_multinomial(job, X, y, w, di, yv, p, nobs,
+                                            prior=prior)
+            elif family == "ordinal":
+                out = self._fit_ordinal(job, X, y, w, di, yv, p)
+            elif p.solver.upper().replace("-", "_") in ("L_BFGS", "LBFGS"):
+                out = self._fit_lbfgs(job, X, y, w, offset, di, p, family, nobs)
+            else:
+                out = self._fit_irls(job, X, y, w, offset, di, p, family, nobs,
+                                     prior=prior,
+                                     response_domain=response_domain)
 
         out["datainfo"] = di
         out["response_domain"] = tuple(yv.domain) if classification else None
@@ -840,17 +857,20 @@ class GLM(ModelBuilder):
         then the standard metric builder on the host-assembled raw."""
         from h2o3_tpu.models.model_base import _make_metrics
 
-        fam = model.output["family_obj"]
-        beta = jnp.asarray(model.output["beta_std"], jnp.float32)
-        parts = []
-        for bi, blk in store.stream(("X", "offset")):
-            eta = jnp.einsum(
-                "np,p->n", blk["X"], beta, precision=_HI) + blk["offset"]
-            parts.append(np.asarray(fam.link.inv(eta)))
-        mu = np.concatenate(parts)[: frame.nrow]
-        raw = np.stack([1 - mu, mu], axis=1) if model.is_classifier else mu
-        yh, wh = model._response_and_weights(frame)
-        return _make_metrics(model, raw, yh, wh)
+        with _mx.span("model.score_metrics", algo=self.algo):
+            fam = model.output["family_obj"]
+            beta = jnp.asarray(model.output["beta_std"], jnp.float32)
+            parts = []
+            with _mx.span("model.predict_raw"):  # each block ends in a pull
+                for bi, blk in store.stream(("X", "offset")):
+                    eta = jnp.einsum(
+                        "np,p->n", blk["X"], beta, precision=_HI
+                    ) + blk["offset"]
+                    parts.append(np.asarray(fam.link.inv(eta)))
+            mu = np.concatenate(parts)[: frame.nrow]
+            raw = np.stack([1 - mu, mu], axis=1) if model.is_classifier else mu
+            yh, wh = model._response_and_weights(frame)
+            return _make_metrics(model, raw, yh, wh)
 
     # -- single-vector families ---------------------------------------------
     def _irls_snapshot(self, key, p: GLMParams, di, beta, family, fam,
@@ -1166,17 +1186,18 @@ class GLM(ModelBuilder):
         """
         if has_intercept is None:
             has_intercept = p.intercept
-        names = di.coef_names()
-        beta_std = np.asarray(beta_std, np.float64)
-        beta_orig = beta_std.copy()
-        shift = 0.0
-        if p.standardize:
-            for c in di.columns:
-                if c.kind == "num":
-                    beta_orig[c.offset] = beta_std[c.offset] / c.sigma
-                    shift += beta_std[c.offset] * c.mean / c.sigma
-            if has_intercept:
-                beta_orig[-1] = beta_std[-1] - shift
+        with _mx.span("glm.coef_output"):
+            names = di.coef_names()
+            beta_std = np.asarray(beta_std, np.float64)
+            beta_orig = beta_std.copy()
+            shift = 0.0
+            if p.standardize:
+                for c in di.columns:
+                    if c.kind == "num":
+                        beta_orig[c.offset] = beta_std[c.offset] / c.sigma
+                        shift += beta_std[c.offset] * c.mean / c.sigma
+                if has_intercept:
+                    beta_orig[-1] = beta_std[-1] - shift
         return {
             "coef_names": names,
             "beta_std": beta_std,

@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from h2o3_tpu.utils import metrics as _mx
+
 _EPS = 1e-15
 _NBUCKETS = 1024
 
@@ -94,6 +96,7 @@ class ModelMetrics:
 # regression
 
 
+@_mx.span("metrics.regression")
 def regression_metrics(
     actual: np.ndarray,
     pred: np.ndarray,
@@ -153,6 +156,7 @@ def _mean_deviance(a, p, w, distribution: str) -> float:
 # binomial
 
 
+@_mx.span("metrics.binomial")
 def binomial_metrics(
     actual: np.ndarray,
     prob: np.ndarray,
@@ -350,6 +354,7 @@ def _confusion(y, p, w, thr) -> list[list[float]]:
 # multinomial
 
 
+@_mx.span("metrics.multinomial")
 def multinomial_metrics(
     actual: np.ndarray,
     probs: np.ndarray,
@@ -447,6 +452,7 @@ def _binom_device_stats():
     import jax.numpy as jnp
 
     @jax.jit
+    @jax.named_scope("ph_metric")
     def stats(y, p, w):
         ok = (~jnp.isnan(y)) & (~jnp.isnan(p)) & (w > 0)
         wok = jnp.where(ok, w, 0.0).astype(jnp.float32)
@@ -588,6 +594,7 @@ def _regression_metrics_device(actual, pred, weights, distribution) -> ModelMetr
     if _REG_STATS is None:
 
         @jax.jit
+        @jax.named_scope("ph_metric")
         def stats(a, p, w):
             ok = (~jnp.isnan(a)) & (~jnp.isnan(p)) & (w > 0)
             wok = jnp.where(ok, w, 0.0).astype(jnp.float32)
@@ -666,6 +673,7 @@ def _multinomial_metrics_device(actual, probs, weights, domain) -> ModelMetrics:
     if K not in _MULTI_STATS:
 
         @jax.jit
+        @jax.named_scope("ph_metric")
         def stats(y, P, w):
             ok = (y >= 0) & (w > 0) & (~jnp.isnan(P).any(axis=1))
             wok = jnp.where(ok, w, 0.0).astype(jnp.float32)

@@ -198,10 +198,12 @@ class Model:
         return y, w
 
     def _score_metrics(self, frame: Frame) -> MM.ModelMetrics:
-        frame = self._apply_preprocessors(frame)
-        raw = np.asarray(self._predict_raw(frame))
-        y, w = self._response_and_weights(frame)
-        return _make_metrics(self, raw, y, w)
+        with _mx.span("model.score_metrics", algo=self.algo):
+            frame = self._apply_preprocessors(frame)
+            with _mx.span("model.predict_raw"):  # ends in the pull to numpy
+                raw = np.asarray(self._predict_raw(frame))
+            y, w = self._response_and_weights(frame)
+            return _make_metrics(self, raw, y, w)
 
     def _distribution_for_metrics(self) -> str:
         return getattr(self.params, "distribution", "gaussian") or "gaussian"
@@ -318,7 +320,12 @@ class ModelBuilder:
             self._x = self._features(train, p.response_column)
 
         job = Job(lambda j: self._drive(j, train, valid), f"{self.algo} build")
-        job.run_sync()
+        # the job's key is the trace id, so the trace opens here, on the
+        # calling thread, and Job.start's trace() joins it: `job` parents
+        # under `train` in one tree (entered there first, a new trace would
+        # root its own tree and `train` would sit in none)
+        with _mx.trace(job.key), _mx.span("train", algo=self.algo):
+            job.run_sync()
         return self.model
 
     # -- the Job body --------------------------------------------------------

@@ -27,6 +27,7 @@ import numpy as np
 
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.parallel.mesh import row_sharding
+from h2o3_tpu.utils import metrics as _mx
 
 MAX_BINS = 255  # codes 1..255 fit uint8 with 0 reserved for NA
 
@@ -106,6 +107,7 @@ def _device_quantile_edges(frame: Frame, names: list[str], nbins: int, sample: i
     prog = _EDGE_PROG.get(key)
     if prog is None:
 
+        @jax.named_scope("ph_edges")
         def run(X):  # (ns, Cn)
             xs = jnp.sort(X, axis=0)  # NaN sort to the end
             m = (~jnp.isnan(X)).sum(axis=0)  # (Cn,)
@@ -128,6 +130,7 @@ def _device_quantile_edges(frame: Frame, names: list[str], nbins: int, sample: i
     return np.asarray(e), np.asarray(m)
 
 
+@_mx.span("tree.fit_bins")  # ends in the pull of the edges to the host
 def fit_bins(frame: Frame, cols: list[str], nbins: int = MAX_BINS, sample: int = 200_000, seed: int = 7, nbins_cats: int | None = None) -> BinSpec:
     """Compute per-column quantile edges from (a sample of) the data.
 
@@ -244,24 +247,37 @@ def bin_frame(spec: BinSpec, frame: Frame):
     ACTUAL binning pass moves (one f32 read + one u8 write per cell) is
     tallied under ``tree_hist_hbm_bytes_total{path=rebin}``; cache hits
     move nothing and tally nothing, which is what the wave-2 A/B measures.
-    """
-    from h2o3_tpu.models.datainfo import _adapt_codes
 
+    Span ``tree.bin_frame{cache=hit|miss}``: a miss is the enqueue of the
+    pass (it ends in no sync; the pass's device time is under ``ph_bin``).
+    """
     from h2o3_tpu.parallel.mesh import mesh_epoch
 
     cache = None
     fp = None
+    B = None
     if _u8_cache_enabled():
         fp = _spec_fingerprint(spec)
         cache = frame.__dict__.setdefault("_bin_cache", {})
         hit = cache.get(fp)
         if hit is not None:
             epoch, B = hit
-            if epoch == mesh_epoch():
-                return B
-            # cached codes were padded/placed for a dead topology (elastic
-            # reform, ISSUE 17): drop and rebin on the new mesh
-            cache.pop(fp, None)
+            if epoch != mesh_epoch():
+                # cached codes were padded/placed for a dead topology
+                # (elastic reform, ISSUE 17): drop and rebin on the new mesh
+                cache.pop(fp, None)
+                B = None
+    with _mx.span("tree.bin_frame", cache="miss" if B is None else "hit"):
+        if B is None:
+            B = _bin_pass(spec, frame)
+            if cache is not None:
+                cache[fp] = (mesh_epoch(), B)
+    return B
+
+
+def _bin_pass(spec: BinSpec, frame: Frame):
+    """The binning pass itself (:func:`bin_frame` without the cache)."""
+    from h2o3_tpu.models.datainfo import _adapt_codes
 
     datas = []
     for ci, name in enumerate(spec.names):
@@ -278,6 +294,7 @@ def bin_frame(spec: BinSpec, frame: Frame):
     if prog is None:
         is_cat_t, nbins_t = key[0], key[1]
 
+        @jax.named_scope("ph_bin")
         def run(datas, edges):
             cols = []
             for ci in range(len(is_cat_t)):
@@ -301,8 +318,6 @@ def bin_frame(spec: BinSpec, frame: Frame):
     from h2o3_tpu.models.tree.shared_tree import _HIST_HBM_BYTES
 
     _HIST_HBM_BYTES.inc(5.0 * B.shape[0] * B.shape[1], path="rebin")
-    if cache is not None:
-        cache[fp] = (mesh_epoch(), B)
     return B
 
 

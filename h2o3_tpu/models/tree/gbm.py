@@ -157,9 +157,11 @@ class SharedTreeModel(Model):
             return super()._score_metrics(frame)
         from h2o3_tpu.models.model_base import _make_metrics
 
-        raw = self._predict_raw_dev(frame)
-        y, w = self._response_and_weights(frame)
-        return _make_metrics(self, raw, y, w)
+        with _mx.span("model.score_metrics", algo=self.algo):
+            with _mx.span("model.predict_raw"):  # enqueues; the metric syncs
+                raw = self._predict_raw_dev(frame)
+            y, w = self._response_and_weights(frame)
+            return _make_metrics(self, raw, y, w)
 
     def _predict_raw_dev(self, frame: Frame):
         raise NotImplementedError
@@ -673,33 +675,36 @@ class GBM(ModelBuilder):
                 if efb is not None:
                     bins_b = bundle_bins(efb, bins)
 
-        # response / weights on device
-        y_np = yv.to_numpy().astype(np.float64)
-        w_np = np.zeros(npad, np.float32)
-        w_np[: train.nrow] = 1.0
-        if p.weights_column:
-            w_np[: train.nrow] *= np.nan_to_num(
-                train.vec(p.weights_column).to_numpy()
-            ).astype(np.float32)
-        w_np[: train.nrow] *= ~np.isnan(y_np) if not classification else (y_np >= 0)
-        ybuf = np.zeros(npad, np.float32)
-        ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
-        # xgboost-surface scale_pos_weight (XGBoostParams only): fold the
-        # positive-class up-weighting into the TRAINING row weights only —
-        # xgboost scales grad/hess (≡ row weights in our Newton leaves) but
-        # evaluates metrics unweighted, so the metric weights (wn) must not
-        # carry it
-        spw = float(getattr(p, "scale_pos_weight", 1.0))
-        w_train_np = w_np
-        if spw != 1.0:
-            if dist != "bernoulli":
-                raise ValueError("scale_pos_weight requires a binary response")
-            w_train_np = w_np.copy()
-            w_train_np[: train.nrow] *= np.where(
-                ybuf[: train.nrow] == 1.0, spw, 1.0
-            ).astype(np.float32)
-        w = jnp.asarray(w_train_np)
-        y = jnp.asarray(ybuf)
+        # response / weights on device: the label pulled to the host, the
+        # weight and response lanes built there and uploaded (span
+        # gbm.response_lanes; starts with a pull, ends in enqueued uploads)
+        with _mx.span("gbm.response_lanes"):
+            y_np = yv.to_numpy().astype(np.float64)
+            w_np = np.zeros(npad, np.float32)
+            w_np[: train.nrow] = 1.0
+            if p.weights_column:
+                w_np[: train.nrow] *= np.nan_to_num(
+                    train.vec(p.weights_column).to_numpy()
+                ).astype(np.float32)
+            w_np[: train.nrow] *= ~np.isnan(y_np) if not classification else (y_np >= 0)
+            ybuf = np.zeros(npad, np.float32)
+            ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
+            # xgboost-surface scale_pos_weight (XGBoostParams only): fold the
+            # positive-class up-weighting into the TRAINING row weights only —
+            # xgboost scales grad/hess (≡ row weights in our Newton leaves) but
+            # evaluates metrics unweighted, so the metric weights (wn) must not
+            # carry it
+            spw = float(getattr(p, "scale_pos_weight", 1.0))
+            w_train_np = w_np
+            if spw != 1.0:
+                if dist != "bernoulli":
+                    raise ValueError("scale_pos_weight requires a binary response")
+                w_train_np = w_np.copy()
+                w_train_np[: train.nrow] *= np.where(
+                    ybuf[: train.nrow] == 1.0, spw, 1.0
+                ).astype(np.float32)
+            w = jnp.asarray(w_train_np)
+            y = jnp.asarray(ybuf)
 
         offset = jnp.zeros(npad, jnp.float32)
         if p.offset_column:
@@ -1022,14 +1027,17 @@ class GBM(ModelBuilder):
         model = self.MODEL_CLS(DKV.make_key(self.algo), p, out)
         model.scoring_history = history
         dom = out["response_domain"]
-        model.training_metrics = _metrics_from_F(
-            dist, F, yn, wn, train.nrow, domain=dom
-        )
-        if valid is not None:
-            Fv_s = jnp.stack(Fv, axis=1) if dist == "multinomial" else Fv[0]
-            model.validation_metrics = _metrics_from_F(
-                dist, Fv_s, yv_np, wv_np, valid.nrow, domain=dom
+        # the model's metrics come from the running scores F (no replay of
+        # the trees, so no model.predict_raw child); ends in the stats' pull
+        with _mx.span("model.score_metrics", algo=self.algo):
+            model.training_metrics = _metrics_from_F(
+                dist, F, yn, wn, train.nrow, domain=dom
             )
+            if valid is not None:
+                Fv_s = jnp.stack(Fv, axis=1) if dist == "multinomial" else Fv[0]
+                model.validation_metrics = _metrics_from_F(
+                    dist, Fv_s, yv_np, wv_np, valid.nrow, domain=dom
+                )
         from h2o3_tpu.models.calibration import maybe_fit_calibration
 
         maybe_fit_calibration(self, model)
@@ -1059,8 +1067,11 @@ def _metrics_from_F(dist, F, yn, wn, nrow, domain=None) -> MM.ModelMetrics:
 
 
 def _train_metric(dist, F, yn, wn, nrow, metric_name, K) -> float:
-    """Cheap training metric from the running scores."""
-    m = _metrics_from_F(dist, F, yn, wn, nrow)
+    """Cheap training metric from the running scores (span
+    ``gbm.train_metric``: ends in the pull of the device statistics, so it
+    waits for the trees enqueued before it)."""
+    with _mx.span("gbm.train_metric", metric=metric_name):
+        m = _metrics_from_F(dist, F, yn, wn, nrow)
     v = m._v.get(metric_name)
     if v is None:
         v = m._v.get("logloss" if dist in ("bernoulli", "multinomial") else "rmse")

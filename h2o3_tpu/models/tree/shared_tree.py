@@ -848,7 +848,8 @@ def _partition_update(
     child = child_base[node] + jnp.where(go_left, 0, 1)
     retired = leaf_now[node]
     new_nid = jnp.where(active, jnp.where(retired, -1, child), -1)
-    new_preds = preds + jnp.where(active & retired, leaf_val[node], 0.0)
+    with jax.named_scope("ph_pred"):  # the prediction update, under ph_part
+        new_preds = preds + jnp.where(active & retired, leaf_val[node], 0.0)
     return new_nid.astype(jnp.int32), new_preds
 
 
@@ -914,16 +915,17 @@ def _finish_level(
     ``node_lo``/``node_hi`` (monotone-constraint bound state) clamp leaf
     values when given; None leaves the unconstrained trace byte-identical.
     """
-    leaf_now, leaf_val, child_base, cs, n_split, record = _leaf_decide(
-        ok, gain, node_w, node_wy, node_wh, split_col, split_bin,
-        is_cat_n, cat_mask, na_left, learn_rate, max_abs_leaf, n_pad,
-        node_lo=node_lo, node_hi=node_hi,
-        reg_lambda=reg_lambda, reg_alpha=reg_alpha,
-    )
+    with jax.named_scope("ph_leaf"):
+        leaf_now, leaf_val, child_base, cs, n_split, record = _leaf_decide(
+            ok, gain, node_w, node_wy, node_wh, split_col, split_bin,
+            is_cat_n, cat_mask, na_left, learn_rate, max_abs_leaf, n_pad,
+            node_lo=node_lo, node_hi=node_hi,
+            reg_lambda=reg_lambda, reg_alpha=reg_alpha,
+        )
 
     varimp = varimp.at[split_col].add(jnp.where(ok, gain, 0.0).astype(varimp.dtype))
 
-    # ph_part: phase tag for tools/profile_fused.py
+    # ph_part: phase tag (utils/telemetry.summarize, tools/profile_fused.py)
     with jax.named_scope("ph_part"):
         nid, preds = _partition_update(
             bins_u8, nid, preds, split_col, split_bin, is_cat_n, cat_mask,
@@ -1020,7 +1022,7 @@ def _level_core(
     if Cr < C:
         keep = jnp.pad(keep, ((0, 0), (0, C - Cr)))
     col_mask = col_mask * keep
-    # ph_split: phase tag for tools/profile_fused.py
+    # ph_split: phase tag (utils/telemetry.summarize, tools/profile_fused.py)
     with jax.named_scope("ph_split"):
         if fuse_layout is not None and split_shard:
             sp = _split_scan_sharded_fused(

@@ -35,10 +35,11 @@ _P = jax.lax.Precision.HIGHEST
 @jax.jit
 def weighted_gram(X, w, z):
     """Return (G, b) = (XᵀWX, XᵀWz) for diagonal W, plus the weight sum."""
-    Xw = X * w[:, None]
-    G = jnp.einsum("np,nq->pq", Xw, X, precision=_P)
-    b = jnp.einsum("np,n->p", Xw, z, precision=_P)
-    return G, b, w.sum(dtype=jnp.float32)
+    with jax.named_scope("ph_gram"):
+        Xw = X * w[:, None]
+        G = jnp.einsum("np,nq->pq", Xw, X, precision=_P)
+        b = jnp.einsum("np,n->p", Xw, z, precision=_P)
+        return G, b, w.sum(dtype=jnp.float32)
 
 
 def weighted_gram_sharded(X, w, z, mesh=None):
@@ -69,6 +70,7 @@ def weighted_gram_sharded(X, w, z, mesh=None):
 
     from h2o3_tpu.ops import collectives
 
+    @jax.named_scope("ph_gram")
     def local(Xl, wl, zl):
         Xw = Xl * wl[:, None]
         G_l = jnp.einsum("np,nq->pq", Xw, Xl, precision=_P)

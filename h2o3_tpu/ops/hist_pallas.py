@@ -601,6 +601,7 @@ def hist_pallas_local(
             out_shape=jax.ShapeDtypeStruct(blk_shape, jnp.float32),
             cost_estimate=cost,
             interpret=interpret,
+            name="hist_pallas_blocked",  # the prefix is the trace readers' key
         )(bins3, nid2, stats)
         if lay_sh.n_ct > n_ct:
             out = jnp.pad(out, ((0, lay_sh.n_ct - n_ct), (0, 0), (0, 0)))
@@ -631,6 +632,7 @@ def hist_pallas_local(
         out_shape=jax.ShapeDtypeStruct((n_nt * nt * ns, cpad * bpad), jnp.float32),
         cost_estimate=cost,
         interpret=interpret,
+        name="hist_pallas_dense",  # the prefix is the trace readers' key
     )(bins3, nid2, stats)
 
     # unscramble: out rows = node·S+stat, lanes = ct-tile-major [bin//CT, col%CT]

@@ -13,9 +13,11 @@ EVERY process all the time, including ``H2O3_TPU_METRICS=0``:
   (the cached-program key carries all three) via :func:`dispatch`, which
   also feeds the ``dispatch_device_seconds{site}`` histogram — measured
   device-time attribution per hot site (tree chunk, IRLS chunk, DL chunk,
-  serving batch, stream block), cross-referenceable by timestamp with
-  ``tools/profile_train_stages.py`` and the ``jax.profiler`` wrapper
-  (utils/telemetry.py stamps ``profiler`` events into the same ring);
+  serving batch, stream block). Each dispatch is a span from the metrics
+  layer's one core (``metrics.OpenSpan``), so inside a ``jax.profiler``
+  capture it is a ``dispatch:<site>`` annotation on the host plane, under
+  the program span that issued it (``utils/telemetry.summarize`` reads
+  them; the wrapper there also stamps ``profiler`` events into this ring);
 - collective phase tallies (per-dispatch byte totals, models/tree);
 - stream-block fetch/evict (frame/chunkstore.py), serving
   page-in/eviction (serving/residency.py);
@@ -115,9 +117,9 @@ def trace_export(trace: str | None = None, n: int | None = None) -> dict:
     trace/span/parent ids — render as complete ("X") spans positioned at
     end-timestamp minus duration; every other ring kind (chunk_fetch,
     queue_wait, collectives, …) renders as an instant event on its trace's
-    lane; ``profiler_start``/``profiler_end`` pairs render the xplane
-    capture window on a dedicated lane, so which dispatches the profiler
-    saw is readable by timestamp overlap. Registry spans of the exported
+    lane; ``profiler_start``/``profiler_end`` pairs render the window in
+    which an xplane capture was open on a dedicated lane (the capture
+    itself holds the spans: ``telemetry.summarize``). Registry spans of the exported
     traces (the "job" / "rest.request" parents) merge onto the same lanes,
     completing the span tree Perfetto shows."""
     return render_trace(events(n=n), trace=trace,
@@ -228,30 +230,28 @@ class _Dispatch:
     @contextmanager: the hot sites enter/exit this once per device program
     and the generator machinery is measurably slower.
 
-    Every dispatch is also a **span** in the active trace tree (ISSUE-18):
-    start/end events carry ``trace`` (the enclosing job/request trace id,
-    None when untraced), a fresh ``span`` id from the shared metrics
-    sequence, and the ``parent`` span active at entry. The span id is
-    pushed as the active span for the dispatch body, so nested dispatches
-    (a stream_block wrapping a tree chunk) and registry spans parent
+    Every dispatch is also a **span** in the active trace tree (ISSUE-18),
+    opened and closed by the metrics layer's one core
+    (``metrics.OpenSpan``: id, parent, trace, clock, profiler annotation
+    ``dispatch:<site>``): start/end events carry ``trace`` (the enclosing
+    job/request trace id, None when untraced), the span's id from the
+    shared sequence, and the ``parent`` span active at entry. The span is
+    the active one for the dispatch body, so nested dispatches (a
+    stream_block wrapping a tree chunk) and registry spans parent
     correctly — all of it gate-free, like the ring itself. On exit the
     measured wall feeds the per-job ledger (utils/jobacct.py) under the
     same trace id."""
 
-    __slots__ = ("site", "meta", "_t0", "_trace", "_span", "_parent", "_tok")
+    __slots__ = ("site", "meta", "_s")
 
     def __init__(self, site: str, meta: dict):
         self.site = site
         self.meta = meta
 
     def __enter__(self):
-        self._trace = _mx.current_trace()
-        self._parent = _mx.current_span()
-        self._span = _mx.next_span_id()
-        record("dispatch_start", site=self.site, trace=self._trace,
-               span=self._span, parent=self._parent, **self.meta)
-        self._tok = _mx.push_span(self._span)
-        self._t0 = time.perf_counter()
+        s = self._s = _mx.OpenSpan(f"dispatch:{self.site}")
+        record("dispatch_start", site=self.site, trace=s.trace,
+               span=s.id, parent=s.parent, **self.meta)
         if _faults.armed():
             # chaos hooks INSIDE the open span: hang_check sleeps while the
             # ring shows an open dispatch_start (what the hang watchdog
@@ -269,14 +269,14 @@ class _Dispatch:
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter() - self._t0
-        _mx.pop_span(self._tok)
+        s = self._s
+        dur = s.close()
         record("dispatch_end", site=self.site,
                dur_ms=round(dur * 1e3, 3),
-               trace=self._trace, span=self._span, parent=self._parent,
+               trace=s.trace, span=s.id, parent=s.parent,
                **({"error": exc_type.__name__} if exc_type else {}))
         _DISPATCH_SECONDS.observe(dur, site=self.site)
-        _jobacct.on_dispatch(self._trace, self.site, dur)
+        _jobacct.on_dispatch(s.trace, self.site, dur)
         from h2o3_tpu.utils import devmem
 
         devmem.on_dispatch()  # high-water marks sample at dispatch boundaries
@@ -284,15 +284,15 @@ class _Dispatch:
             from h2o3_tpu.utils import overload as _ov
 
             _ov.note_dispatch_error(self.site, exc)
-        elif _HUNG_SPANS and self._span in _HUNG_SPANS:
+        elif _HUNG_SPANS and s.id in _HUNG_SPANS:
             # the hang watchdog tripped on this span and already latched the
             # cloud degraded: a late result from a wedged dispatch must not
             # be trusted — fail-stop so the supervisor's reform+resume owns
             # the job from here.
-            _HUNG_SPANS.discard(self._span)
+            _HUNG_SPANS.discard(s.id)
             raise RuntimeError(
                 f"cloud is degraded (fail-stop): dispatch site "
-                f"{self.site!r} span {self._span} was declared wedged by "
+                f"{self.site!r} span {s.id} was declared wedged by "
                 "the hang watchdog and its late result is discarded; "
                 "supervised jobs resume from their latest snapshot")
         return False

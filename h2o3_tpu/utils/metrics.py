@@ -10,11 +10,12 @@ Three pieces, one module:
   artifacts, so the live endpoint and the bench numbers can never disagree.
 - **Spans** (:func:`span`): a hierarchical timing context manager.
   ``span("gbm.build_tree", trees=8)`` nests under the enclosing span and
-  under the active Job's trace (:func:`trace`, entered by ``Job.start``);
-  every completed span lands in the per-trace event list (served as
-  Chrome-trace JSON over ``GET /3/Jobs/{key}/trace``), in the recent-span
-  ring merged into ``/3/Timeline``, and in the ``span_seconds`` latency
-  histogram.
+  under the active Job's trace (:func:`trace`, entered by
+  ``ModelBuilder.train`` and ``Job.start``); every completed span lands in
+  the per-trace event list (served as Chrome-trace JSON over
+  ``GET /3/Jobs/{key}/trace``), in the recent-span ring merged into
+  ``/3/Timeline``, in the ``span_seconds`` latency histogram and, while a
+  profiler session is open, in the capture itself (:class:`OpenSpan`).
 - **Gate**: ``H2O3_TPU_METRICS=0`` turns the layer into near-free no-ops
   (read once at import — the hot paths must not re-read the environment).
   Counters created with ``always=True`` keep counting even when gated:
@@ -22,9 +23,10 @@ Three pieces, one module:
   a test/bench CONTRACT (dispatch/compile accounting), not optional
   telemetry.
 
-Hot-path budget: one ``perf_counter`` pair + one locked dict update per
-span/observe — the bench fused-tree acceptance bound is <= 2% overhead
-registry-on vs ``H2O3_TPU_METRICS=0``.
+Hot-path budget: one ``perf_counter`` pair, one profiler annotation (a flag
+test while no capture is open) + one locked dict update per span/observe —
+the bench fused-tree acceptance bound is <= 2% overhead registry-on vs
+``H2O3_TPU_METRICS=0``.
 """
 
 from __future__ import annotations
@@ -370,10 +372,29 @@ def histogram(name: str, help: str = "", buckets=None,
     return REGISTRY.histogram(name, help, buckets, always)
 
 
-def counter_value(name: str, **labels) -> float:
-    """Registry read without create-on-miss (0.0 for unknown families)."""
-    fam = REGISTRY._families.get(name)
-    return fam.value(**labels) if isinstance(fam, (Counter, Gauge)) else 0.0
+def counter_value(name: str, /, **labels) -> float:
+    """Registry read without create-on-miss (0.0 for unknown families or
+    children). ``name`` may also be one sample's flat name, the form
+    :meth:`MetricsRegistry.compact_snapshot` prints:
+    ``tree_hist_hbm_bytes_total{path=rebin}``; a histogram child's running
+    sum and count are ``span_seconds_sum{name=glm.fit}`` and
+    ``span_seconds_count{name=glm.fit}``. A reader that can pass one string
+    only (the benchmark's ``COUNTERS``) reaches every sample this way."""
+    base, brace, _ = name.partition("{")
+    fam = REGISTRY._families.get(base)
+    if isinstance(fam, (Counter, Gauge)):
+        if not brace:
+            return fam.value(**labels)
+        return next((float(v) for lab, v in fam.samples()
+                     if _flat_name(base, lab) == name), 0.0)
+    stem, _, part = base.rpartition("_")  # span_seconds + sum | count
+    hist = REGISTRY._families.get(stem)
+    if part in ("sum", "count") and isinstance(hist, Histogram):
+        want = stem + name[len(base):] if brace else _flat_name(stem, labels)
+        return next((float(s if part == "sum" else n)
+                     for lab, _cum, s, n in hist.samples()
+                     if _flat_name(stem, lab) == want), 0.0)
+    return 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -454,17 +475,15 @@ def current_span() -> int | None:
 
 
 def next_span_id() -> int:
-    """Allocate a span id from the shared sequence. The ring's dispatch
-    spans and the registry spans draw from ONE counter so a trace tree
-    mixing both never collides."""
+    """Allocate a span id from the shared sequence (the serving batcher's
+    batch and queue-wait ids): one counter, so a trace tree mixing ring and
+    registry spans never collides."""
     return next(_IDS)
 
 
 def push_span(sid: int):
-    """Make ``sid`` the active span (returns the reset token). The flight
-    recorder's dispatch context manager uses this so nested dispatches —
-    and registry spans opened inside one — parent correctly even under
-    H2O3_TPU_METRICS=0."""
+    """Make ``sid`` the active span (returns the reset token): the serving
+    batcher parents its shared dispatch under the batch id this way."""
     return _SPAN_VAR.set(sid)
 
 
@@ -487,30 +506,75 @@ def _record_span(ev: dict) -> None:
             spans.append(ev)
 
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, imported at the first span
+
+
+def _annotation(name: str, **stats):
+    """The profiler's own annotation for ``name``: while a profiler session
+    is open (``jax.profiler.trace`` / ``start_trace``) it lands on the
+    capture's host plane with ``stats`` intact, on the device trace's clock;
+    with no session open it is a flag test."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **stats)
+
+
+class OpenSpan:
+    """The one span enter/exit core, shared by :func:`span` and the flight
+    recorder's dispatch spans: constructing it OPENS the span (an id from
+    the shared sequence, parent and trace from the contextvars, the active
+    span pushed, a profiler annotation entered, the clocks stamped) and
+    :meth:`close` ends it and returns its seconds. What the caller then
+    records (trace tree and histogram, or ring event and job ledger) is the
+    caller's; how a span is timed, nested and put into a profiler capture
+    is here and nowhere else. Not gated: :func:`span` checks the gate before
+    it opens one, the dispatch spans run in every process."""
+
+    __slots__ = ("id", "parent", "trace", "ts", "_t0", "_tok", "_ann")
+
+    def __init__(self, name: str):
+        self.id = next(_IDS)
+        self.parent = _SPAN_VAR.get()
+        self.trace = _TRACE_VAR.get()
+        self._tok = _SPAN_VAR.set(self.id)
+        self._ann = _annotation(name, span_id=self.id,
+                                parent=self.parent or 0,
+                                trace=self.trace or "")
+        self._ann.__enter__()
+        self.ts = time.time()
+        self._t0 = time.perf_counter()
+
+    def close(self) -> float:
+        dur = time.perf_counter() - self._t0
+        self._ann.__exit__(None, None, None)
+        _SPAN_VAR.reset(self._tok)
+        return dur
+
+
 @contextlib.contextmanager
 def span(name: str, **labels):
     """Time a named region. Nests under the active span/trace; on exit the
     completed span is recorded into the trace tree, the recent ring (merged
-    into /3/Timeline) and the ``span_seconds`` histogram."""
+    into /3/Timeline) and the ``span_seconds`` histogram. For its life it is
+    also an annotation inside any open profiler capture (:class:`OpenSpan`),
+    so an xplane holds the program's span tree beside the device operations."""
     if not _ENABLED:
         yield None
         return
-    sid = next(_IDS)
-    parent = _SPAN_VAR.get()
-    token = _SPAN_VAR.set(sid)
-    ts = time.time()
-    t0 = time.perf_counter()
+    s = OpenSpan(name)
     try:
-        yield sid
+        yield s.id
     finally:
-        dur = time.perf_counter() - t0
-        _SPAN_VAR.reset(token)
+        dur = s.close()
         _record_span({
             "name": name,
-            "trace": _TRACE_VAR.get(),
-            "id": sid,
-            "parent": parent,
-            "ts": ts,
+            "trace": s.trace,
+            "id": s.id,
+            "parent": s.parent,
+            "ts": s.ts,
             "dur_s": dur,
             "thread": threading.get_ident(),
             "labels": {k: str(v) for k, v in labels.items()},

@@ -517,7 +517,8 @@ def test_hung_span_fail_stops_at_dispatch_exit():
     d = flightrec.dispatch("wd_failstop")
     with pytest.raises(RuntimeError, match="fail-stop"):
         with d:
-            flightrec.mark_span_hung(d._span)
+            # the open dispatch is the active span (metrics.OpenSpan)
+            flightrec.mark_span_hung(mx.current_span())
     ends = [e for e in flightrec.events(kind="dispatch_end")
             if e["site"] == "wd_failstop"]
     assert ends  # the span still closed in the ring

@@ -72,6 +72,23 @@ def test_blocked_histogram_kernel_lowers_for_tpu(n_nodes):
     assert "tpu_custom_call" in _export_tpu(f, *_hist_args())
 
 
+@pytest.mark.parametrize("blocked,name", [
+    (False, "hist_pallas_dense"), (True, "hist_pallas_blocked")])
+def test_histogram_kernels_keep_their_names_in_the_lowered_text(blocked, name):
+    """The profiler names a kernel's device events after its ``pallas_call``
+    (``%hist_pallas_dense.66 = ... custom-call``), and the benchmark's
+    ``hist_kernel_roofline_pct`` finds them by ``^%?hist_pallas``: both
+    layouts carry a stable name with that prefix."""
+    import re
+
+    f = functools.partial(
+        hp.hist_pallas_local, n_nodes=64, n_bins=BINS, interpret=False,
+        blocked=blocked, tiles=TILES)
+    names = re.findall(r'kernel_name = "([^"]+)"', _export_tpu(f, *_hist_args()))
+    assert names == [name]
+    assert re.match(r"^%?hist_pallas", names[0])
+
+
 def _split_lowers(platform: str) -> bool:
     L = hp.plan_layout(COLS, 64, BINS, LANES, tiles=TILES)
     f = functools.partial(split_candidates, layout=L, interpret=False)
