@@ -610,32 +610,42 @@ class GLMModel(Model):
     algo = "glm"
 
     def _predict_raw(self, frame: Frame) -> np.ndarray:
+        if not self.output.get("ordinal"):
+            return np.asarray(self._predict_raw_dev(frame))
         di: DataInfo = self.output["datainfo"]
-        X, valid = di.transform(frame)
-        if self.output.get("ordinal"):
-            beta = np.asarray(self.output["beta_std"], np.float64)
-            theta = np.asarray(self.output["theta"], np.float64)
-            eta = np.asarray(X, np.float64)[: frame.nrow] @ beta
-            cum = 1.0 / (1.0 + np.exp(-(theta[None, :] - eta[:, None])))
-            lo = np.concatenate([np.zeros((len(eta), 1)), cum], axis=1)
-            hi = np.concatenate([cum, np.ones((len(eta), 1))], axis=1)
-            return np.clip(hi - lo, 1e-12, 1.0)
+        X, _ = di.transform(frame)
+        beta = np.asarray(self.output["beta_std"], np.float64)
+        theta = np.asarray(self.output["theta"], np.float64)
+        eta = np.asarray(X, np.float64)[: frame.nrow] @ beta
+        cum = 1.0 / (1.0 + np.exp(-(theta[None, :] - eta[:, None])))
+        lo = np.concatenate([np.zeros((len(eta), 1)), cum], axis=1)
+        hi = np.concatenate([cum, np.ones((len(eta), 1))], axis=1)
+        return np.clip(hi - lo, 1e-12, 1.0)
+
+    def _device_predictor(self):
+        # ordinal scoring is float64 numpy code: it stays on the host path
+        return None if self.output.get("ordinal") else self._predict_raw_dev
+
+    def _predict_raw_dev(self, frame: Frame):
+        """``_predict_raw`` of every family but ordinal, with no pull to the
+        host: (n, 2) for binomial, ``mu`` for the regression families,
+        (n, K) for multinomial."""
+        di: DataInfo = self.output["datainfo"]
+        X, _ = di.transform(frame)
         if self.output.get("multinomial"):
             Beta = jnp.asarray(self.output["beta_multinomial_std"], jnp.float32)
-            probs = np.asarray(_softmax_probs(X, Beta))[: frame.nrow]
-            return probs
+            return _softmax_probs(X, Beta)[: frame.nrow]
         beta = jnp.asarray(self.output["beta_std"], jnp.float32)
-        offset = _offset_col(self.params, frame)
         fam = self.output["family_obj"]
         # issued op by op, so the scope names these operations only where a
         # caller traces this method into a program of its own
         with jax.named_scope("ph_score"):
-            eta = np.asarray(
-                jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
-            )[: frame.nrow]
-            mu = np.asarray(fam.link.inv(jnp.asarray(eta)))
+            eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + _offset_col(
+                self.params, frame
+            )
+            mu = fam.link.inv(eta)[: frame.nrow]
         if self.is_classifier:
-            return np.stack([1 - mu, mu], axis=1)
+            return jnp.stack([1 - mu, mu], axis=1)
         return mu
 
     @property

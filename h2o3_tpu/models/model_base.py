@@ -18,6 +18,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Sequence
 
+import jax
 import numpy as np
 
 from h2o3_tpu.cluster.job import Job
@@ -197,11 +198,31 @@ class Model:
             w = frame.vec(self.params.weights_column).to_numpy()
         return y, w
 
+    def _device_predictor(self):
+        """The model's ``_predict_raw_dev(frame)``: ``_predict_raw`` with no
+        pull to the host (the same mathematics, jax arrays out). None where
+        the model has none, or declines for this fit."""
+        return getattr(self, "_predict_raw_dev", None)
+
     def _score_metrics(self, frame: Frame) -> MM.ModelMetrics:
-        with _mx.span("model.score_metrics", algo=self.algo):
+        """On an accelerator a model that can predict there hands device
+        arrays to ``_make_metrics``: metrics.py then reduces the sufficient
+        statistics on the device and KBs come down instead of a prediction
+        column. Everywhere else the predictions are pulled and the host path
+        computes the exact float64 summaries."""
+        predict_dev = (
+            self._device_predictor() if jax.default_backend() != "cpu" else None
+        )
+        path = "host" if predict_dev is None else "device"
+        with _mx.span("model.score_metrics", algo=self.algo, path=path):
             frame = self._apply_preprocessors(frame)
-            with _mx.span("model.predict_raw"):  # ends in the pull to numpy
-                raw = np.asarray(self._predict_raw(frame))
+            # host: ends in the pull to numpy; device: enqueues, and the
+            # metric's pull of its statistics is the sync
+            with _mx.span("model.predict_raw"):
+                if predict_dev is None:
+                    raw = np.asarray(self._predict_raw(frame))
+                else:
+                    raw = predict_dev(frame)
             y, w = self._response_and_weights(frame)
             return _make_metrics(self, raw, y, w)
 

@@ -149,23 +149,6 @@ class SharedTreeModel(Model):
             preds.append(pk)
         return jnp.stack(preds, axis=1) if K > 1 else preds[0]
 
-    def _score_metrics(self, frame: Frame):
-        """Device-stat scoring on accelerators: predictions never leave the
-        device; metrics.py reduces sufficient statistics there (KBs come
-        down to the host instead of a full prediction column)."""
-        if jax.default_backend() == "cpu":
-            return super()._score_metrics(frame)
-        from h2o3_tpu.models.model_base import _make_metrics
-
-        with _mx.span("model.score_metrics", algo=self.algo):
-            with _mx.span("model.predict_raw"):  # enqueues; the metric syncs
-                raw = self._predict_raw_dev(frame)
-            y, w = self._response_and_weights(frame)
-            return _make_metrics(self, raw, y, w)
-
-    def _predict_raw_dev(self, frame: Frame):
-        raise NotImplementedError
-
     def _varimp_table(self):
         vi = self.output.get("varimp")
         if vi is None:

@@ -526,7 +526,8 @@ class OpenSpan:
     """The one span enter/exit core, shared by :func:`span` and the flight
     recorder's dispatch spans: constructing it OPENS the span (an id from
     the shared sequence, parent and trace from the contextvars, the active
-    span pushed, a profiler annotation entered, the clocks stamped) and
+    span pushed, a profiler annotation entered that carries the ids and the
+    caller's ``labels`` as its stats, the clocks stamped) and
     :meth:`close` ends it and returns its seconds. What the caller then
     records (trace tree and histogram, or ring event and job ledger) is the
     caller's; how a span is timed, nested and put into a profiler capture
@@ -535,14 +536,17 @@ class OpenSpan:
 
     __slots__ = ("id", "parent", "trace", "ts", "_t0", "_tok", "_ann")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, labels: dict | None = None):
         self.id = next(_IDS)
         self.parent = _SPAN_VAR.get()
         self.trace = _TRACE_VAR.get()
         self._tok = _SPAN_VAR.set(self.id)
-        self._ann = _annotation(name, span_id=self.id,
-                                parent=self.parent or 0,
-                                trace=self.trace or "")
+        stats = {"span_id": self.id, "parent": self.parent or 0,
+                 "trace": self.trace or ""}
+        # the span's labels ride behind its own stats, so a capture says
+        # which branch a span took (model.score_metrics: path=device|host)
+        self._ann = _annotation(name, **stats, **{
+            k: v for k, v in (labels or {}).items() if k not in stats})
         self._ann.__enter__()
         self.ts = time.time()
         self._t0 = time.perf_counter()
@@ -564,7 +568,7 @@ def span(name: str, **labels):
     if not _ENABLED:
         yield None
         return
-    s = OpenSpan(name)
+    s = OpenSpan(name, labels)
     try:
         yield s.id
     finally:

@@ -261,7 +261,10 @@ def load_capture(path: str) -> dict:
                                for n, s, d, st in evs)
         elif pname == _HOST_PLANE:
             for _lname, evs in _plane_lines(raw):
-                host.extend([n, s, d, int(st["span_id"]), int(st.get("parent", 0))]
+                # a span that says which branch it took (its ``path`` label)
+                # is summed under that name: model.score_metrics{path=device}
+                host.extend([n + (f"{{path={st['path']}}}" if "path" in st else ""),
+                             s, d, int(st["span_id"]), int(st.get("parent", 0))]
                             for n, s, d, st in evs if "span_id" in st)
     return {"device": device, "host": host}
 

@@ -480,3 +480,140 @@ def test_lbfgs_lambda_search_with_offset_does_not_early_stop():
     path = m.output["regularization_path"]
     assert len(path) > 1, "path stopped at lambda_max (offset-blind null)"
     assert abs(m.coef["x0"] - 0.8) < 0.1
+
+
+# ---------------------------------------------------------------------------
+# the device predictor, and the one Model._score_metrics that routes to it
+
+
+def _family_frame(family, n=3001, seed=11):
+    """3001 rows: the frame's padded length (a multiple of the 8 shards)
+    is longer than its rows."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, 3))
+    eta = X @ np.array([0.8, -0.5, 0.3]) + 0.2
+    off = rng.uniform(-0.3, 0.3, n)
+    df = pd.DataFrame(X, columns=list("abc"))
+    df["off"] = off
+    types = None
+    if family == "gaussian":
+        df["y"] = eta + off + 0.1 * rng.normal(size=n)
+    elif family == "poisson":
+        df["y"] = rng.poisson(np.exp(0.5 * eta + off)).astype(float)
+    elif family == "binomial":
+        p = 1 / (1 + np.exp(-(eta + off)))
+        df["y"] = np.where(rng.random(n) < p, "yes", "no")
+    else:  # multinomial, ordinal: three classes, no offset
+        df = df.drop(columns="off")
+        cls = np.digitize(eta + rng.logistic(size=n), [-0.5, 0.8])
+        df["y"] = cls.astype(str)
+        types = {"y": "enum"}
+    return df, Frame.from_pandas(df, column_types=types)
+
+
+@pytest.mark.parametrize("family", ["binomial", "gaussian", "poisson",
+                                    "multinomial"])
+def test_predict_raw_dev_is_predict_raw_without_the_pull(family):
+    import jax
+
+    df, fr = _family_frame(family)
+    offset = None if family == "multinomial" else "off"
+    m = GLM(family=family, lambda_=0.0, offset_column=offset,
+            standardize=family != "multinomial").train(
+        y="y", training_frame=fr, x=list("abc"))
+    assert fr.npad > fr.nrow
+    dev = m._device_predictor()(fr)
+    assert isinstance(dev, jax.Array)
+    host = m._predict_raw(fr)
+    assert isinstance(host, np.ndarray)
+    np.testing.assert_array_equal(host, np.asarray(dev))
+    # against the formula on the original scale, in numpy
+    X = df[list("abc")].to_numpy()
+    if family == "multinomial":
+        # fitted unstandardised: (P, K) with the intercepts in the last row
+        B = np.asarray(m.output["beta_multinomial_std"], np.float64)
+        z = X @ B[:-1] + B[-1]
+        want = np.exp(z - z.max(axis=1, keepdims=True))
+        want /= want.sum(axis=1, keepdims=True)
+    else:
+        eta = (X @ np.array([m.coef[c] for c in "abc"]) + m.coef["Intercept"]
+               + df["off"].to_numpy())
+        want = {"gaussian": eta, "poisson": np.exp(eta),
+                "binomial": 1 / (1 + np.exp(-eta))}[family]
+        if family == "binomial":
+            want = np.stack([1 - want, want], axis=1)
+    assert host.shape == want.shape
+    np.testing.assert_allclose(host, want, rtol=2e-4, atol=2e-5)
+
+
+def test_ordinal_declines_the_device_predictor():
+    _, fr = _family_frame("ordinal")
+    m = GLM(family="ordinal").train(y="y", training_frame=fr)
+    assert m._device_predictor() is None
+    assert m._predict_raw(fr).shape == (fr.nrow, 3)
+
+
+def _as_accelerator(monkeypatch):
+    """Make the routing read the backend as an accelerator, and nothing
+    else: the predictors and the statistics still run on the CPU mesh."""
+    import types
+
+    import jax
+
+    from h2o3_tpu.models import metrics as MM
+    from h2o3_tpu.models import model_base
+
+    monkeypatch.setattr(model_base, "jax",
+                        types.SimpleNamespace(default_backend=lambda: "tpu"))
+    monkeypatch.setattr(
+        MM, "_on_device",
+        lambda *arrays: any(isinstance(a, jax.Array) for a in arrays))
+    seen = []
+    for name in ("binomial_metrics", "multinomial_metrics",
+                 "regression_metrics"):
+        def spy(actual, pred, *a, _f=getattr(MM, name), **kw):
+            seen.append(type(pred))
+            return _f(actual, pred, *a, **kw)
+
+        monkeypatch.setattr(MM, name, spy)
+    return seen
+
+
+@pytest.mark.parametrize("algo,path", [
+    ("glm", "device"), ("gbm", "device"), ("glm_multinomial", "device"),
+    ("glm_ordinal", "host"), ("naivebayes", "host")])
+def test_score_metrics_routes_through_the_one_base_method(
+        monkeypatch, algo, path):
+    import jax
+
+    from h2o3_tpu.models.model_base import Model
+    from h2o3_tpu.utils import metrics as mx
+
+    family = {"glm_multinomial": "multinomial",
+              "glm_ordinal": "ordinal"}.get(algo, "binomial")
+    _, fr = _family_frame(family)
+    if algo == "gbm":
+        from h2o3_tpu.models.tree.gbm import GBM
+
+        m = GBM(ntrees=3, max_depth=3, seed=1).train(
+            y="y", training_frame=fr, x=list("abc"))
+    elif algo == "naivebayes":
+        from h2o3_tpu.models.naive_bayes import NaiveBayes
+
+        m = NaiveBayes().train(y="y", training_frame=fr, x=list("abc"))
+    else:
+        m = GLM(family=family).train(y="y", training_frame=fr, x=list("abc"))
+    assert type(m)._score_metrics is Model._score_metrics
+    cpu = m._score_metrics(fr)
+    seen = _as_accelerator(monkeypatch)
+    with mx.trace(f"route-{algo}"):
+        got = m._score_metrics(fr)
+    assert len(seen) == 1
+    assert issubclass(seen[0], jax.Array) == (path == "device")
+    spans = {e["name"]: e for e in mx.trace_events(f"route-{algo}")}
+    assert spans["model.score_metrics"]["labels"] == {
+        "algo": m.algo, "path": path}
+    assert spans["model.predict_raw"]["parent"] == spans[
+        "model.score_metrics"]["id"]
+    assert got.nobs == cpu.nobs == fr.nrow
+    assert got.logloss == pytest.approx(cpu.logloss, rel=1e-5)

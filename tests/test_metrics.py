@@ -371,3 +371,117 @@ def test_persist_retry_bumps_counter_and_logs(monkeypatch, tmp_path):
     assert after - before == 2
     tail = "\n".join(Log.tail(50, level="WARN"))
     assert "flaky-probe" in tail and "retrying" in tail
+
+
+# ---------------------------------------------------------------------------
+# model metrics: the device-statistics path (models/metrics.py) against the
+# exact host path. On an accelerator every tree model and GLM report the
+# device path's numbers; here its functions are called directly on the CPU.
+
+_N_PARITY = 200_000
+
+
+@pytest.fixture(scope="module")
+def logit_sample():
+    rng = np.random.default_rng(7)
+    x = rng.normal(size=(_N_PARITY, 3))
+    eta = x @ np.array([1.2, -0.8, 0.4]) - 0.2
+    p = (1.0 / (1.0 + np.exp(-eta))).astype(np.float32)
+    y = (rng.random(_N_PARITY) < p).astype(np.float64)
+    return {"x": x, "eta": eta, "p": p, "y": y,
+            "w": rng.uniform(0.5, 2.0, _N_PARITY)}
+
+
+def _parity_inputs(s, case):
+    """(actual, weights, binomial probability) of one case."""
+    y, w, p = s["y"].copy(), None, s["p"].copy()
+    if case != "unweighted":
+        w = s["w"].copy()
+    if case == "nan_labels_zero_weights":
+        y[::17] = np.nan
+        w[::13] = 0.0
+    elif case == "saturated":  # a saturated sigmoid, right and wrong
+        p[:100] = 0.0
+        p[100:200] = 1.0
+    elif case == "constant":
+        p[:] = 0.3
+    return y, w, p
+
+
+def _rel(a, b):
+    return abs(a - b) / max(abs(a), 1e-30)
+
+
+@pytest.mark.parametrize("kind,case", [
+    ("binomial", "unweighted"), ("binomial", "weighted"),
+    ("binomial", "nan_labels_zero_weights"), ("binomial", "saturated"),
+    ("binomial", "constant"),
+    ("regression", "unweighted"), ("regression", "weighted"),
+    ("regression", "nan_labels_zero_weights"),
+    ("multinomial", "unweighted"), ("multinomial", "weighted"),
+    ("multinomial", "nan_labels_zero_weights"),
+])
+def test_device_statistics_match_the_host_path(logit_sample, kind, case):
+    from h2o3_tpu.models import metrics as MM
+
+    s = logit_sample
+    y, w, p = _parity_inputs(s, case)
+    sw = float(np.sum((np.ones_like(y) if w is None else w)[~np.isnan(y)]))
+    if kind == "binomial":
+        host = MM.binomial_metrics(y, p, w)
+        dev = MM._binomial_metrics_device(y, p, w, ("0", "1"))
+        assert np.isfinite(dev.logloss)
+        assert np.sum(dev.confusion_matrix) == pytest.approx(sw, rel=1e-6)
+        if case == "constant":
+            # every threshold up to the score predicts the same: which one
+            # stands for them (and PR-AUC's tie order) is each path's own
+            assert dev.ks == 0.0 and dev.auc == 0.5
+        else:
+            assert abs(dev.auc - host.auc) < 1e-4
+            assert abs(dev.pr_auc - host.pr_auc) < 1e-3
+            assert abs(dev.ks - host.ks) < 1e-3
+            assert (abs(dev.default_threshold - host.default_threshold)
+                    <= 1 / 400 + 1 / 1024)
+        scalars = ("logloss", "mse")
+    elif kind == "regression":
+        a = np.where(np.isnan(y), np.nan, np.random.default_rng(3).poisson(
+            np.exp(0.3 * s["eta"])).astype(np.float64))
+        mu = np.exp(0.3 * s["eta"]).astype(np.float32)
+        host = MM.regression_metrics(a, mu, w, "poisson")
+        dev = MM._regression_metrics_device(a, mu, w, "poisson")
+        scalars = ("mse", "mae", "rmsle", "r2", "mean_residual_deviance")
+    else:
+        z = np.stack([s["eta"], -s["eta"], 0.5 * s["x"][:, 0]], axis=1)
+        P = np.exp(z - z.max(axis=1, keepdims=True))
+        P = (P / P.sum(axis=1, keepdims=True)).astype(np.float32)
+        cls = (np.random.default_rng(4).random(_N_PARITY)[:, None]
+               > np.cumsum(P, axis=1)).sum(axis=1).clip(0, 2)
+        yk = np.where(np.isnan(y), -1, cls).astype(np.int64)
+        host = MM.multinomial_metrics(yk, P, w)
+        dev = MM._multinomial_metrics_device(yk, P, w, ())
+        assert np.sum(dev.confusion_matrix) == pytest.approx(sw, rel=1e-6)
+        np.testing.assert_allclose(dev.confusion_matrix, host.confusion_matrix,
+                                   rtol=1e-5)
+        np.testing.assert_allclose(dev.hit_ratios, host.hit_ratios, rtol=2e-6)
+        scalars = ("logloss", "mse", "classification_error",
+                   "mean_per_class_error")
+    assert dev.nobs == host.nobs
+    for name in scalars:
+        assert _rel(host.value(name), dev.value(name)) < 2e-6, name
+
+
+def test_device_log_is_float32_accurate():
+    """The device statistics take their logs from a polynomial (the TPU's
+    own ``log`` reads low by 2e-6 in the mean): an ulp or two from float64
+    over every magnitude a clipped probability has, exact at 1."""
+    from h2o3_tpu.models import metrics as MM
+
+    x = np.concatenate([
+        np.logspace(-15, 0, 20001), 1 - np.logspace(-7.2, -0.3, 5001),
+        np.random.default_rng(0).random(50000), [1e-15, 0.5, np.sqrt(0.5), 1.0],
+    ]).astype(np.float32)
+    got = np.asarray(MM._log_f32(x), np.float64)
+    want = np.log(x.astype(np.float64))
+    assert got[-1] == 0.0
+    assert np.max(np.abs(got - want) / np.maximum(np.abs(want), 1e-7)) < 3e-7
+    assert abs(np.mean(got - want)) < 2e-9

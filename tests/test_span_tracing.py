@@ -164,13 +164,15 @@ def test_span_is_an_annotation_inside_a_profiler_capture(tmp_path):
     x = jnp.arange(1024.0)
     with telemetry.profiler(logdir):
         with mx.trace("cap-1"), mx.span("outer.t25", k="v") as outer:
-            with mx.span("inner.t25") as inner:
+            with mx.span("inner.t25", path="device", parent="x") as inner:
                 (x * 2).block_until_ready()
             with flightrec.dispatch("t25site"):
                 pass
     assert glob.glob(os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb"))
     cap = telemetry.load_capture(logdir)
     host = {name: (sid, parent, s, d) for name, s, d, sid, parent in cap["host"]}
+    # a span's `path` label names the branch it took, in the summary too
+    host["inner.t25"] = host.pop("inner.t25{path=device}")
     assert host["outer.t25"][:2] == (outer, 0)
     assert host["inner.t25"][:2] == (inner, outer)
     assert host["dispatch:t25site"][1] == outer  # the ring's span, same core
@@ -187,11 +189,13 @@ def test_span_is_an_annotation_inside_a_profiler_capture(tmp_path):
     st = dict(evs[0].stats)
     assert int(st["span_id"]) == inner and int(st["parent"]) == outer
     assert st["trace"] == "cap-1"
+    # the labels ride behind the span's own stats, which a label cannot take
+    assert st["path"] == "device" and len(st) == 4
     rep = telemetry.summarize(logdir)
     rows = {r["name"]: r for r in rep["spans"]}
     assert rows["outer.t25"]["self_s"] <= rows["outer.t25"]["total_s"]
     assert rows["outer.t25"]["self_s"] == pytest.approx(
-        rows["outer.t25"]["total_s"] - rows["inner.t25"]["total_s"]
+        rows["outer.t25"]["total_s"] - rows["inner.t25{path=device}"]["total_s"]
         - rows["dispatch:t25site"]["total_s"], abs=1e-6)
 
 
@@ -277,9 +281,9 @@ def test_span_and_dispatch_share_one_enter_exit_core(monkeypatch):
     real = mx.OpenSpan
 
     class Spy(real):
-        def __init__(self, name):
+        def __init__(self, name, *labels):
             made.append(name)
-            super().__init__(name)
+            super().__init__(name, *labels)
 
     monkeypatch.setattr(mx, "OpenSpan", Spy)
     with mx.span("core.t25"):

@@ -151,6 +151,20 @@ def test_tile_sweep_skips_only_candidates_that_do_not_fit(monkeypatch):
         hp._run_tile_sweep(COLS, 64, BINS, LANES, interpret=False)
 
 
+@pytest.mark.parametrize("rows", [6_000_000, 6_029_312])
+def test_binomial_statistics_program_lowers_for_tpu(rows):
+    """The program behind every binomial metric on a chip (GLM's since
+    ISSUE 26, the tree models' before): the weighted sums and the
+    1024-bucket score histogram over ``glm_higgs``'s 6,000,000 rows and over
+    their padded length."""
+    from h2o3_tpu.models import metrics as MM
+
+    lane = jax.ShapeDtypeStruct((rows,), jnp.float32)
+    exported = jax.export.export(
+        MM._binom_device_stats(), platforms=["tpu"])(lane, lane, lane)
+    assert exported.out_avals[0].shape == (4 + 2 * MM._NBUCKETS,)
+
+
 # ---------------------------------------------------------------------------
 # compile-cache placement and the smoke's refusal, in fresh interpreters
 
