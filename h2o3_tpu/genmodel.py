@@ -365,7 +365,7 @@ def _design_matrix(meta_di: dict, table) -> np.ndarray:
             if c.get("pair_domains"):
                 # cat x cat combined factor: remap each source onto ITS
                 # training domain, then combined code = a*|domain_b| + b
-                # (mirrors DataInfo._transform_interaction)
+                # (mirrors datainfo._design)
                 da, db = c["pair_domains"]
                 ca = _col_codes(table, a, da, n)
                 cb = _col_codes(table, b, db, n)

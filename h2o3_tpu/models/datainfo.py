@@ -13,13 +13,15 @@ validation/test frames (the ``adaptTestForTrain`` contract).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from h2o3_tpu.frame.frame import CAT, Frame, Vec
-from h2o3_tpu.parallel.mesh import row_sharding
+from h2o3_tpu.parallel.mesh import mesh_key, row_sharding
+from h2o3_tpu.utils import flightrec as _fr
 
 MEAN_IMPUTATION = "mean_imputation"
 SKIP = "skip"
@@ -213,108 +215,132 @@ class DataInfo:
             names.append("Intercept")
         return names
 
-    @jax.named_scope("ph_std")  # names the operations where a caller traces it
     def transform(self, frame: Frame):
         """Build the (npad, p) float32 design matrix on device, plus a row
-        validity mask folding in padding and (if skip-handling) NA rows."""
-        cols = []
-        valid = frame.row_mask()
-        for c in self.columns:
-            if c.pair is not None:
-                col, valid = self._transform_interaction(frame, c, valid)
-                cols.append(col)
-                continue
-            v = frame.vec(c.name)
-            if c.kind == "hash":
-                buckets = self._hashed_codes(v, c)
-                if self.missing_handling == SKIP:
-                    valid = valid * (buckets >= 0).astype(jnp.float32)
-                # use_all_factor_levels=False drops bucket 0 (reference),
-                # exactly like the cat path — see the hash_buckets field doc
-                cols.append(
-                    _expand_cat(
-                        buckets, self.hash_buckets, c.width,
-                        self.use_all_factor_levels,
-                    )
-                )
-            elif c.kind == "cat":
-                codes = _adapt_codes(v, c.domain)
-                if self.missing_handling == SKIP:
-                    valid = valid * (codes >= 0).astype(jnp.float32)
-                cols.append(_expand_cat(codes, len(c.domain), c.width, self.use_all_factor_levels))
-            else:
-                data = v.data
-                isna = jnp.isnan(data)
-                if self.missing_handling == SKIP:
-                    valid = valid * (~isna).astype(jnp.float32)
-                x = jnp.where(isna, c.mean, data)
-                if self.standardize:
-                    x = (x - c.mean) / c.sigma
-                elif self.missing_handling == SKIP:
-                    x = jnp.where(isna, 0.0, x)
-                cols.append(x[:, None])
-        if self.add_intercept:
-            cols.append(jnp.ones((frame.npad, 1), jnp.float32))
-        X = jnp.concatenate(cols, axis=1)
-        X = jax.device_put(X, row_sharding())
-        # zero out invalid rows so they contribute nothing to reductions
-        X = X * valid[:, None]
-        return X, valid
+        validity mask folding in padding and (if skip-handling) NA rows.
 
-    def _hashed_codes(self, v: Vec, c: ColumnSpec):
-        """Device bucket codes for a hashed column, LUT-cached per column
-        (most-recent domain) so steady-state scoring never re-pays the
+        One dispatch (``dispatch:design``): the whole expansion is the traced
+        program :func:`_design`, keyed by the columns' structure. The column
+        arrays, the training statistics and the code LUTs are its operands,
+        so a second frame of the same structure compiles nothing."""
+        cols, operands = [], []
+        stats = np.zeros((max(1, len(self.columns)), 4), np.float32)
+        for i, c in enumerate(self.columns):
+            stats[i, :2] = c.mean, c.sigma
+            if c.pair_means is not None:
+                stats[i, 2:] = c.pair_means
+            if c.pair_domains is not None:  # cat x cat combined factor
+                da, db = c.pair_domains
+                cols.append(("cat_cat", len(c.domain), c.width, len(db)))
+                operands.append((
+                    _codes_operands(frame.vec(c.pair[0]), da),
+                    _codes_operands(frame.vec(c.pair[1]), db),
+                ))
+            elif c.pair is not None and c.kind == "num":
+                cols.append(("num_num",))
+                operands.append(
+                    (frame.vec(c.pair[0]).data, frame.vec(c.pair[1]).data))
+            elif c.pair is not None:  # onehot(cat) * numeric
+                cols.append(("cat_num", len(c.domain), c.width))
+                operands.append((
+                    _codes_operands(frame.vec(c.pair[0]), c.domain),
+                    frame.vec(c.pair[1]).data,
+                ))
+            elif c.kind == "hash":
+                v = frame.vec(c.name)
+                cols.append(("cat", self.hash_buckets, c.width))
+                operands.append((v.data, self._hash_lut_for(v, c)))
+            elif c.kind == "cat":
+                cols.append(("cat", len(c.domain), c.width))
+                operands.append(_codes_operands(frame.vec(c.name), c.domain))
+            else:
+                cols.append(("num",))
+                operands.append((frame.vec(c.name).data,))
+        plan = (
+            tuple(cols), self.standardize, self.missing_handling == SKIP,
+            self.use_all_factor_levels, self.add_intercept, frame.npad,
+            mesh_key(),
+        )
+        with _fr.dispatch("design", rows=frame.npad, cols=self.ncols_expanded):
+            return _design(plan, np.int32(frame.nrow), stats, operands)
+
+    def _hash_lut_for(self, v: Vec, c: ColumnSpec):
+        """Device LUT (level code -> bucket) of a hashed column, cached per
+        column (most-recent domain) so steady-state scoring never re-pays the
         O(cardinality) host hash loop and the cache stays bounded by the
         model's column count."""
         hit = self._hash_luts.get(c.name)
         if hit is not None and hit[0] is v.domain:
-            lut_dev = hit[1]
-        else:
-            lut_dev = _hash_lut(v.domain or (), c.name, self.hash_buckets)
-            self._hash_luts[c.name] = (v.domain, lut_dev)
-        return jnp.where(v.data >= 0, lut_dev[jnp.clip(v.data, 0)], -1)
+            return hit[1]
+        lut_dev = _hash_lut(v.domain or (), c.name, self.hash_buckets)
+        self._hash_luts[c.name] = (v.domain, lut_dev)
+        return lut_dev
 
-    def _transform_interaction(self, frame: Frame, c: ColumnSpec, valid):
-        """Interaction block: numeric product or onehot(cat) * numeric.
 
-        NA imputation uses the TRAINING means (c.pair_means) — never the
-        scoring batch's — and missing_handling=SKIP invalidates rows with
-        missing sources exactly like the base columns do.
-        """
-        if c.pair_domains is not None:  # cat x cat combined factor
-            va, vb = frame.vec(c.pair[0]), frame.vec(c.pair[1])
-            da, db = c.pair_domains
+@partial(jax.jit, static_argnums=0)
+@jax.named_scope("ph_std")  # a traced program: the scope names its operations
+def _design(plan, nrow, stats, operands):
+    """The traced body of :meth:`DataInfo.transform`. ``plan`` is static:
+    each column's kind, cardinality and width, then the DataInfo's flags,
+    ``npad`` and the mesh. ``stats`` holds a row (mean, sigma, pair means)
+    for each column; ``operands`` holds each column's arrays.
+
+    NA imputation uses the TRAINING means, never the scoring batch's, and
+    missing_handling=SKIP invalidates rows with a missing source, in the
+    interaction blocks exactly like the base columns."""
+    col_plans, standardize, skip, use_all, add_intercept, npad, _mesh = plan
+    valid = (jnp.arange(npad) < nrow).astype(jnp.float32)
+    present = jnp.ones(npad, bool)  # rows with no missing source
+    cols = []
+    for i, (cp, ops) in enumerate(zip(col_plans, operands)):
+        mean, sigma = stats[i, 0], stats[i, 1]
+        if cp[0] == "num":
+            data = ops[0]
+            isna = jnp.isnan(data)
+            present &= ~isna
+            x = jnp.where(isna, mean, data)
+            if standardize:
+                x = (x - mean) / sigma
+            elif skip:
+                x = jnp.where(isna, 0.0, x)
+            cols.append(x[:, None])
+        elif cp[0] == "num_num":
+            xa, xb = ops
+            present &= ~(jnp.isnan(xa) | jnp.isnan(xb))
+            x = jnp.nan_to_num(xa, nan=stats[i, 2]) * jnp.nan_to_num(
+                xb, nan=stats[i, 3])
+            if standardize:
+                x = (x - mean) / sigma
+            cols.append(x[:, None])
+        elif cp[0] == "cat_cat":
             # int32 BEFORE the product: enum codes may be stored int8/int16
             # (narrowest-dtype compression) and ca*len(db)+cb overflows there
-            ca = _adapt_codes(va, da).astype(jnp.int32)
-            cb = _adapt_codes(vb, db).astype(jnp.int32)
-            codes = jnp.where((ca >= 0) & (cb >= 0), ca * len(db) + cb, -1)
-            if self.missing_handling == SKIP:
-                valid = valid * (codes >= 0).astype(jnp.float32)
-            oh = _expand_cat(
-                codes, len(c.domain), c.width, self.use_all_factor_levels
-            )
-            return oh, valid
-        if c.kind == "num":
-            va, vb = frame.vec(c.pair[0]), frame.vec(c.pair[1])
-            ma, mb = c.pair_means or (0.0, 0.0)
-            na = jnp.isnan(va.data) | jnp.isnan(vb.data)
-            if self.missing_handling == SKIP:
-                valid = valid * (~na).astype(jnp.float32)
-            xa = jnp.nan_to_num(va.data, nan=ma)
-            xb = jnp.nan_to_num(vb.data, nan=mb)
-            x = xa * xb
-            if self.standardize:
-                x = (x - c.mean) / c.sigma
-            return x[:, None], valid
-        cv, nv = frame.vec(c.pair[0]), frame.vec(c.pair[1])
-        codes = _adapt_codes(cv, c.domain)
-        if self.missing_handling == SKIP:
-            valid = valid * (codes >= 0).astype(jnp.float32)
-            valid = valid * (~jnp.isnan(nv.data)).astype(jnp.float32)
-        oh = _expand_cat(codes, len(c.domain), c.width, self.use_all_factor_levels)
-        x = jnp.nan_to_num(nv.data, nan=(c.pair_means or (0.0, 0.0))[1])
-        return oh * x[:, None], valid
+            ca = _codes(ops[0]).astype(jnp.int32)
+            cb = _codes(ops[1]).astype(jnp.int32)
+            codes = jnp.where((ca >= 0) & (cb >= 0), ca * cp[3] + cb, -1)
+            present &= codes >= 0
+            cols.append(_expand_cat(codes, cp[1], cp[2], use_all))
+        elif cp[0] == "cat_num":
+            codes, x = _codes(ops[0]), ops[1]
+            present &= (codes >= 0) & ~jnp.isnan(x)
+            oh = _expand_cat(codes, cp[1], cp[2], use_all)
+            cols.append(oh * jnp.nan_to_num(x, nan=stats[i, 3])[:, None])
+        else:
+            # "cat"; a hashed column is one whose LUT maps levels to buckets.
+            # use_all_factor_levels=False drops level (bucket) 0, the
+            # reference — see the hash_buckets field doc
+            codes = _codes(ops)
+            present &= codes >= 0
+            cols.append(_expand_cat(codes, cp[1], cp[2], use_all))
+    if skip:
+        valid = valid * present.astype(jnp.float32)
+    if add_intercept:
+        cols.append(jnp.ones((npad, 1), jnp.float32))
+    X = jnp.concatenate(cols, axis=1)
+    X = jax.lax.with_sharding_constraint(X, row_sharding())
+    # zero out invalid rows so they contribute nothing to reductions
+    X = X * valid[:, None]
+    return X, jax.lax.with_sharding_constraint(valid, row_sharding())
 
 
 def _hash_lut(domain: tuple[str, ...], col_name: str, n_buckets: int):
@@ -325,7 +351,7 @@ def _hash_lut(domain: tuple[str, ...], col_name: str, n_buckets: int):
     column name so two hashed columns decorrelate. Because the hash sees the
     level STRING, train and scoring frames land in identical buckets with no
     domain adaptation, at any cardinality. One crc32 per LEVEL, so callers
-    must cache per domain (``DataInfo._hashed_codes`` does); NA codes (< 0)
+    must cache per domain (``DataInfo._hash_lut_for`` does); NA codes (< 0)
     stay NA (-1) → all-zero indicator row.
     """
     import zlib
@@ -342,22 +368,36 @@ def _hash_lut(domain: tuple[str, ...], col_name: str, n_buckets: int):
 
 def _hash_codes(v: Vec, col_name: str, n_buckets: int):
     """Uncached convenience wrapper (tests / one-off use)."""
-    lut_dev = _hash_lut(v.domain or (), col_name, n_buckets)
-    return jnp.where(v.data >= 0, lut_dev[jnp.clip(v.data, 0)], -1)
+    return _codes((v.data, _hash_lut(v.domain or (), col_name, n_buckets)))
 
 
-def _adapt_codes(v: Vec, train_domain: tuple[str, ...]):
-    """Remap a categorical Vec's codes onto the training domain — the
-    ``CategoricalWrappedVec`` / ``adaptTestForTrain`` successor. Unseen
-    levels map to NA (-1), matching H2O's default warning path."""
+def _codes_operands(v: Vec, train_domain: tuple[str, ...]) -> tuple:
+    """A categorical Vec's codes on the training domain, as operands of a
+    traced program: ``(codes,)`` where the domains agree, else ``(codes,
+    remap)`` with the host-built LUT from the Vec's levels to the training
+    ones — the ``CategoricalWrappedVec`` / ``adaptTestForTrain`` successor.
+    Unseen levels map to NA (-1), matching H2O's default warning path."""
     if v.domain == train_domain:
-        return v.data
+        return (v.data,)
     lut = {d: i for i, d in enumerate(train_domain)}
     remap = np.full(len(v.domain or ()) + 1, -1, dtype=np.int32)
     for j, d in enumerate(v.domain or ()):
         remap[j] = lut.get(d, -1)
-    remap_dev = jnp.asarray(remap)
-    return jnp.where(v.data >= 0, remap_dev[jnp.clip(v.data, 0)], -1)
+    return (v.data, remap)
+
+
+def _codes(ops):
+    """The codes of :func:`_codes_operands` (or of a hashed column's
+    ``(codes, bucket LUT)``); traceable. NA (< 0) stays NA (-1)."""
+    if len(ops) == 1:
+        return ops[0]
+    data, lut = ops
+    return jnp.where(data >= 0, jnp.asarray(lut)[jnp.clip(data, 0)], -1)
+
+
+def _adapt_codes(v: Vec, train_domain: tuple[str, ...]):
+    """Remap a categorical Vec's codes onto the training domain."""
+    return _codes(_codes_operands(v, train_domain))
 
 
 def _expand_cat(codes, card: int, width: int, use_all: bool):

@@ -512,6 +512,26 @@ def _multinomial_pass(X, Y1h, w, Beta, K, k):
     return G, b, -2.0 * ll
 
 
+def _fam_args(p) -> tuple:
+    """``get_family``'s arguments after the family's name, from the params
+    (hashable: the static key of the family's traced programs)."""
+    return (
+        p.link,
+        float(p.tweedie_variance_power or 1.5),
+        float(p.tweedie_link_power),
+        float(p.theta),
+    )
+
+
+@partial(jax.jit, static_argnames=("family_key", "fam_args"))
+@jax.named_scope("ph_score")  # a traced program: the scope names its operations
+def _linear_mu(X, beta, offset, family_key, fam_args):
+    """Linear predictor and inverse link of the single-vector families."""
+    fam = get_family(family_key, *fam_args)
+    eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
+    return fam.link.inv(eta)
+
+
 @partial(jax.jit, static_argnames=())
 @jax.named_scope("ph_score")
 def _softmax_probs(X, Beta):
@@ -632,21 +652,25 @@ class GLMModel(Model):
         (n, K) for multinomial."""
         di: DataInfo = self.output["datainfo"]
         X, _ = di.transform(frame)
+        raw = self._raw_from_design(
+            X, _offset_col(self.params, frame), frame.nrow)
+        if self.is_classifier and raw.ndim == 1:
+            return jnp.stack([1 - raw, raw], axis=1)
+        return raw
+
+    def _raw_from_design(self, X, offset, nrow: int):
+        """The raw predictions from a design matrix of this model's
+        ``DataInfo`` (the builder hands over the one it fitted on, so the
+        training metrics transform nothing): (n, K) probabilities for
+        multinomial, else ``mu`` — for binomial the second class's
+        probability, which ``_make_metrics`` takes as it is."""
         if self.output.get("multinomial"):
             Beta = jnp.asarray(self.output["beta_multinomial_std"], jnp.float32)
-            return _softmax_probs(X, Beta)[: frame.nrow]
+            return _softmax_probs(X, Beta)[:nrow]
         beta = jnp.asarray(self.output["beta_std"], jnp.float32)
-        fam = self.output["family_obj"]
-        # issued op by op, so the scope names these operations only where a
-        # caller traces this method into a program of its own
-        with jax.named_scope("ph_score"):
-            eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + _offset_col(
-                self.params, frame
-            )
-            mu = fam.link.inv(eta)[: frame.nrow]
-        if self.is_classifier:
-            return jnp.stack([1 - mu, mu], axis=1)
-        return mu
+        return _linear_mu(
+            X, beta, offset, self.output["family"], _fam_args(self.params)
+        )[:nrow]
 
     @property
     def coef(self) -> dict:
@@ -658,6 +682,28 @@ class GLMModel(Model):
     def _distribution_for_metrics(self) -> str:
         fam = self.output["family"]
         return {"poisson": "poisson", "gamma": "gamma"}.get(fam, "gaussian")
+
+
+@jax.jit
+@jax.named_scope("ph_std")
+def _response_lanes(ydata, valid, weights, offset):
+    """The resident fit's row lanes from the frame's device columns: the
+    response with 0 where it is missing (a categorical code < 0, a NaN),
+    the weight ``valid * weights * (1 - missing response)``, the offset,
+    and ``nobs`` — what the streamed path builds in numpy
+    (``GLM._plan_streamed``), with nothing pulled or uploaded."""
+    if jnp.issubdtype(ydata.dtype, jnp.floating):
+        yna = jnp.isnan(ydata)
+        y = jnp.nan_to_num(ydata.astype(jnp.float32), nan=0.0)
+    else:
+        yna = ydata < 0
+        y = jnp.where(yna, 0, ydata).astype(jnp.float32)
+    w = valid
+    if weights is not None:
+        w = w * jnp.nan_to_num(weights)
+    w = w * (1.0 - yna.astype(jnp.float32))  # NA-response rows get weight 0
+    offset = jnp.zeros_like(y) if offset is None else jnp.nan_to_num(offset)
+    return y, w, offset, w.sum()
 
 
 def _offset_col(params, frame: Frame):
@@ -713,15 +759,6 @@ class GLM(ModelBuilder):
                 hash_buckets=int(p.hash_buckets) if p.hash_buckets else None,
             )
 
-            y_np = yv.to_numpy()
-            if yv.is_categorical():
-                y_np = y_np.astype(np.float32)
-                y_np[y_np < 0] = np.nan
-            ybuf = np.zeros(train.npad, np.float32)
-            ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
-            yna = np.zeros(train.npad, np.float32)
-            yna[: train.nrow] = np.isnan(y_np)
-
             # out-of-core streaming (ISSUE 11, frame/chunkstore.py): a design
             # matrix past the HBM window streams as row-block chunks through
             # the per-iteration Gram accumulation (the IRLS Gram is a sum over
@@ -731,22 +768,25 @@ class GLM(ModelBuilder):
             if (family not in ("multinomial", "ordinal")
                     and p.solver.upper().replace("-", "_") not in ("L_BFGS", "LBFGS")
                     and not p.compute_p_values):
-                stream = self._plan_streamed(train, di, p, ybuf, yna)
+                stream = self._plan_streamed(train, di, p, yv)
             if stream is not None:
                 X = stream
                 w = stream.lane("w")
-                y = ybuf
+                y = stream.lane("y")
                 offset = stream.lane("offset")
+                nobs_dev = w.sum()
             else:
+                # resident: nothing n-row-sized is made on the host
                 X, valid_mask = di.transform(train)
-                w = valid_mask
-                if p.weights_column:
-                    w = w * jnp.nan_to_num(train.vec(p.weights_column).data)
-                offset = _offset_col(p, train)
-                w = w * (1.0 - jnp.asarray(yna))  # NA-response rows get weight 0
-                y = jnp.asarray(ybuf)
+                y, w, offset, nobs_dev = _response_lanes(
+                    yv.data, valid_mask,
+                    train.vec(p.weights_column).data
+                    if p.weights_column else None,
+                    train.vec(p.offset_column).data
+                    if p.offset_column else None,
+                )
 
-            nobs = float(np.asarray(w.sum()))
+            nobs = float(np.asarray(nobs_dev))
         job.update(0.05)
 
         from h2o3_tpu.models.model_base import (
@@ -810,18 +850,27 @@ class GLM(ModelBuilder):
             # streamed scoring: never re-materialize the resident design
             model.training_metrics = self._streamed_metrics(model, stream, train)
             stream.close()
-        else:
+        elif family == "ordinal":  # float64 host scoring, from the frame
             model.training_metrics = model._score_metrics(train)
+        else:
+            # from the design matrix the fit ran on: `train` is not
+            # transformed a second time. X lives no longer than this call
+            model.training_metrics = model._score_metrics(
+                train,
+                raw=lambda: model._raw_from_design(X, offset, train.nrow),
+            )
         if valid is not None:
             model.validation_metrics = model._score_metrics(valid)
         return model
 
-    def _plan_streamed(self, train: Frame, di, p: GLMParams, ybuf, yna):
+    def _plan_streamed(self, train: Frame, di, p: GLMParams, yv):
         """ChunkStore with the block-transformed design lanes, or None for
         the resident path. The block transform reuses ``di.transform`` on
         host-block sub-frames — elementwise per row, so each lane equals
         the resident design matrix row-for-row — and the source feature
-        columns then drop to compressed/host residency."""
+        columns then drop to compressed/host residency. The response lanes
+        of a streamed fit are host lanes of the store, built here in numpy;
+        the resident path makes its own on the device."""
         from h2o3_tpu.frame import chunkstore as cs
 
         P = di.ncols_expanded
@@ -829,6 +878,14 @@ class GLM(ModelBuilder):
         if store is None:
             return None
         npad = train.npad
+        y_np = yv.to_numpy()
+        if yv.is_categorical():
+            y_np = y_np.astype(np.float32)
+            y_np[y_np < 0] = np.nan
+        ybuf = np.zeros(npad, np.float32)
+        ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
+        yna = np.zeros(npad, np.float32)
+        yna[: train.nrow] = np.isnan(y_np)
         Log.info(
             f"GLM out-of-core streaming: {store.n_blocks} blocks x "
             f"{store.block_rows} rows, design width {P}"
@@ -868,15 +925,13 @@ class GLM(ModelBuilder):
         from h2o3_tpu.models.model_base import _make_metrics
 
         with _mx.span("model.score_metrics", algo=self.algo):
-            fam = model.output["family_obj"]
             beta = jnp.asarray(model.output["beta_std"], jnp.float32)
             parts = []
             with _mx.span("model.predict_raw"):  # each block ends in a pull
                 for bi, blk in store.stream(("X", "offset")):
-                    eta = jnp.einsum(
-                        "np,p->n", blk["X"], beta, precision=_HI
-                    ) + blk["offset"]
-                    parts.append(np.asarray(fam.link.inv(eta)))
+                    parts.append(np.asarray(_linear_mu(
+                        blk["X"], beta, blk["offset"],
+                        model.output["family"], _fam_args(model.params))))
             mu = np.concatenate(parts)[: frame.nrow]
             raw = np.stack([1 - mu, mu], axis=1) if model.is_classifier else mu
             yh, wh = model._response_and_weights(frame)
@@ -906,12 +961,7 @@ class GLM(ModelBuilder):
 
     def _fit_irls(self, job, X, y, w, offset, di, p: GLMParams, family, nobs,
                   prior=None, response_domain=None):
-        fam_args = (
-            p.link,
-            float(p.tweedie_variance_power or 1.5),
-            float(p.tweedie_link_power),
-            float(p.theta),
-        )
+        fam_args = _fam_args(p)
         fam = get_family(family, *fam_args)
         P = di.ncols_expanded
         icpt = P - 1 if p.intercept else None
@@ -1327,12 +1377,7 @@ class GLM(ModelBuilder):
     def _fit_lbfgs(self, job, X, y, w, offset, di, p: GLMParams, family, nobs):
         from scipy import optimize as spo
 
-        fam_args = (
-            p.link,
-            float(p.tweedie_variance_power or 1.5),
-            float(p.tweedie_link_power),
-            float(p.theta),
-        )
+        fam_args = _fam_args(p)
         if p.compute_p_values:
             raise ValueError("compute_p_values requires solver=IRLSM")
         fam = get_family(family, *fam_args)

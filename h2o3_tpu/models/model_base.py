@@ -23,7 +23,7 @@ import numpy as np
 
 from h2o3_tpu.cluster.job import Job
 from h2o3_tpu.cluster.registry import DKV
-from h2o3_tpu.frame.frame import CAT, Frame, Vec
+from h2o3_tpu.frame.frame import CAT, STR, Frame, Vec
 from h2o3_tpu.models import metrics as MM
 from h2o3_tpu.utils import metrics as _mx
 from h2o3_tpu.utils.log import Log
@@ -187,15 +187,26 @@ class Model:
             return self.training_metrics
         return self._score_metrics(test_data)
 
-    def _response_and_weights(self, frame: Frame):
+    def _response_and_weights(self, frame: Frame, device: bool = False):
+        """The response (classifiers: codes on the model's domain) and the
+        weights of ``frame``'s rows. ``device``: as slices of the frame's
+        resident columns, for the device statistics, where the codes need
+        no remap — a frame with another domain takes the host remap."""
         y_name = self.params.response_column
         yv = frame.vec(y_name)
-        y = yv.to_numpy()
-        if self.is_classifier and yv.is_categorical():
+        remap = (self.is_classifier and yv.is_categorical()
+                 and yv.domain != tuple(self.output["response_domain"]))
+        on_dev = device and not remap and yv.kind != STR
+        if on_dev:
+            y = yv.data[: frame.nrow]
+        elif remap:
             y = _remap_response(yv, self.output["response_domain"])
+        else:
+            y = yv.to_numpy()
         w = None
         if self.params.weights_column:
-            w = frame.vec(self.params.weights_column).to_numpy()
+            wv = frame.vec(self.params.weights_column)
+            w = wv.data[: frame.nrow] if on_dev else wv.to_numpy()
         return y, w
 
     def _device_predictor(self):
@@ -204,12 +215,15 @@ class Model:
         the model has none, or declines for this fit."""
         return getattr(self, "_predict_raw_dev", None)
 
-    def _score_metrics(self, frame: Frame) -> MM.ModelMetrics:
+    def _score_metrics(self, frame: Frame, raw=None) -> MM.ModelMetrics:
         """On an accelerator a model that can predict there hands device
         arrays to ``_make_metrics``: metrics.py then reduces the sufficient
         statistics on the device and KBs come down instead of a prediction
         column. Everywhere else the predictions are pulled and the host path
-        computes the exact float64 summaries."""
+        computes the exact float64 summaries. ``raw``: a builder that still
+        holds what the raw predictions on ``frame`` come from (GLM: the
+        design matrix it fitted on) passes a function that returns them as
+        device arrays, and the frame is not predicted on again."""
         predict_dev = (
             self._device_predictor() if jax.default_backend() != "cpu" else None
         )
@@ -219,11 +233,14 @@ class Model:
             # host: ends in the pull to numpy; device: enqueues, and the
             # metric's pull of its statistics is the sync
             with _mx.span("model.predict_raw"):
-                if predict_dev is None:
+                if raw is not None:
+                    raw = raw() if predict_dev is not None else np.asarray(raw())
+                elif predict_dev is None:
                     raw = np.asarray(self._predict_raw(frame))
                 else:
                     raw = predict_dev(frame)
-            y, w = self._response_and_weights(frame)
+            y, w = self._response_and_weights(
+                frame, device=predict_dev is not None)
             return _make_metrics(self, raw, y, w)
 
     def _distribution_for_metrics(self) -> str:
@@ -266,7 +283,9 @@ def _make_metrics(model: Model, raw: np.ndarray, y: np.ndarray, w) -> MM.ModelMe
         return MM.binomial_metrics(y, raw, w, domain=domain)
     if raw.shape[1] == 2:
         return MM.binomial_metrics(y, raw[:, 1], w, domain=domain)
-    return MM.multinomial_metrics(y.astype(np.int64), raw, w, domain=domain)
+    if isinstance(y, np.ndarray):  # device codes stay as they are stored
+        y = y.astype(np.int64)
+    return MM.multinomial_metrics(y, raw, w, domain=domain)
 
 
 class ModelBuilder:

@@ -617,3 +617,59 @@ def test_score_metrics_routes_through_the_one_base_method(
         "model.score_metrics"]["id"]
     assert got.nobs == cpu.nobs == fr.nrow
     assert got.logloss == pytest.approx(cpu.logloss, rel=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the fit's design matrix reaches the training metrics, and no further
+# (ISSUE 28)
+
+def _metric_values(mm):
+    return {k: v for k, v in mm._v.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
+@pytest.mark.parametrize("family,path", [
+    ("binomial", "host"), ("gaussian", "host"), ("multinomial", "host"),
+    ("poisson", "host"), ("binomial", "device"), ("gaussian", "device"),
+    ("multinomial", "device")])
+def test_training_metrics_from_the_handed_over_design(monkeypatch, family, path):
+    """``train()`` scores the design matrix it fitted on; ``_score_metrics``
+    of the training frame transforms it again. The same numbers, with an
+    offset column where the family has one, on both metric paths."""
+    from h2o3_tpu.utils import flightrec
+
+    _, fr = _family_frame(family)
+    if path == "device":
+        _as_accelerator(monkeypatch)
+    flightrec.reset()
+    m = GLM(family=family, lambda_=1e-4,
+            offset_column=None if family == "multinomial" else "off").train(
+        y="y", training_frame=fr, x=list("abc"))
+    designs = [e for e in flightrec.events(kind="dispatch_end")
+               if e["site"] == "design"]
+    assert len(designs) == 1  # the fit's; the metrics issued none
+    again = m._score_metrics(fr)
+    got, want = _metric_values(m.training_metrics), _metric_values(again)
+    assert got.keys() == want.keys() and got["nobs"] == fr.nrow
+    for k in want:
+        assert got[k] == pytest.approx(want[k], rel=1e-6, abs=1e-9, nan_ok=True), k
+
+
+@pytest.mark.parametrize("family", ["binomial", "multinomial"])
+def test_no_design_matrix_outlives_train(family):
+    """Nothing reachable from a finished model (its ``DataInfo`` is in its
+    output, the model in the DKV) holds the (npad, p) design: after
+    ``train()`` no live device array has its shape."""
+    import gc
+
+    import jax
+
+    _, fr = _family_frame(family, n=1777)
+    m = GLM(family=family, lambda_=1e-4).train(
+        y="y", training_frame=fr, x=list("abc"))
+    P = m.output["datainfo"].ncols_expanded
+    gc.collect()
+    wide = [a.shape for a in jax.live_arrays()
+            if a.ndim == 2 and a.shape[0] == fr.npad and a.shape[1] >= P]
+    assert wide == []
+    assert m.training_metrics.nobs == fr.nrow

@@ -95,6 +95,37 @@ def test_train_leaves_one_tree_rooted_at_train(algo, want):
                                        else "gbm.build_tree") for d in disp)
 
 
+@pytest.mark.parametrize("with_validation", [False, True])
+def test_glm_train_issues_the_design_program_once_for_each_frame(with_validation):
+    """``DataInfo.transform`` is one dispatch (``dispatch:design``): a GLM
+    ``train()`` opens it once, under ``glm.datainfo``, and the training
+    metrics come from that design matrix (no ``dispatch:design`` under
+    ``model.score_metrics``); a validation frame is transformed once, there."""
+    from h2o3_tpu import estimators as E
+
+    est = E.H2OGeneralizedLinearEstimator(family="binomial", lambda_=1e-4)
+    flightrec.reset()
+    before = set(mx._TRACES)
+    est.train(y="y", training_frame=_frame(),
+              validation_frame=_frame(n=300, seed=4) if with_validation else None)
+    (tid,) = set(mx._TRACES) - before
+    by_id = {e["id"]: e for e in mx.trace_events(tid)}
+
+    def ancestors(span_id):
+        while span_id in by_id:
+            yield by_id[span_id]["name"]
+            span_id = by_id[span_id]["parent"]
+
+    designs = [list(ancestors(e["parent"]))
+               for e in flightrec.events(kind="dispatch_end")
+               if e["site"] == "design" and e["trace"] == tid]
+    assert designs[0][0] == "glm.datainfo"
+    under_metrics = [a for a in designs if "model.score_metrics" in a]
+    assert len(designs) == 1 + len(under_metrics)
+    assert len(under_metrics) == (1 if with_validation else 0)
+    assert all(a[0] == "model.predict_raw" for a in under_metrics)
+
+
 def test_bin_frame_span_says_hit_or_miss():
     from h2o3_tpu.models.tree.binning import bin_frame, fit_bins
 
@@ -322,6 +353,22 @@ def _lowered(which):
         return glm._softmax_probs.lower(X, jax.ShapeDtypeStruct((8, 3), f32))
     if which == "binom_stats":
         return MM._binom_device_stats().lower(y, y, w)
+    if which == "linear_mu":
+        return glm._linear_mu.lower(X, beta, off, "binomial",
+                                    ("family_default", 1.5, 1.0, 1e-5))
+    if which == "response_lanes":
+        return glm._response_lanes.lower(
+            jax.ShapeDtypeStruct(y.shape, jnp.int8), w, None, None)
+    if which == "design":
+        from h2o3_tpu.models import datainfo
+        from h2o3_tpu.parallel.mesh import mesh_key
+
+        plan = ((("num",), ("cat", 3, 3)), True, False, True, True,
+                y.shape[0], mesh_key())
+        return datainfo._design.lower(
+            plan, jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((2, 4), f32),
+            [(y,), (jax.ShapeDtypeStruct(y.shape, jnp.int8),)])
     if which == "finish_level":
         n, C, npad = 256, 4, 2
         fn = jax.jit(lambda bins, nid, preds, vi, ok, gain, nw, col, cm: st._finish_level(
@@ -340,6 +387,9 @@ def _lowered(which):
     ("weighted_gram", {"ph_gram"}),
     ("softmax_probs", {"ph_score"}),
     ("binom_stats", {"ph_metric"}),
+    ("linear_mu", {"ph_score"}),
+    ("response_lanes", {"ph_std"}),
+    ("design", {"ph_std"}),
     ("finish_level", {"ph_leaf", "ph_part", "ph_pred"}),
 ])
 def test_phase_scopes_are_metadata_of_the_lowered_program(which, scopes):

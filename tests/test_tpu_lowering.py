@@ -165,6 +165,26 @@ def test_binomial_statistics_program_lowers_for_tpu(rows):
     assert exported.out_avals[0].shape == (4 + 2 * MM._NBUCKETS,)
 
 
+@pytest.mark.parametrize("standardize", [True, False])
+def test_design_program_lowers_for_tpu(standardize):
+    """``DataInfo.transform``'s one program at ``glm_higgs``'s shape: 28
+    numeric columns and the intercept over the 6,029,312 padded rows."""
+    from h2o3_tpu.models import datainfo
+    from h2o3_tpu.parallel.mesh import mesh_key
+
+    rows, cols = 6_029_312, 28
+    plan = ((("num",),) * cols, standardize, False, False, True, rows,
+            mesh_key())
+    lane = jax.ShapeDtypeStruct((rows,), jnp.float32)
+    exported = jax.export.export(
+        jax.jit(functools.partial(datainfo._design.__wrapped__, plan)),
+        platforms=["tpu"],
+    )(jax.ShapeDtypeStruct((), jnp.int32),
+      jax.ShapeDtypeStruct((cols, 4), jnp.float32), [(lane,)] * cols)
+    X, valid = exported.out_avals
+    assert X.shape == (rows, cols + 1) and valid.shape == (rows,)
+
+
 # ---------------------------------------------------------------------------
 # compile-cache placement and the smoke's refusal, in fresh interpreters
 
