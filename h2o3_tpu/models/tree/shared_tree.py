@@ -146,6 +146,37 @@ _COLS_BUNDLED = _metrics.counter(
     "feature columns removed from the histogram C dimension by exclusive "
     "feature bundling, per build", always=True)
 
+# Partition passes (ISSUE 29): one for every tree level a program executes —
+# ``_partition_update`` is the partition of every builder and the replay op.
+# A pass traced into a tree program is tallied at trace time and replayed at
+# each dispatch like the byte counters (``_run_counted``: the saturated
+# region by its executed iterations); a direct call counts itself; a program
+# dispatched outside ``_run_counted`` (``replay_batch``, uplift, the REST
+# scorer) counts its levels where it dispatches. ``path`` names the
+# formulation: one, ``dense`` — no per-row gather at any frontier width.
+_PART_LEVELS = _metrics.counter(
+    "tree_partition_levels_total",
+    "partition / replay passes over the row lanes executed by tree "
+    "programs, by formulation", always=True)
+
+
+def count_partition_levels(n: int) -> None:
+    """``n`` partition passes of a program dispatched outside
+    :func:`_run_counted`."""
+    _PART_LEVELS.inc(n, path="dense")
+
+
+def _count_partition_level(nid) -> None:
+    """One pass: into the dispatcher's tally while a program is traced,
+    straight into the counter for a direct call."""
+    if isinstance(nid, jax.core.Tracer):
+        from h2o3_tpu.ops.collectives import record_collective
+
+        record_collective("part/dense", 1)
+    else:
+        count_partition_levels(1)
+
+
 # program-key registry + per-program collective tallies: _run_counted
 # captures a program's ((phase, lane, group) -> bytes) tally during its
 # first (tracing) dispatch and replays it on every later one.
@@ -209,6 +240,8 @@ def _run_counted(fn, args, mult: int = 1, sat_from=None):
             continue
         if ph.startswith("hbm/"):
             _HIST_HBM_BYTES.inc(b * m, path=ph[4:])
+        elif ph.startswith("part/"):
+            _PART_LEVELS.inc(b * m, path=ph[5:])
         else:
             _COLL_BYTES.inc(b * m, phase=ph)
             _COLL_BYTES.inc(b * m, phase=ph, lane=lane)
@@ -830,27 +863,139 @@ def _split_scan_sharded(
 # ---------------------------------------------------------------------------
 # partition update (DecidedNode re-labeling + leaf retirement)
 # — also the prediction-replay op, so it keeps its own jit wrapper.
+#
+# A TPU executes a per-row gather element by element (86 ms to fetch one
+# byte for each of 4M rows, PERF.md §6 PR 29), so the routing is written
+# with none: a row's entries of the level's node tables are ONE contraction
+# of its one-hot node indicator with the tables, and the split column's
+# code is a compare-select-reduce over the C columns. Every per-row
+# intermediate is (n,) or (k, n) with the rows along the lanes; nothing is
+# shaped (n, 1).
 
 
-@jax.jit
-def _partition_update(
-    bins_u8, nid, preds, split_col, split_bin, is_cat, cat_mask, na_left, leaf_now, leaf_val, child_base
+def _byte_lanes(word, n_bytes: int):
+    """The low ``n_bytes`` bytes of an int32 table (n_pad,), as
+    (n_bytes, n_pad) bfloat16 lanes (0..255 is exact there)."""
+    u = jax.lax.bitcast_convert_type(word.astype(jnp.int32), jnp.uint32)
+    return jnp.stack(
+        [((u >> (8 * i)) & 255).astype(jnp.bfloat16) for i in range(n_bytes)])
+
+
+def _from_bytes(rows):
+    """Inverse of :func:`_byte_lanes` on the contracted (k, n) int32 rows."""
+    word = rows[0]
+    for i in range(1, rows.shape[0]):
+        word = word | (rows[i] << (8 * i))
+    return word
+
+
+def _n_bytes(max_value: int) -> int:
+    return max(1, (int(max_value).bit_length() + 7) // 8)
+
+
+@partial(jax.jit, static_argnames=("any_cat",))
+def _partition_dense(
+    bins_u8, nid, preds, split_col, split_bin, is_cat, cat_mask, na_left,
+    leaf_now, leaf_val, child_base, *, any_cat: bool,
 ):
+    n, C = bins_u8.shape
+    n_pad = split_col.shape[0]
+    i32, bf16 = jnp.int32, jnp.bfloat16
     active = nid >= 0
     node = jnp.where(active, nid, 0)
-    col = split_col[node]
-    b = jnp.take_along_axis(bins_u8, col[:, None].astype(jnp.int32), axis=1).squeeze(1).astype(jnp.int32)
-    go_left = jnp.where(
-        b == 0,
-        na_left[node],
-        jnp.where(is_cat[node], cat_mask[node, b], b <= split_bin[node]),
+
+    # the node tables as (k, n_pad) bfloat16 lanes, every entry an integer
+    # in 0..256 (exact in bfloat16): three flags, the threshold + 1 (codes
+    # are 0..255, so a threshold outside [-1, 255] routes as its clip), and
+    # the bytes of the column, the child base and the leaf value's bits.
+    # Their widths come from the static shapes: a column is < C, a child
+    # id < 2 * n_pad.
+    flags = (na_left.astype(i32) | (is_cat.astype(i32) << 1)
+             | (leaf_now.astype(i32) << 2))
+    tables = [
+        flags.astype(bf16)[None],
+        (jnp.clip(split_bin.astype(i32), -1, 255) + 1).astype(bf16)[None],
+        _byte_lanes(split_col, _n_bytes(C - 1)),
+        _byte_lanes(child_base, _n_bytes(2 * n_pad - 1)),
+        _byte_lanes(
+            jax.lax.bitcast_convert_type(leaf_val.astype(jnp.float32), i32), 4),
+    ]
+    if any_cat:
+        # the membership mask, eight bins to a byte: (ceil(B / 8), n_pad)
+        nb_mask = -(-cat_mask.shape[1] // 8)
+        bits = jnp.pad(
+            cat_mask, ((0, 0), (0, 8 * nb_mask - cat_mask.shape[1]))
+        ).reshape(n_pad, nb_mask, 8).astype(i32)
+        tables.append(
+            (bits << jnp.arange(8, dtype=i32)).sum(axis=2).T.astype(bf16))
+
+    # one 0/1 operand and one nonzero product a row: the f32 accumulator
+    # holds each table entry exactly. XLA fuses the indicator into the
+    # contraction; (n_pad, n) is never materialised.
+    hot = (node[None, :] == jnp.arange(n_pad, dtype=i32)[:, None]).astype(bf16)
+    rows = jax.lax.dot_general(
+        jnp.concatenate(tables), hot, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(i32)  # (k, n)
+    row_flags, thr, col, child, leaf, *mask = jnp.split(
+        rows, np.cumsum([t.shape[0] for t in tables])[:-1].tolist())
+    row_flags, thr = row_flags[0], thr[0] - 1
+    col, child = _from_bytes(col), _from_bytes(child)
+    leaf = jax.lax.bitcast_convert_type(_from_bytes(leaf), jnp.float32)
+
+    # the split column's code: one pass over the codes
+    b = jnp.sum(
+        jnp.where(jax.lax.broadcast_in_dim(col, (n, C), (0,))
+                  == jnp.arange(C, dtype=i32)[None, :],
+                  bins_u8.astype(i32), 0),
+        axis=1,
     )
-    child = child_base[node] + jnp.where(go_left, 0, 1)
-    retired = leaf_now[node]
-    new_nid = jnp.where(active, jnp.where(retired, -1, child), -1)
+    go_left = b <= thr
+    if any_cat:
+        byte = jnp.sum(
+            jnp.where((b >> 3)[None, :] == jnp.arange(nb_mask, dtype=i32)[:, None],
+                      mask[0], 0),
+            axis=0,
+        )
+        go_left = jnp.where(
+            (row_flags & 2) != 0, ((byte >> (b & 7)) & 1) != 0, go_left)
+    go_left = jnp.where(b == 0, (row_flags & 1) != 0, go_left)
+    retired = (row_flags & 4) != 0
+    new_nid = jnp.where(
+        active, jnp.where(retired, -1, child + jnp.where(go_left, 0, 1)), -1)
     with jax.named_scope("ph_pred"):  # the prediction update, under ph_part
-        new_preds = preds + jnp.where(active & retired, leaf_val[node], 0.0)
-    return new_nid.astype(jnp.int32), new_preds
+        new_preds = preds + jnp.where(active & retired, leaf, 0.0)
+    # The barrier keeps the node ids a 1-D lane. The histogram kernel takes
+    # them as an (n, 1) operand in (8, 128) tiles — 128 lanes a row — and
+    # XLA's layout assignment otherwise carries that tiling back through
+    # every elementwise producer: the selects above and the next level's
+    # pair bookkeeping then run on 2 GB arrays. Behind the barrier the one
+    # relayout copy sits in front of the kernel and the rest stays on (n,)
+    # lanes (a layout constraint does the same on one device, but the SPMD
+    # partitioner gathers its operand across a row-sharded mesh).
+    new_nid = jax.lax.optimization_barrier(new_nid.astype(jnp.int32))
+    return new_nid, new_preds
+
+
+def _partition_update(
+    bins_u8, nid, preds, split_col, split_bin, is_cat, cat_mask, na_left,
+    leaf_now, leaf_val, child_base, any_cat: bool | None = None,
+):
+    """Route every row one level down and retire the rows whose node went
+    leaf (``genmodel.goes_left`` is the host twin of the rule).
+
+    ``any_cat`` says whether a node of the level may split on a categorical
+    column; False drops the membership-mask term from the program. Callers
+    pass what they know statically (the builders' ``cat_cols``); left None
+    it is read off a host-resident ``is_cat`` and assumed otherwise.
+    """
+    if any_cat is None:
+        any_cat = bool(is_cat.any()) if isinstance(is_cat, np.ndarray) else True
+    _count_partition_level(nid)
+    return _partition_dense(
+        bins_u8, nid, preds, split_col, split_bin, is_cat, cat_mask, na_left,
+        leaf_now, leaf_val, child_base, any_cat=any_cat,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -907,13 +1052,15 @@ def _finish_level(
     bins_u8, nid, preds, varimp, ok, gain, node_w, node_wy, node_wh,
     split_col, split_bin, is_cat_n, cat_mask, na_left,
     learn_rate, max_abs_leaf, n_pad, node_lo=None, node_hi=None,
-    reg_lambda=None, reg_alpha=None,
+    reg_lambda=None, reg_alpha=None, any_cat: bool = True,
 ):
     """Shared tail of every level: leaf decision, child-id assignment,
     varimp scatter, partition update, and the replayable record.
 
     ``node_lo``/``node_hi`` (monotone-constraint bound state) clamp leaf
     values when given; None leaves the unconstrained trace byte-identical.
+    ``any_cat`` False (a frame with no categorical column, or the all-leaf
+    terminal level) keeps the membership-mask term out of the partition.
     """
     with jax.named_scope("ph_leaf"):
         leaf_now, leaf_val, child_base, cs, n_split, record = _leaf_decide(
@@ -929,7 +1076,7 @@ def _finish_level(
     with jax.named_scope("ph_part"):
         nid, preds = _partition_update(
             bins_u8, nid, preds, split_col, split_bin, is_cat_n, cat_mask,
-            na_left, leaf_now, leaf_val, child_base,
+            na_left, leaf_now, leaf_val, child_base, any_cat=any_cat,
         )
     return nid, preds, varimp, n_split, record, cs
 
@@ -1071,7 +1218,7 @@ def _level_core(
         sp["node_w"], sp["node_wy"], sp["node_wh"],
         sp["col"], sp["split_bin"], sp["is_cat"], sp["cat_mask"], sp["na_left"],
         learn_rate, max_abs_leaf, n_pad, node_lo=node_lo, node_hi=node_hi,
-        reg_lambda=rl, reg_alpha=ra,
+        reg_lambda=rl, reg_alpha=ra, any_cat=bool(cat_cols),
     )
 
     half = n_pad_next // 2
@@ -1113,7 +1260,7 @@ def _force_leaf_from_stats(
         node_w, node_wy, node_wh, zi, zi, jnp.zeros(n_pad, bool),
         jnp.zeros((n_pad, n_bins), bool), jnp.zeros(n_pad, bool),
         learn_rate, max_abs_leaf, n_pad, node_lo=node_lo, node_hi=node_hi,
-        reg_lambda=rl, reg_alpha=ra,
+        reg_lambda=rl, reg_alpha=ra, any_cat=False,
     )
     return nid, preds, varimp, n_split, record
 
@@ -1722,6 +1869,7 @@ def _level_step_mono_fn(
         split_col, split_bin, is_cat_n, cat_mask, na_left,
         learn_rate, max_abs_leaf, n_pad, node_lo=node_lo, node_hi=node_hi,
         reg_lambda=rl, reg_alpha=ra,
+        any_cat=bool(cat_cols) and not force_leaf,
     )
     # child bounds scatter: left child at child_base, right at child_base+1
     new_lo, new_hi = _child_bounds(
@@ -2157,6 +2305,8 @@ def replay_batch(bins_u8, stacked, preds):
         # chunk's build without copying the running prediction
         prog = jax.jit(run, donate_argnums=(2,))
         _STEP_CACHE[key] = prog
+    if n_levels:
+        count_partition_levels(n_levels * stacked[0]["split_col"].shape[0])
     return prog(bins_u8, stacked, preds)
 
 

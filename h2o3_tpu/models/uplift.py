@@ -34,6 +34,7 @@ from h2o3_tpu.models.tree.shared_tree import (
     Tree,
     TreeLevel,
     _partition_update,
+    count_partition_levels,
 )
 from h2o3_tpu.ops.histogram import histogram_in_jit
 from h2o3_tpu.utils.log import Log
@@ -294,6 +295,7 @@ def _build_uplift_tree(bins_u8, wt, y, wc, *, n_bins, is_cat_cols, max_depth,
     tree = Tree()
     if use_fused_trees(max_depth):
         prog = _uplift_tree_program(max_depth, n_bins, node_cap, metric)
+        count_partition_levels(max_depth + 1)
         _, preds, varimp, records = prog(
             bins_u8, preds, varimp, wt, wyt, wc, wyc, key, is_cat_dev,
             jnp.float32(min_rows), jnp.float32(min_split_improvement),
@@ -312,6 +314,7 @@ def _build_uplift_tree(bins_u8, wt, y, wc, *, n_bins, is_cat_cols, max_depth,
         n_pad_next = min(2 * n_pad, node_cap)
         force_leaf = depth == max_depth
         step = _uplift_level(n_pad, n_pad_next, n_bins, force_leaf, metric)
+        count_partition_levels(1)
         nid, preds, varimp, n_split, rec = step(
             bins_u8, nid, preds, varimp, wt, wyt, wc, wyc,
             jax.random.fold_in(key, depth), is_cat_dev,

@@ -400,6 +400,10 @@ class BatchScorer:
             head = np.float32(max(out["ntrees_actual"], 1))
             kind = "drf"
         self._host_args = {"groups": groups, "head": head}
+        # partition passes a dispatch runs: every recorded level of every tree
+        self._part_levels = sum(
+            len(stacked) * len(stacked[0]["split_col"])
+            for gk in groups for stacked in gk if stacked)
         self._struct = (
             kind, mode, self._K,
             tuple(tuple(len(s) for s in gk) for gk in groups),
@@ -594,6 +598,7 @@ class BatchScorer:
 
     def _score_tree(self, cols, n: int, dev) -> dict:
         from h2o3_tpu.models.tree.binning import bin_frame
+        from h2o3_tpu.models.tree.shared_tree import count_partition_levels
 
         spec = self._spec
         b = bucket_batch_rows(n)
@@ -616,6 +621,7 @@ class BatchScorer:
         _note_shapes((self._struct, bins.shape,
                       _group_shapes(self._host_args["groups"])))
         prog = _tree_program(self._struct)
+        count_partition_levels(self._part_levels)
         raw = np.asarray(jax.device_get(
             prog(bins, dev["groups"], dev["head"])))[:n]
         if not self.model.is_classifier:

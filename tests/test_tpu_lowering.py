@@ -185,6 +185,37 @@ def test_design_program_lowers_for_tpu(standardize):
     assert X.shape == (rows, cols + 1) and valid.shape == (rows,)
 
 
+@pytest.mark.parametrize("n_pad,any_cat", [
+    (1, False), (64, False), (64, True), (2048, False), (2048, True)])
+def test_partition_lowers_for_tpu_with_no_row_gather(n_pad, any_cat):
+    """``_partition_update`` at the headline shape (ISSUE 29): a TPU runs a
+    per-row gather element by element, and an ``(n, 1)`` row lane is tiled to
+    128 lanes a row, so the partition lowers with neither — at the cells'
+    widest frontier (64) and at ``node_cap`` (2048) alike: one formulation,
+    no crossover."""
+    import re
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    module = _export_tpu(
+        functools.partial(st._partition_update, any_cat=any_cat),
+        S((ROWS, COLS), jnp.uint8), S((ROWS,), jnp.int32),
+        S((ROWS,), jnp.float32), S((n_pad,), jnp.int32),
+        S((n_pad,), jnp.int32), S((n_pad,), jnp.bool_),
+        S((n_pad, BINS), jnp.bool_), S((n_pad,), jnp.bool_),
+        S((n_pad,), jnp.bool_), S((n_pad,), jnp.float32),
+        S((n_pad,), jnp.int32))
+    gathers = [ln for ln in module.splitlines() if "stablehlo.gather" in ln
+               or "stablehlo.dynamic_gather" in ln]
+    assert not [g for g in gathers if f"tensor<{ROWS}" in g.split("->")[-1]], gathers
+    assert not re.search(rf"tensor<{ROWS}x1x", module)  # no (ROWS, 1) tensor
+    assert "stablehlo.dot_general" in module  # the node tables: one contraction
+    # the node ids leave behind a barrier (the histogram kernel's (n, 1)
+    # operand must not carry its tiling back up the program)
+    assert "stablehlo.optimization_barrier" in module
+
+
 # ---------------------------------------------------------------------------
 # compile-cache placement and the smoke's refusal, in fresh interpreters
 
