@@ -721,7 +721,7 @@ def _phase_headline() -> dict:
 
     reset_build_stats()
     _coll_phases = ("hist_reduce", "winner_gather")
-    _hbm_paths = ("fused", "pallas_unfused", "dense", "fused_via_dense")
+    _hbm_paths = ("pallas_unfused", "dense")
     coll_before = {
         ph: _mx.counter_value("tree_collective_bytes_total", phase=ph)
         for ph in _coll_phases
@@ -786,9 +786,7 @@ def _phase_headline() -> dict:
             ph: round(v, 1) for ph, v in coll_bytes.items()
         },
         # modeled hist+split HBM traffic (traced-structure tally,
-        # tree_hist_hbm_bytes_total): the fused Pallas pipeline's
-        # acceptance metric — a fused run must undercut the
-        # H2O3_TPU_SPLIT_FUSE=0 control >= 2x at the same shape
+        # tree_hist_hbm_bytes_total)
         "hist_hbm_bytes_per_tree": round(
             sum(hbm_bytes.values()) / max(stats["trees_built"], 1), 1
         ),

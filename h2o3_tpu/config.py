@@ -36,23 +36,8 @@ _KNOBS: dict[str, tuple[str, str]] = {
     "H2O3_TPU_HIST_SUBTRACT": (
         "1", "fused tree builder: build lighter child's histogram, derive "
         "sibling by parent subtraction (0 = direct per-node histograms)"),
-    "H2O3_TPU_SPLIT_FUSE": (
-        "auto", "fused Pallas histogram→split pipeline: the histogram kernel "
-                "emits its native VMEM tile layout (no HBM unscramble "
-                "passes), the cross-device reduce-scatter ships whole column "
-                "tiles, and a Pallas split-scan kernel consumes the tiles "
-                "block-by-block in VMEM so only per-(node,col) winner "
-                "candidates reach HBM. The split kernel is INTERPRET-ONLY: "
-                "Mosaic refuses it (cumsum, N-D gather — "
-                "tests/test_tpu_lowering.py), so 'auto' = OFF on every "
-                "backend and the default lane is the dense histogram "
-                "(Pallas on TPU, scatter on CPU) + the XLA split scan, "
-                "column-sharded on >1 device. '1' forces the fused pipeline "
-                "where the kernels can run — the Pallas interpreter on CPU, "
-                "the CI/parity lane; on a TPU it fails at trace time. '0' = "
-                "off. See the docs/MIGRATION.md fallback matrix"),
     "H2O3_TPU_PALLAS_TILES": (
-        "", "Pallas histogram/split kernel tile sizes as 'ROW,COL,NODE' "
+        "", "Pallas histogram kernel tile sizes as 'ROW,COL,NODE' "
             "(e.g. '512,8,64' — the built-in defaults). Tiles are a static "
             "compile key: every setting gets its own executable, so the "
             "tile sweep (tools/bench_kernel_sweep.py) varies them via the "
@@ -219,14 +204,6 @@ _KNOBS: dict[str, tuple[str, str]] = {
         "", "extra Host header names accepted for state-changing REST "
         "requests (comma list; '*' disables the CSRF/rebinding guard)"),
     "H2O3_TPU_LOG_LEVEL": ("INFO", "default log level"),
-    "H2O3_TPU_BIN_ADAPT": (
-        "0", "per-level bin coarsening in the fused tree builder (numeric "
-             "frames): depth>=3 halves data bins per level, floor 63 — "
-             "DHistogram's per-level re-binning analog. Off by default: "
-             "measured 5% SLOWER on TPU v5e at 1M x 28 depth 6 (2.42 vs "
-             "2.55 trees/sec, BENCH_builder_20260731T010117Z*) — the extra "
-             "full-matrix coarsen copies outweigh the smaller histograms at "
-             "the subtraction path's already-reduced node counts"),
     "H2O3_TPU_TREE_GOSS": (
         "", "gradient-based one-side sampling for tree builds (arXiv:"
             "1706.08359, ISSUE 16): 'a,b' keeps the top-a fraction of rows "

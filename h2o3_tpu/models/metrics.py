@@ -184,6 +184,16 @@ def binomial_metrics(
     f1 = table["f1"]
     best = int(np.nanargmax(f1)) if not np.all(np.isnan(f1)) else 0
     best_thr = float(thresholds[best])
+    # The table's thresholds are quantiles of the scores, so for a model
+    # with tied scores (every tree ensemble) the max-F1 one IS a score, and
+    # ``p >= thr`` then turns on the last bit of p for that whole tie group:
+    # a scorer in another precision (the exported MOJO's float64, the native
+    # runtime, another backend) labelled the group the other way. The labels'
+    # threshold is therefore carried half-way down to the next score below:
+    # the same rows of this frame at or over it, and none within rounding.
+    below = p[p < best_thr]
+    if below.size:
+        best_thr = float(0.5 * (best_thr + below.max()))
     cm = _confusion(y, p, w, best_thr)
 
     mx = {}

@@ -199,8 +199,7 @@ def fit_bins_for(params, frame: Frame, cols: list[str]) -> BinSpec:
     if getattr(params, "nbins_top_level", 1024) != 1024:
         Log.warn(
             "nbins_top_level has no effect: bins are static quantiles fit "
-            "once (upstream re-bins per level); tune nbins / nbins_cats, or "
-            "the H2O3_TPU_BIN_ADAPT env knob for per-level coarsening")
+            "once (upstream re-bins per level); tune nbins / nbins_cats")
     return fit_bins(
         frame, cols, nbins=params.nbins,
         seed=abs(params.seed) or 7,

@@ -54,8 +54,7 @@ class SharedTreeParams(CommonParams):
     nbins_cats: int = 1024
     # accepted for surface parity; upstream starts each tree at
     # nbins_top_level bins and halves per level down to nbins — the static
-    # quantile design bins ONCE, so this knob has no effect here (the
-    # H2O3_TPU_BIN_ADAPT env var is the per-level coarsening analog)
+    # quantile design bins ONCE, so this knob has no effect here
     nbins_top_level: int = 1024
     min_split_improvement: float = 1e-5
     sample_rate: float = 1.0
@@ -543,8 +542,8 @@ class GBM(ModelBuilder):
         else:
             spec = fit_bins_for(p, train, self._x)
 
-        # monotone constraints resolve BEFORE the lane gates: both the
-        # streamed and the scanned/fused lanes now accept them (ISSUE 15)
+        # monotone constraints resolve BEFORE the lane gates: the streamed
+        # lane and the per-level loop accept them
         mono_vec = None
         if p.monotone_constraints:
             if dist not in ("gaussian", "bernoulli", "tweedie", "quantile"):
@@ -571,28 +570,24 @@ class GBM(ModelBuilder):
 
         # leaf-wise growth (ISSUE 16): lossguide rations each level's splits
         # by gain rank against the remaining max_leaves budget; the budget
-        # rides the fused whole-tree program's level carry, so the policy is
-        # fused-lane-only (the per-level host loop never sees it)
+        # rides the whole-tree program's level carry, so the policy is
+        # whole-tree-only (the per-level host loops, which every monotone
+        # build takes, never see it)
         if p.grow_policy not in ("depthwise", "lossguide"):
             raise ValueError(
                 f"grow_policy must be 'depthwise' or 'lossguide', got {p.grow_policy!r}"
             )
+        from h2o3_tpu.models.tree.shared_tree import use_fused_trees
+
         max_leaves = 0
         if p.grow_policy == "lossguide":
-            from h2o3_tpu.models.tree.shared_tree import (
-                _split_fuse_on as _sf_on,
-                use_fused_trees as _fused_ok,
-            )
-
             if p.max_leaves < 2:
                 raise ValueError("grow_policy=lossguide requires max_leaves >= 2")
-            if not _fused_ok(p.max_depth) or (
-                mono_vec is not None and not _sf_on()
-            ):
+            if not use_fused_trees(p.max_depth) or mono_vec is not None:
                 raise ValueError(
                     "grow_policy=lossguide runs on the fused whole-tree lane "
-                    "(H2O3_TPU_WHOLE_TREE=1 within H2O3_TPU_FUSED_MAX_DEPTH; "
-                    "monotone lossguide additionally needs H2O3_TPU_SPLIT_FUSE)"
+                    "(H2O3_TPU_WHOLE_TREE=1 within H2O3_TPU_FUSED_MAX_DEPTH) "
+                    "and does not combine with monotone_constraints"
                 )
             max_leaves = int(p.max_leaves)
 
@@ -624,36 +619,16 @@ class GBM(ModelBuilder):
         # mutually-exclusive sparse/one-hot columns into shared u8 code
         # columns — the histogram grid accumulates over the bundled Cb < C
         # axis and expands back to real columns right after (split records,
-        # varimp, MOJO and scoring never see bundle space). Fused
-        # whole-tree lanes only; bin-adapt coarsening would scramble bundle
-        # codes, so nonzero shifts (or a streamed build, which returns
-        # above) skip bundling entirely.
+        # varimp, MOJO and scoring never see bundle space). Whole-tree
+        # programs only: a monotone build (per-level loop) or a streamed
+        # build (which returns above) skips bundling entirely.
         efb = bins_b = None
         from h2o3_tpu import config as _config
 
         if _config.get_bool("H2O3_TPU_TREE_EFB"):
-            from h2o3_tpu.models.tree.binning import (
-                bucket_nbins as _bnb,
-                bundle_bins,
-                fit_efb,
-            )
-            from h2o3_tpu.models.tree.shared_tree import (
-                _bin_shifts,
-                _split_fuse_on as _sf_on2,
-                use_fused_trees as _fused_ok2,
-            )
+            from h2o3_tpu.models.tree.binning import bundle_bins, fit_efb
 
-            _cats = tuple(
-                int(i) for i in np.nonzero(np.asarray(spec.is_cat, bool))[0]
-            )
-            if (
-                _fused_ok2(p.max_depth)
-                and (mono_vec is None or _sf_on2())
-                and all(
-                    s == 0
-                    for s in _bin_shifts(p.max_depth, _bnb(n_bins), _cats)
-                )
-            ):
+            if use_fused_trees(p.max_depth) and mono_vec is None:
                 efb = fit_efb(spec, bins, nrow=train.nrow)
                 if efb is not None:
                     bins_b = bundle_bins(efb, bins)
@@ -759,18 +734,10 @@ class GBM(ModelBuilder):
         # — fewer dispatches and host syncs; on the CPU mesh per-level
         # dispatch overhead × levels × trees was ~a third of build wall-clock.
         # H2O3_TPU_WHOLE_TREE=0 restores the per-tree per-level loop.
-        # Monotone builds take the scanned lane when the fused Pallas
-        # pipeline is active (ISSUE 15: the constraint mask runs inside the
-        # split kernel and the bound state rides the fused level carry);
-        # with the fuse gate off they keep the legacy per-level loop
-        # bit-for-bit.
-        from h2o3_tpu.models.tree.shared_tree import (
-            _split_fuse_on,
-            use_fused_trees,
-        )
-
+        # Monotone builds keep the per-level loop (build_tree's mono step
+        # carries the per-node bound state from level to level).
         use_scan = (dist != "multinomial" and use_fused_trees(p.max_depth)
-                    and (mono_vec is None or _split_fuse_on()))
+                    and mono_vec is None)
 
         start_trees = 0
         if prior is not None:
@@ -841,7 +808,6 @@ class GBM(ModelBuilder):
                         col_sample_rate_per_tree=p.col_sample_rate_per_tree,
                         reg_lambda=getattr(p, "reg_lambda", 0.0),
                         reg_alpha=getattr(p, "reg_alpha", 0.0),
-                        monotone=mono_vec,
                         max_leaves=max_leaves,
                         efb=efb,
                         bins_b=bins_b,

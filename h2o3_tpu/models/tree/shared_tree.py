@@ -103,33 +103,18 @@ _COLL_SECONDS = _metrics.counter(
     "calibration microbench), by phase", always=True)
 
 # HBM-traffic model of the histogram+split phases, by pipeline path
-# (``path``: fused = blocked Pallas histogram → Pallas split kernel, no
-# unscramble pass; pallas_unfused = Pallas histogram + two HBM unscramble
+# (``path``: pallas_unfused = Pallas histogram + two HBM unscramble
 # transposes + dense XLA scan; dense = scatter/matmul histogram + dense
-# scan; fused_via_dense = the CPU correctness lane that re-blocks a dense
-# histogram). Same traced-structure tally mechanism as the collective
-# bytes (ops/histogram.record_hbm): one write per materialized
-# intermediate + one read per consumed one, recorded at trace time and
-# replayed per dispatch — so the fused pipeline's "no full-histogram HBM
-# round-trip" claim is a measured artifact number, not prose. Terminal
-# force-leaf levels skip the scan read the model counts: an upper bound,
-# like the saturated-region collective tally.
+# scan; rebin = a binning pass, tallied by binning.bin_frame). Same
+# traced-structure tally mechanism as the collective bytes
+# (ops/histogram.record_hbm): one write per materialized intermediate + one
+# read per consumed one, recorded at trace time and replayed per dispatch.
+# Terminal force-leaf levels skip the scan read the model counts: an upper
+# bound, like the saturated-region collective tally.
 _HIST_HBM_BYTES = _metrics.counter(
     "tree_hist_hbm_bytes_total",
     "modeled per-device HBM bytes moved by the histogram+split phases of "
     "tree builds, by pipeline path", always=True)
-
-# Fallback observability (ISSUE 15): builds that WANT the fused Pallas lane
-# (the knob/backend gate says fuse) but drop to a slow lane for a
-# structural reason. ISSUE 16 closed the last structural reason (uplift's
-# 4-lane scan now runs through the whole-tree fused program); the uplift /
-# mono / cat_sharded reasons stay wired so a future regression of the
-# closure is a counter bump, not an archaeology dig through MIGRATION.md —
-# uplift only tallies on the legacy per-level loop (H2O3_TPU_WHOLE_TREE=0).
-_FUSED_FALLBACKS = _metrics.counter(
-    "tree_fused_fallbacks_total",
-    "tree builds that fell back from the fused Pallas histogram→split lane "
-    "while the fuse gate was ON, by structural reason", always=True)
 
 # Wave-2 arithmetic-reduction observability (ISSUE 16). Rows-sampled is the
 # MODELED kept-row volume of GOSS builds ((a+b) · padded rows · trees —
@@ -546,51 +531,11 @@ def _split_shard_on() -> bool:
     return config.get_bool("H2O3_TPU_SPLIT_SHARD") and n_col_shards() > 1
 
 
-def _split_fuse_on() -> bool:
-    """THE gate for the fused Pallas histogram→split pipeline
-    (``H2O3_TPU_SPLIT_FUSE``). 'auto' (default) = OFF on every backend:
-    ``ops/split_pallas.py`` is interpret-only — Mosaic refuses its body
-    (``cumsum`` over a middle axis, then ``Only 2D gather is supported``;
-    tests/test_tpu_lowering.py pins the refusal) — so no default may trace
-    it, and the chip's default lane is the dense Pallas histogram + the XLA
-    ``_split_scan``, column-sharded on >1 device. '1' forces it (CPU runs
-    the kernels in the Pallas interpreter — the CI/parity lane; on a TPU it
-    fails at trace time, loudly); '0' = off."""
-    from h2o3_tpu import config
-
-    return config.get("H2O3_TPU_SPLIT_FUSE") not in (
-        "auto", "", "0", "false", "False")
-
-
-def _split_fuse_active(cat_cols: tuple, split_shard: bool,
-                       uplift: bool = False) -> bool:
-    """Whether a program being built NOW should trace the fused pipeline.
-
-    The post-ISSUE-15 fallback matrix (docs/MIGRATION.md): monotone builds
-    fuse (the per-bin feasibility mask runs inside the kernel grid step —
-    ops/split_pallas._split_kernel_mono) and categorical columns on a
-    column-sharded mesh fuse too (every block runs the mean-sort branch on
-    a BLOCK-LOCAL dense gather, selecting per column — the dense sharded
-    scan's own scheme, now fed from the blocked tiles). ISSUE 16 closed
-    uplift too: its 4-lane scan runs through the whole-tree fused uplift
-    program (models/uplift._uplift_tree_program), so ``uplift=True`` here
-    is only reached from the LEGACY per-level uplift loop
-    (H2O3_TPU_WHOLE_TREE=0 / depth cap); a structural fallback while the
-    gate is ON tallies ``tree_fused_fallbacks_total{reason}``."""
-    if not _split_fuse_on():
-        return False
-    if uplift:
-        _FUSED_FALLBACKS.inc(reason="uplift")
-        return False
-    return True
-
-
 def _kernel_key() -> tuple:
     """Program-cache component for everything that changes the TRACED
-    kernels without changing any call-site argument: the fuse toggle, the
-    Pallas tile triple, and the local-histogram override. Without these a
-    cached program compiled under one setting would silently serve another
-    (the --fused-ab sweep toggles H2O3_TPU_SPLIT_FUSE in-process)."""
+    kernels without changing any call-site argument: the Pallas tile triple
+    and the local-histogram override. Without these a cached program
+    compiled under one setting would silently serve another."""
     from h2o3_tpu import config
     from h2o3_tpu.ops.hist_pallas import _tiles
 
@@ -598,134 +543,10 @@ def _kernel_key() -> tuple:
     # shape-dependent tiles inside the trace — _tiles() alone could not
     # distinguish 'auto' from the '' defaults; HIST_I16 changes the traced
     # local accumulation (ops/histogram._maybe_i16)
-    return (_split_fuse_on(), _tiles(),
+    return (_tiles(),
             config.get("H2O3_TPU_PALLAS_TILES").strip(),
             config.get("H2O3_TPU_HIST"),
             config.get_bool("H2O3_TPU_HIST_I16"))
-
-
-def _split_scan_sharded_fused(
-    blk, layout, is_cat, col_mask, min_rows, min_split_improvement,
-    any_cat: bool = False, mono=None, node_lo=None, node_hi=None, mesh=None,
-):
-    """Column-sharded split scan on a BLOCKED histogram: each device runs
-    the Pallas split kernel (ops/split_pallas.py) on its own 1/P tile range
-    in VMEM — the full histogram never exists on any device — and the
-    winner merge is byte-identical to the dense sharded path's: per-block
-    winners all_gather (O(N·P) scalars), argmax over blocks picks the
-    lowest block, blocks are contiguous ascending column ranges, and every
-    block's gains are computed against GLOBAL column 0's node totals.
-
-    ``any_cat`` (ISSUE 15) closes the cat+sharded fallback: block
-    membership of a categorical column is dynamic (the traced body is
-    one-per-mesh), so — exactly like the dense sharded scan — every block
-    runs the mean-sort categorical branch on ALL its local columns via a
-    BLOCK-LOCAL dense gather (``blocked_cols_dense`` over the local tiles,
-    O(N·(C/P)·B·S) HBM, never the full histogram) and selects per column by
-    the sliced ``is_cat``; the winner tuple then carries the (N, B)
-    membership mask. Numeric columns stay on the kernel throughout.
-
-    ``mono``/``node_lo``/``node_hi`` thread the monotone-constrained kernel
-    variant per block (the direction lane slices like the column mask) and
-    the winner tuple gains ``mid``/``mono_col`` for bound propagation."""
-    import jax.tree_util as jtu
-
-    from h2o3_tpu.ops.histogram import record_collective
-    from h2o3_tpu.ops.hist_pallas import blocked_node_totals
-    from h2o3_tpu.ops.split_pallas import fused_split_scan
-    from h2o3_tpu.parallel.mesh import (
-        col_axis_name, get_mesh, n_col_shards, shard_map,
-    )
-    from jax.sharding import PartitionSpec as P
-
-    mesh = mesh or get_mesh()
-    n_dev = n_col_shards(mesh)
-    cax = col_axis_name(mesh)
-    L = layout
-    lloc = L.local(n_dev)
-    N, B, S = L.n_nodes, L.n_bins, L.ns
-    C = is_cat.shape[0]
-    if L.cpad > C:  # layout padding columns: masked, can never win
-        is_cat = jnp.pad(is_cat, (0, L.cpad - C))
-        col_mask = jnp.pad(col_mask, ((0, 0), (0, L.cpad - C)))
-        if mono is not None:
-            mono = jnp.pad(mono, (0, L.cpad - C))
-    # the dense sharded scan's scheme: every local column routes through
-    # the categorical branch, per-column selection by is_cat
-    local_cats = tuple(range(lloc.cpad)) if any_cat else ()
-
-    if n_dev > 1:
-        per_dev = N * (4 + 4 + 4 + 1 + 1 + 12 + 12 + 4 * S)
-        if any_cat:
-            per_dev += N * B
-        if mono is not None:
-            per_dev += N * 8
-        record_collective("winner_gather", n_dev * per_dev)
-
-    def body(blk_loc, cm, ic, mono_g, lo, hi):
-        d = jax.lax.axis_index(cax)
-        col0 = (d * lloc.cpad).astype(jnp.int32)
-        # node totals from GLOBAL column 0 = block 0's local column 0
-        tot_loc = blocked_node_totals(blk_loc, lloc)
-        tot0 = jax.lax.all_gather(tot_loc, cax)[0]
-        cm_blk = jax.lax.dynamic_slice_in_dim(cm, col0, lloc.cpad, axis=1)
-        ic_blk = jax.lax.dynamic_slice_in_dim(ic, col0, lloc.cpad, axis=0)
-        mono_blk = (
-            None if mono_g is None
-            else jax.lax.dynamic_slice_in_dim(mono_g, col0, lloc.cpad, axis=0)
-        )
-        sp = fused_split_scan(
-            blk_loc, lloc, ic_blk, cm_blk, min_rows, min_split_improvement,
-            local_cats, node_totals=tot0,
-            mono=mono_blk, node_lo=lo, node_hi=hi,
-        )
-        win = {
-            "gain": sp["gain"],
-            "col": col0 + sp["col"].astype(jnp.int32),
-            "split_bin": sp["split_bin"],
-            "na_left": sp["na_left"],
-            "is_cat": sp["is_cat"],
-            "Lst": sp["Lst"],
-            "Rst": sp["Rst"],
-        }
-        if any_cat:
-            win["cat_mask"] = sp["cat_mask"]
-        if mono_g is not None:
-            win["mid"] = sp["mid"]
-            win["mono_col"] = sp["mono_col"]
-        g = jtu.tree_map(lambda a: jax.lax.all_gather(a, cax), win)
-        # identical merge to the dense sharded path: argmax over the block
-        # axis — first max wins, i.e. the LOWEST block
-        bb = jnp.argmax(g["gain"], axis=0)  # (N,)
-
-        def pick(a):
-            idx = bb.reshape((1,) + bb.shape + (1,) * (a.ndim - 2))
-            return jnp.take_along_axis(a, idx, axis=0).squeeze(0)
-
-        out = {k: pick(v) for k, v in g.items()}
-        out["ok"] = out["gain"] >= min_split_improvement
-        out["node_w"] = tot0[:, 0]
-        out["node_wy"] = tot0[:, 1]
-        out["node_wh"] = tot0[:, 2]
-        if not any_cat:
-            out["cat_mask"] = jnp.zeros((N, B), bool)
-        return out
-
-    if mono is None:
-        return shard_map(
-            lambda b, cm, ic: body(b, cm, ic, None, None, None),
-            mesh=mesh,
-            in_specs=(P(cax), P(), P()),
-            out_specs=P(),
-            check_vma=False,
-        )(blk, col_mask, is_cat)
-    return shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(cax), P(), P(), P(), P(), P()),
-        out_specs=P(),
-        check_vma=False,
-    )(blk, col_mask, is_cat, mono, node_lo, node_hi)
 
 
 def _split_scan_sharded(
@@ -1086,9 +907,9 @@ def _child_bounds(ok, child_base, mono_col, mid, node_lo, node_hi,
     """Monotone child-bound propagation: children of a constrained split
     tighten to the parent's ``mid`` on the constrained side (left child at
     ``child_base``, right at ``child_base+1``; leaves drop out-of-bounds).
-    Factored out of the per-level mono step so the fused whole-tree
-    program, the streamed decide and the per-level loop scatter the SAME
-    ops. Returns ``(new_lo, new_hi)`` sized ``n_pad_next``."""
+    Factored out of the per-level mono step so the streamed decide and the
+    per-level loop scatter the SAME ops. Returns ``(new_lo, new_hi)`` sized
+    ``n_pad_next``."""
     new_lo = jnp.full(n_pad_next, -jnp.inf, jnp.float32)
     new_hi = jnp.full(n_pad_next, jnp.inf, jnp.float32)
     inc = mono_col > 0
@@ -1112,7 +933,6 @@ def _level_core(
     leaf_reg=None,
     *, n_pad: int, n_pad_next: int, cat_cols: tuple = (),
     n_cols_real: int | None = None, split_shard: bool = False,
-    fuse_layout=None, mono=None, node_lo=None, node_hi=None,
     leaf_budget=None,
 ):
     """Split scan → decisions → partition for one level, given its histogram.
@@ -1121,17 +941,6 @@ def _level_core(
     column-sharded (and possibly padded past the real column count — the
     sharded scan masks the pad), and the scan+merge reproduces the
     replicated path's decisions bit-exactly (:func:`_split_scan_sharded`).
-
-    ``fuse_layout`` (a ``hist_pallas.HistLayout``) selects the fused Pallas
-    pipeline: ``hist`` is then the BLOCKED histogram tensor and the scan
-    runs as the VMEM-tile split kernel (``ops/split_pallas.py``) — sharded
-    or replicated — emitting the same decision dict.
-
-    ``mono`` ((C,) int, traced) + ``node_lo``/``node_hi`` ((n_pad,)) select
-    monotone-constrained split finding on EVERY scan variant (fused or
-    dense, sharded or replicated — ISSUE 15 closed the fused gap); the
-    return then appends ``(new_lo, new_hi)`` sized ``n_pad_next`` for the
-    caller's bound carry.
 
     ``leaf_budget`` (traced int32 scalar, ISSUE 16 ``grow_policy=lossguide``)
     rations this level's splits by gain rank: only the ``leaf_budget``
@@ -1171,30 +980,15 @@ def _level_core(
     col_mask = col_mask * keep
     # ph_split: phase tag (utils/telemetry.summarize, tools/profile_fused.py)
     with jax.named_scope("ph_split"):
-        if fuse_layout is not None and split_shard:
-            sp = _split_scan_sharded_fused(
-                hist, fuse_layout, is_cat, col_mask, min_rows,
-                min_split_improvement, any_cat=bool(cat_cols),
-                mono=mono, node_lo=node_lo, node_hi=node_hi,
-            )
-        elif fuse_layout is not None:
-            from h2o3_tpu.ops.split_pallas import fused_split_scan
-
-            sp = fused_split_scan(
-                hist, fuse_layout, is_cat, col_mask, min_rows,
-                min_split_improvement, cat_cols,
-                mono=mono, node_lo=node_lo, node_hi=node_hi,
-            )
-        elif split_shard:
+        if split_shard:
             sp = _split_scan_sharded(
                 hist, is_cat, col_mask, min_rows, min_split_improvement,
                 any_cat=bool(cat_cols),
-                mono=mono, node_lo=node_lo, node_hi=node_hi,
             )
         else:
             sp = _split_scan(
                 hist, is_cat, col_mask, min_rows, min_split_improvement,
-                cat_cols, mono=mono, node_lo=node_lo, node_hi=node_hi,
+                cat_cols,
             )
     ok = sp["ok"]
     # frontier cap: children must fit n_pad_next; later nodes go leaf
@@ -1217,7 +1011,7 @@ def _level_core(
         bins_u8, nid, preds, varimp, ok, gain,
         sp["node_w"], sp["node_wy"], sp["node_wh"],
         sp["col"], sp["split_bin"], sp["is_cat"], sp["cat_mask"], sp["na_left"],
-        learn_rate, max_abs_leaf, n_pad, node_lo=node_lo, node_hi=node_hi,
+        learn_rate, max_abs_leaf, n_pad,
         reg_lambda=rl, reg_alpha=ra, any_cat=bool(cat_cols),
     )
 
@@ -1233,25 +1027,15 @@ def _level_core(
         "Lst": scat(jnp.zeros((half, 3), sp["Lst"].dtype), sp["Lst"]),
         "Rst": scat(jnp.zeros((half, 3), sp["Rst"].dtype), sp["Rst"]),
     }
-    extra = ()
-    if mono is not None:
-        new_lo, new_hi = _child_bounds(
-            ok, record["child_base"], sp["mono_col"], sp["mid"],
-            node_lo, node_hi, n_pad_next,
-        )
-        extra = (new_lo, new_hi)
-    if leaf_budget is not None:
-        extra = extra + (new_budget,)
-    return (nid, preds, varimp, n_split, record, pair_info) + extra
+    out = (nid, preds, varimp, n_split, record, pair_info)
+    return out if leaf_budget is None else out + (new_budget,)
 
 
 def _force_leaf_from_stats(
     bins_u8, nid, preds, varimp, node_w, node_wy, node_wh,
     learn_rate, max_abs_leaf, n_pad, n_bins, leaf_reg=None,
-    node_lo=None, node_hi=None,
 ):
-    """Terminal level: every active node becomes a leaf (no split scan).
-    ``node_lo``/``node_hi`` clamp the leaf values on monotone builds."""
+    """Terminal level: every active node becomes a leaf (no split scan)."""
     ok = jnp.zeros(n_pad, bool)
     zi = jnp.zeros(n_pad, jnp.int32)
     rl, ra = (None, None) if leaf_reg is None else leaf_reg
@@ -1259,7 +1043,7 @@ def _force_leaf_from_stats(
         bins_u8, nid, preds, varimp, ok, jnp.zeros(n_pad, jnp.float32),
         node_w, node_wy, node_wh, zi, zi, jnp.zeros(n_pad, bool),
         jnp.zeros((n_pad, n_bins), bool), jnp.zeros(n_pad, bool),
-        learn_rate, max_abs_leaf, n_pad, node_lo=node_lo, node_hi=node_hi,
+        learn_rate, max_abs_leaf, n_pad,
         reg_lambda=rl, reg_alpha=ra, any_cat=False,
     )
     return nid, preds, varimp, n_split, record
@@ -1271,7 +1055,6 @@ def _level_step_fn(
     leaf_reg=None,
     *, n_pad: int, n_pad_next: int, n_bins: int, force_leaf: bool,
     cat_cols: tuple = (), split_shard: bool = False,
-    split_fuse: bool = False,
 ):
     """One whole tree level on device (histogram built from scratch).
 
@@ -1283,19 +1066,10 @@ def _level_step_fn(
 
     hist = histogram_in_jit(
         bins_u8, nid, (w, wy, wh), n_pad, n_bins, col_sharded=split_shard,
-        fused=split_fuse,
     )
-    lay = None
-    if split_fuse:
-        hist, lay = hist
 
     if force_leaf:
-        if split_fuse:
-            from h2o3_tpu.ops.hist_pallas import blocked_node_totals
-
-            tot = blocked_node_totals(hist, lay)  # global col 0 ≡ any col
-        else:
-            tot = hist[:, 0, :, :].sum(axis=1)  # (n_pad, 3); col 0 ≡ any col
+        tot = hist[:, 0, :, :].sum(axis=1)  # (n_pad, 3); col 0 ≡ any col
         return _force_leaf_from_stats(
             bins_u8, nid, preds, varimp, tot[:, 0], tot[:, 1], tot[:, 2],
             learn_rate, max_abs_leaf, n_pad, n_bins, leaf_reg,
@@ -1304,82 +1078,21 @@ def _level_step_fn(
         hist, bins_u8, nid, preds, varimp, key, cols_enabled, is_cat,
         min_rows, min_split_improvement, learn_rate, max_abs_leaf,
         col_sample_rate, leaf_reg, n_pad=n_pad, n_pad_next=n_pad_next,
-        cat_cols=cat_cols, split_shard=split_shard, fuse_layout=lay,
+        cat_cols=cat_cols, split_shard=split_shard,
     )
     return out[:5]
 
 
-# -- per-level bin adaptivity (DHistogram's per-level re-binning analog) ----
-# Upstream re-derives histogram ranges per level (nbins_top_level halving to
-# nbins); here deep levels coarsen the static quantile bins instead: the
-# dense one-hot histogram's cost is ∝ bin count, and nodes deep in the tree
-# hold few rows, where 63 quantile bins split as well as 254. Recorded
-# splits are converted back to FULL-resolution thresholds (a coarse prefix
-# split is exactly a full-res prefix split), so partition replay, MOJO
-# export and the native scorer are untouched. Numeric-only: coarsening ENUM
-# bins would merge arbitrary categories; frames with categorical features
-# keep full bins at every level.
-
-_BIN_ADAPT_START = 3  # first depth allowed to coarsen
-_BIN_ADAPT_MIN = 63  # never fewer data bins than this
-
-
-def _bin_shifts(max_depth: int, n_bins: int, cat_cols: tuple) -> list[int]:
-    from h2o3_tpu import config
-
-    if cat_cols or not config.get_bool("H2O3_TPU_BIN_ADAPT"):
-        return [0] * (max_depth + 1)
-    D = n_bins - 1  # data bins (bin 0 = NA)
-    out = []
-    for d in range(max_depth + 1):
-        s = max(d - (_BIN_ADAPT_START - 1), 0)
-        while s > 0 and (D >> s) < _BIN_ADAPT_MIN:
-            s -= 1
-        out.append(s)
-    return out
-
-
-def _coarse_nbins(n_bins: int, s: int) -> int:
-    return (-(-(n_bins - 1) // (1 << s))) + 1 if s else n_bins
-
-
-def _coarsen_bins(bins_u8, s: int):
-    if s == 0:
-        return bins_u8
-    b = bins_u8.astype(jnp.int32)
-    return jnp.where(b == 0, 0, ((b - 1) >> s) + 1).astype(jnp.uint8)
-
-
-def _coarsen_hist(hist, ds: int):
-    """Sum adjacent data-bin groups of 2**ds (NA bin passes through)."""
-    if ds == 0:
-        return hist
-    N, C, _, S = hist.shape
-    na = hist[:, :, :1, :]
-    data = hist[:, :, 1:, :]
-    D = data.shape[2]
-    Dc = -(-D // (1 << ds))
-    pad = Dc * (1 << ds) - D
-    if pad:
-        data = jnp.pad(data, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    data = data.reshape(N, C, Dc, 1 << ds, S).sum(3)
-    return jnp.concatenate([na, data], axis=2)
-
-
-def _sat_region(max_depth: int, node_cap: int, shifts: list[int]) -> tuple:
+def _sat_region(max_depth: int, node_cap: int) -> tuple:
     """(start, count) of the node_cap-SATURATED level run rolled into a
     ``lax.while_loop``: levels where the frontier is pinned at ``node_cap``
-    (so every iteration has identical shapes) and the bin-coarsening shift is
-    constant from the preceding level on (so the parent-histogram carry needs
-    no per-iteration re-coarsening). Unrolling those levels instead would
-    compile O(depth) copies of the most expensive level body — the while_loop
-    form compiles ONE body and early-exits on device the moment a level
-    produces no splits (the deep-DRF regime where most levels are dead)."""
+    (so every iteration has identical shapes). Unrolling those levels instead
+    would compile O(depth) copies of the most expensive level body — the
+    while_loop form compiles ONE body and early-exits on device the moment a
+    level produces no splits (the deep-DRF regime where most levels are
+    dead)."""
     for d in range(1, max_depth):
-        if (
-            min(1 << d, node_cap) == node_cap
-            and len(set(shifts[d - 1 : max_depth])) == 1
-        ):
+        if min(1 << d, node_cap) == node_cap:
             if max_depth - d >= 2:
                 return d, max_depth - d
             break
@@ -1392,8 +1105,7 @@ def _fused_levels(
     leaf_reg=None,
     *, max_depth: int, n_bins: int, node_cap: int, cat_cols: tuple,
     subtract: bool = True, n_cols_real: int | None = None,
-    split_shard: bool = False, split_fuse: bool = False, mono=None,
-    max_leaves: int = 0, efb=None, bins_b=None,
+    split_shard: bool = False, max_leaves: int = 0, efb=None, bins_b=None,
 ):
     """All levels of one tree, traced into a single program, with the two
     histogram work reductions the reference's hot loop embodies
@@ -1410,8 +1122,8 @@ def _fused_levels(
 
     At depth 6 that is 1+1+2+4+8+16+0 = 32 node-histogram units vs 127 for
     the direct scheme — ~4× fewer MXU FLOPs in the phase that dominates
-    tree time. ``subtract=False`` recovers the direct scheme (A/B testing,
-    ``H2O3_TPU_HIST_SUBTRACT=0``).
+    tree time. ``subtract=False`` recovers the direct scheme (the reference
+    the parity tests compare against, ``H2O3_TPU_HIST_SUBTRACT=0``).
 
     Level structure (one compiled program, zero host round-trips):
     frontier-GROWTH levels (node count 1, 2, 4, … < node_cap) unroll — each
@@ -1422,26 +1134,19 @@ def _fused_levels(
     — all-leaf, zero-valued, reachable by no row — so replay, export and the
     level masks need no notion of "how deep did this tree actually go".
 
-    ``mono`` ((Cp,) int, traced) threads monotone constraints through every
-    level IN the fused program (ISSUE 15): per-node ``[lo, hi]`` bound
-    state rides the level-to-level carry (including the saturated
-    while_loop's), each level's scan masks infeasible candidates inside
-    the kernel, and both force-leaf paths clamp their leaf values.
-
     ``max_leaves`` > 0 (ISSUE 16 ``grow_policy=lossguide``) threads an
-    int32 remaining-leaf budget through the same carry: each level rations
-    its splits by gain rank (:func:`_level_core`) and decrements the
-    budget, so the finished tree has at most ``max_leaves`` leaves.
+    int32 remaining-leaf budget through the level-to-level carry (including
+    the saturated while_loop's): each level rations its splits by gain rank
+    (:func:`_level_core`) and decrements the budget, so the finished tree
+    has at most ``max_leaves`` leaves.
 
     ``efb``/``bins_b`` (ISSUE 16 exclusive feature bundling) accumulate
     every level's histogram from the BUNDLED code matrix ``bins_b``
     ((npad, Cb), Cb < C) and expand it back to real columns immediately
     after accumulation (:func:`~h2o3_tpu.models.tree.binning.expand_hist`),
-    so subtraction, coarsening, the split scans and the partition walk are
-    untouched — the O(rows · C) accumulation is the only thing that
-    shrinks. EFB rides the replicated dense lane only (callers force
-    ``split_shard=split_fuse=False``) and requires the bin-adapt shifts to
-    be zero (bundle codes don't survive coarsening).
+    so subtraction, the split scans and the partition walk are untouched —
+    the O(rows · C) accumulation is the only thing that shrinks. EFB rides
+    the replicated lane only (callers force ``split_shard=False``).
     """
     from h2o3_tpu.ops.histogram import histogram_in_jit
 
@@ -1449,10 +1154,7 @@ def _fused_levels(
     if efb is not None:
         from h2o3_tpu.models.tree.binning import expand_arrays, expand_hist
 
-        assert not split_shard and not split_fuse, "EFB is dense-lane only"
-        assert all(
-            s == 0 for s in _bin_shifts(max_depth, n_bins, cat_cols)
-        ), "EFB requires zero bin-adapt shifts"
+        assert not split_shard, "EFB is replicated-lane only"
         _efb_arrs = expand_arrays(efb, bins_u8.shape[1], n_bins)
         efb_expand = lambda h: expand_hist(_efb_arrs, h)
 
@@ -1461,95 +1163,49 @@ def _fused_levels(
     # stack/reshape interleave
     node_cap = max(2, node_cap - (node_cap % 2))
     nid = jnp.zeros(bins_u8.shape[0], jnp.int32)
-    # monotone bound carry: level d's bounds are sized to its frontier
-    # (level d-1's n_pad_next), starting from the unbounded root
-    node_lo = jnp.full(1, -jnp.inf, jnp.float32) if mono is not None else None
-    node_hi = jnp.full(1, jnp.inf, jnp.float32) if mono is not None else None
     # lossguide: remaining net-leaf budget (root is 1 leaf; a split adds 1)
     leaf_budget = jnp.int32(max_leaves - 1) if max_leaves else None
     recs = []
     parent_hist = None
-    parent_lay = None  # static HistLayout of the blocked parent (fused path)
     pair_info = None
     n_split = None
-    shifts = _bin_shifts(max_depth, n_bins, cat_cols)
-    prev_shift = 0
-    sat_start, n_sat = _sat_region(max_depth, node_cap, shifts)
+    sat_start, n_sat = _sat_region(max_depth, node_cap)
+    bins_h = bins_b if efb_expand else bins_u8  # what the histograms read
 
-    def level_hist(bins_d, nb_d, depth, nid, pair_info, parent_hist, sd,
-                   parent_lay=None):
-        """One level's histogram — direct or sibling-sub; returns
-        ``(hist, layout)`` where ``layout`` is None on the dense path and
-        the ``HistLayout`` of the blocked tensor on the fused one.
-        Under ``split_shard`` the column axis comes back sharded (and padded
-        to the shard count); subtraction, coarsening and the parent carry
-        are columnwise (fused: tile-local reshape) ops, so they stay
-        block-local and never transpose in HBM."""
+    def level_hist(depth, nid, pair_info, parent_hist):
+        """One level's histogram — direct or sibling-sub. Under
+        ``split_shard`` the column axis comes back sharded (and padded to
+        the shard count); subtraction and the parent carry are columnwise
+        ops, so they stay block-local and never transpose in HBM."""
         n_pad = min(1 << depth, node_cap)
         if depth == 0 or not subtract:
             h = histogram_in_jit(
-                bins_b if efb_expand else bins_d, nid, (w, wy, wh), n_pad,
-                nb_d, col_sharded=split_shard, fused=split_fuse,
+                bins_h, nid, (w, wy, wh), n_pad, n_bins,
+                col_sharded=split_shard,
             )
-            if efb_expand:
-                return efb_expand(h), None
-            return h if split_fuse else (h, None)
+            return efb_expand(h) if efb_expand else h
         half = n_pad // 2
         row_pair = jnp.maximum(nid, 0) >> 1  # pair = nid//2 (child_base even)
         row_left = (nid & 1) == 0
         bl = pair_info["build_left"]
         build_row = (nid >= 0) & (row_left == bl[row_pair])
         nid_build = jnp.where(build_row, row_pair, -1)
-        if split_fuse:
-            from h2o3_tpu.ops.hist_pallas import (
-                blocked_coarsen, relayout_nodes,
-            )
-
-            built, blay = histogram_in_jit(
-                bins_d, nid_build, (w, wy, wh), half, nb_d,
-                col_sharded=split_shard, fused=True,
-            )
-            # the blocked tensor's node axis is a pure row-reshape
-            # (rows = node·S + stat), so sibling selection/stacking runs on
-            # logical (n_ct, node, S, lanes) views with no lane transpose
-            psel_blk, clay = blocked_coarsen(parent_hist, parent_lay, sd)
-            lanes = clay.ct * clay.bpad
-            v = psel_blk.reshape(clay.n_ct, clay.nn, clay.ns, lanes)
-            psel = jnp.where(
-                pair_info["valid"][None, :, None, None],
-                v[:, pair_info["parent_idx"], :, :],
-                0.0,
-            )  # (n_ct, half, S, lanes)
-            b4 = built.reshape(blay.n_ct, blay.nn, blay.ns, lanes)[:, :half]
-            sib = psel - b4
-            blb = bl[None, :, None, None]
-            stacked = jnp.stack(
-                [jnp.where(blb, b4, sib), jnp.where(blb, sib, b4)], axis=2
-            ).reshape(blay.n_ct, n_pad, blay.ns, lanes)
-            flay = relayout_nodes(blay, n_pad)
-            if flay.nn > n_pad:
-                stacked = jnp.pad(
-                    stacked, ((0, 0), (0, flay.nn - n_pad), (0, 0), (0, 0))
-                )
-            return stacked.reshape(flay.shape), flay
         built = histogram_in_jit(
-            bins_b if efb_expand else bins_d, nid_build, (w, wy, wh), half,
-            nb_d, col_sharded=split_shard,
-        )  # (half, C, Bc, 3) — EFB accumulates bundled, expands to real C
+            bins_h, nid_build, (w, wy, wh), half, n_bins,
+            col_sharded=split_shard,
+        )  # (half, C, B, 3) — EFB accumulates bundled, expands to real C
         if efb_expand:
             built = efb_expand(built)
-        # parent histogram was built at the previous level's (finer)
-        # binning — sum its data-bin groups down to this level's
         psel = jnp.where(
             pair_info["valid"][:, None, None, None],
-            _coarsen_hist(parent_hist, sd)[pair_info["parent_idx"]],
+            parent_hist[pair_info["parent_idx"]],
             0.0,
         )
         sib = psel - built
         blb = bl[:, None, None, None]
         return jnp.stack(
             [jnp.where(blb, built, sib), jnp.where(blb, sib, built)], axis=1
-        ).reshape(n_pad, *built.shape[1:]), None
+        ).reshape(n_pad, *built.shape[1:])
 
     depth = 0
     sat_iters = jnp.int32(0)  # executed saturated-region levels (0 if none)
@@ -1560,16 +1216,7 @@ def _fused_levels(
 
         if depth == sat_start:
             # ---- saturated run: ONE compiled body, on-device early exit ----
-            sd = shifts[depth]
-            nb_d = _coarse_nbins(n_bins, sd)
-            bins_d = _coarsen_bins(bins_u8, sd)
-            if split_fuse and subtract and parent_lay.n_nodes < node_cap:
-                from h2o3_tpu.ops.hist_pallas import blocked_pad_nodes
-
-                parent_hist, parent_lay = blocked_pad_nodes(
-                    parent_hist, parent_lay, node_cap
-                )
-            elif not split_fuse and subtract and parent_hist.shape[0] < node_cap:
+            if subtract and parent_hist.shape[0] < node_cap:
                 # first iteration's parent frontier may be node_cap/2 wide;
                 # zero-pad so the carry shape is loop-invariant (the pad rows
                 # are gated off by pair_info["valid"])
@@ -1582,7 +1229,7 @@ def _fused_levels(
             zb = jnp.zeros((n_sat, node_cap), bool)
             bufs = {
                 "node_w": zf, "split_col": zi, "split_bin": zi,
-                "is_cat": zb, "cat_mask": jnp.zeros((n_sat, node_cap, nb_d), bool),
+                "is_cat": zb, "cat_mask": jnp.zeros((n_sat, node_cap, n_bins), bool),
                 "na_left": zb, "leaf_now": jnp.ones((n_sat, node_cap), bool),
                 "leaf_val": zf, "child_base": zi, "gain": zf,
             }
@@ -1592,41 +1239,24 @@ def _fused_levels(
 
             def sat_body(carry):
                 i, nid_c, preds_c, vi_c, _, phist, pinfo, bufs_c = carry[:8]
-                lo_c = hi_c = bgt_c = None
-                k = 8
-                if mono is not None:
-                    lo_c, hi_c = carry[8], carry[9]
-                    k = 10
-                if max_leaves:
-                    bgt_c = carry[k]
+                bgt_c = carry[8] if max_leaves else None
                 d = sat_start + i
                 lkey = jax.random.fold_in(tkey, d)
-                hist, hlay = level_hist(
-                    bins_d, nb_d, sat_start, nid_c, pinfo, phist, 0,
-                    parent_lay=parent_lay,
-                )
+                hist = level_hist(sat_start, nid_c, pinfo, phist)
                 out = _level_core(
-                    hist, bins_d, nid_c, preds_c, vi_c, lkey, cols_enabled,
+                    hist, bins_u8, nid_c, preds_c, vi_c, lkey, cols_enabled,
                     is_cat, min_rows, min_split_improvement, learn_rate,
                     max_abs_leaf, col_sample_rate, leaf_reg,
                     n_pad=node_cap, n_pad_next=node_cap, cat_cols=cat_cols,
                     n_cols_real=n_cols_real, split_shard=split_shard,
-                    fuse_layout=hlay, mono=mono, node_lo=lo_c, node_hi=hi_c,
                     leaf_budget=bgt_c,
                 )
                 nid_c, preds_c, vi_c, nsp, rec, pinfo = out[:6]
-                if mono is not None:
-                    lo_c, hi_c = out[6], out[7]
-                if max_leaves:
-                    bgt_c = out[-1]
-                if sd:
-                    rec = dict(rec, split_bin=rec["split_bin"] << sd)
                 bufs_c = {k: bufs_c[k].at[i].set(rec[k]) for k in bufs_c}
                 # direct mode threads a fixed dummy parent carry instead
                 base = (i + 1, nid_c, preds_c, vi_c, nsp,
                         hist if subtract else phist, pinfo, bufs_c)
-                base = base + ((lo_c, hi_c) if mono is not None else ())
-                return base + ((bgt_c,) if max_leaves else ())
+                return base + ((out[-1],) if max_leaves else ())
 
             if not subtract:
                 # the direct scheme needs no parent-histogram/pair carry;
@@ -1642,28 +1272,20 @@ def _fused_levels(
             # report actual volume, not the n_sat upper bound
             carry0 = (jnp.int32(0), nid, preds, varimp, n_split, parent_hist,
                       pair_info, bufs)
-            if mono is not None:
-                carry0 = carry0 + (node_lo, node_hi)
             if max_leaves:
                 carry0 = carry0 + (leaf_budget,)
             with tally_group("sat"):
                 out = jax.lax.while_loop(sat_cond, sat_body, carry0)
             (sat_iters, nid, preds, varimp, n_split, parent_hist,
              pair_info, bufs) = out[:8]
-            if mono is not None:
-                node_lo, node_hi = out[8], out[9]
             if max_leaves:
                 leaf_budget = out[-1]
-            prev_shift = sd
             for j in range(n_sat):
                 recs.append({k: bufs[k][j] for k in bufs})
             depth = max_depth
             continue
 
         lkey = jax.random.fold_in(tkey, depth)
-        sd = shifts[depth]
-        nb_d = _coarse_nbins(n_bins, sd)
-        bins_d = _coarsen_bins(bins_u8, sd)
 
         if force_leaf and subtract and pair_info is not None:
             # leaf stats straight from the parents' chosen splits
@@ -1674,52 +1296,30 @@ def _fused_levels(
                 bins_u8, nid, preds, varimp,
                 node_stats[:, 0], node_stats[:, 1], node_stats[:, 2],
                 learn_rate, max_abs_leaf, n_pad, n_bins, leaf_reg,
-                node_lo=node_lo, node_hi=node_hi,
             )
             recs.append(rec)
             break
 
-        hist, hlay = level_hist(
-            bins_d, nb_d, depth, nid, pair_info, parent_hist,
-            sd - prev_shift, parent_lay=parent_lay,
-        )
+        hist = level_hist(depth, nid, pair_info, parent_hist)
 
         if force_leaf:
-            if split_fuse:
-                from h2o3_tpu.ops.hist_pallas import blocked_node_totals
-
-                tot = blocked_node_totals(hist, hlay)
-            else:
-                tot = hist[:, 0, :, :].sum(axis=1)
+            tot = hist[:, 0, :, :].sum(axis=1)
             nid, preds, varimp, _, rec = _force_leaf_from_stats(
                 bins_u8, nid, preds, varimp, tot[:, 0], tot[:, 1], tot[:, 2],
                 learn_rate, max_abs_leaf, n_pad, n_bins, leaf_reg,
-                node_lo=node_lo, node_hi=node_hi,
             )
         else:
             out = _level_core(
-                hist, bins_d, nid, preds, varimp, lkey, cols_enabled, is_cat,
+                hist, bins_u8, nid, preds, varimp, lkey, cols_enabled, is_cat,
                 min_rows, min_split_improvement, learn_rate, max_abs_leaf,
                 col_sample_rate, leaf_reg, n_pad=n_pad, n_pad_next=n_pad_next,
                 cat_cols=cat_cols, n_cols_real=n_cols_real,
-                split_shard=split_shard, fuse_layout=hlay,
-                mono=mono, node_lo=node_lo, node_hi=node_hi,
-                leaf_budget=leaf_budget,
+                split_shard=split_shard, leaf_budget=leaf_budget,
             )
             nid, preds, varimp, n_split, rec, pair_info = out[:6]
-            if mono is not None:
-                node_lo, node_hi = out[6], out[7]
             if max_leaves:
                 leaf_budget = out[-1]
             parent_hist = hist
-            parent_lay = hlay
-            prev_shift = sd
-            if sd:
-                # a coarse prefix split IS a full-res prefix split: convert
-                # the recorded threshold so replay/export stay full-res.
-                # (partition above already ran on the coarse bins — rows land
-                # identically either way.) cat_mask is unused: numeric-only.
-                rec = dict(rec, split_bin=rec["split_bin"] << sd)
         recs.append(rec)
         depth += 1
     return nid, preds, varimp, tuple(recs), sat_iters
@@ -1891,7 +1491,7 @@ def _mesh_key():
 def _level_step_mono(n_pad, n_pad_next, n_bins, force_leaf, cat_cols=(),
                      split_shard=False):
     # _kernel_key: the Pallas tile/override knobs change the traced
-    # histogram kernel even though mono levels never fuse the split
+    # histogram kernel
     key = ("mono", n_pad, n_pad_next, n_bins, force_leaf, cat_cols,
            split_shard, _kernel_key(), _mesh_key(), jax.default_backend())
     fn = _STEP_CACHE.get(key)
@@ -1915,10 +1515,9 @@ _STEP_CACHE: dict = {}
 def _level_step(
     n_pad: int, n_pad_next: int, n_bins: int, force_leaf: bool,
     cat_cols: tuple = (), split_shard: bool = False,
-    split_fuse: bool = False,
 ):
     key = (n_pad, n_pad_next, n_bins, force_leaf, cat_cols, split_shard,
-           split_fuse, _kernel_key(), _mesh_key(), jax.default_backend())
+           _kernel_key(), _mesh_key(), jax.default_backend())
     fn = _STEP_CACHE.get(key)
     if fn is None:
         fn = jax.jit(
@@ -1926,7 +1525,7 @@ def _level_step(
                 _level_step_fn,
                 n_pad=n_pad, n_pad_next=n_pad_next,
                 n_bins=n_bins, force_leaf=force_leaf, cat_cols=cat_cols,
-                split_shard=split_shard, split_fuse=split_fuse,
+                split_shard=split_shard,
             )
         )
         _STEP_CACHE[key] = fn
@@ -1951,7 +1550,7 @@ def _clamp_node_cap(node_cap: int, npad: int, min_rows) -> int:
 def _tree_program(
     max_depth: int, n_bins: int, node_cap: int, cat_cols: tuple,
     n_cols_real: int | None = None, n_cols_pad: int | None = None,
-    mono: bool = False, max_leaves: int = 0, efb=None,
+    max_leaves: int = 0, efb=None,
 ):
     """One jitted program building a WHOLE tree (growth levels unrolled, the
     saturated run as a lax.while_loop — see :func:`_fused_levels`).
@@ -1965,26 +1564,20 @@ def _tree_program(
     and get a real-width varimp back.
     """
     subtract = _subtract_enabled()
-    if efb is not None:
-        # EFB rides the replicated dense lane only: the bundled C axis is
-        # too small to shard/fuse profitably, and the dense scans are
-        # decision-equal to the sharded/fused ones by construction
-        split_shard = split_fuse = False
-    else:
-        split_shard = _split_shard_on()
-        split_fuse = _split_fuse_active(cat_cols, split_shard)
+    # EFB rides the replicated lane only: the bundled C axis is too small
+    # to shard profitably, and the replicated scan is decision-equal to the
+    # sharded one by construction
+    split_shard = efb is None and _split_shard_on()
     key = ("tree", max_depth, n_bins, node_cap, cat_cols, subtract,
-           n_cols_real, n_cols_pad, split_shard, split_fuse, bool(mono),
+           n_cols_real, n_cols_pad, split_shard,
            int(max_leaves), None if efb is None else efb.key,
-           _kernel_key(), _mesh_key(),
-           tuple(_bin_shifts(max_depth, n_bins, cat_cols)),
-           jax.default_backend())
+           _kernel_key(), _mesh_key(), jax.default_backend())
 
     def make():
         def whole_tree(
             bins_u8, preds, varimp, w, wy, wh, key_, cols_enabled, is_cat,
             min_rows, min_split_improvement, learn_rate, max_abs_leaf,
-            col_sample_rate, leaf_reg=None, mono_vec=None, bins_b=None,
+            col_sample_rate, leaf_reg=None, bins_b=None,
         ):
             C = bins_u8.shape[1]
             Cp = n_cols_pad or C
@@ -1993,15 +1586,13 @@ def _tree_program(
                 is_cat = jnp.pad(is_cat, (0, Cp - C))
                 varimp = jnp.pad(varimp, (0, Cp - C))
                 cols_enabled = jnp.pad(cols_enabled, (0, Cp - C))
-                if mono_vec is not None:  # pad columns are unconstrained
-                    mono_vec = jnp.pad(mono_vec, (0, Cp - C))
             nid, preds_, varimp_, records, sat_iters = _fused_levels(
                 bins_u8, preds, varimp, w, wy, wh, key_, cols_enabled, is_cat,
                 min_rows, min_split_improvement, learn_rate, max_abs_leaf,
                 col_sample_rate, leaf_reg,
                 max_depth=max_depth, n_bins=n_bins, node_cap=node_cap,
                 cat_cols=cat_cols, subtract=subtract, n_cols_real=n_cols_real,
-                split_shard=split_shard, split_fuse=split_fuse, mono=mono_vec,
+                split_shard=split_shard,
                 max_leaves=max_leaves, efb=efb, bins_b=bins_b,
             )
             return nid, preds_, varimp_[:C], records, sat_iters
@@ -2037,7 +1628,6 @@ def build_trees_scanned(
     node_cap: int = 2048,
     reg_lambda: float = 0.0,
     reg_alpha: float = 0.0,
-    monotone=None,
     max_leaves: int = 0,
     efb=None,
     bins_b=None,
@@ -2069,11 +1659,7 @@ def build_trees_scanned(
     is_cat_dev = jnp.asarray(is_cat_np)
 
     subtract = _subtract_enabled()
-    if efb is not None:
-        split_shard = split_fuse = False  # EFB: replicated dense lane only
-    else:
-        split_shard = _split_shard_on()
-        split_fuse = _split_fuse_active(cat_cols, split_shard)
+    split_shard = efb is None and _split_shard_on()  # EFB: replicated only
     goss = _goss_ab()
     # the float rates are baked into the traced closure, so they MUST be part
     # of the cache key (a boolean would silently reuse another model's rates);
@@ -2081,9 +1667,8 @@ def build_trees_scanned(
     # goss (a, b floats) and the EFB plan fingerprint bake in the same way
     key = (
         "scan", n_trees, max_depth, n_bins, node_cap, cat_cols, grad_key, C,
-        tuple(_bin_shifts(max_depth, n_bins, cat_cols)),
         float(sample_rate), float(col_sample_rate_per_tree), subtract,
-        split_shard, split_fuse, monotone is not None, goss,
+        split_shard, goss,
         int(max_leaves), None if efb is None else efb.key, _kernel_key(),
         _mesh_key(), jax.default_backend(),
     )
@@ -2092,14 +1677,12 @@ def build_trees_scanned(
         def whole_chunk(
             bins_u8, w, y, preds, varimp, base_key, row_key_, offset, lrs, is_cat,
             min_rows_, msi_, max_abs_leaf_, col_rate_, leaf_reg_,
-            mono_vec=None, bins_b=None,
+            bins_b=None,
         ):
             if Cp > C:  # bucketed column pad: code 0 (NA) everywhere, masked
                 bins_u8 = jnp.pad(bins_u8, ((0, 0), (0, Cp - C)))
                 is_cat = jnp.pad(is_cat, (0, Cp - C))
                 varimp = jnp.pad(varimp, (0, Cp - C))
-                if mono_vec is not None:  # pad columns are unconstrained
-                    mono_vec = jnp.pad(mono_vec, (0, Cp - C))
 
             def body(carry, per_tree):
                 F, vi = carry
@@ -2147,8 +1730,7 @@ def build_trees_scanned(
                     leaf_reg_,
                     max_depth=max_depth, n_bins=n_bins, node_cap=node_cap,
                     cat_cols=cat_cols, subtract=subtract, n_cols_real=C,
-                    split_shard=split_shard, split_fuse=split_fuse,
-                    mono=mono_vec,
+                    split_shard=split_shard,
                     max_leaves=max_leaves, efb=efb, bins_b=bins_b,
                 )
                 return (F, vi), (recs, sat_i)
@@ -2183,10 +1765,6 @@ def build_trees_scanned(
     # the scan body traces once but runs once per tree: mult=n_trees; the
     # saturated-region tallies instead scale by the chunk's total EXECUTED
     # sat levels, returned as the program's last output
-    mono_dev = (
-        None if monotone is None
-        else jnp.asarray(np.asarray(monotone, np.int32))
-    )
     if goss is not None:
         # modeled expected kept-row volume, same convention as the HBM
         # byte tallies (host-side: the factor never leaves the program)
@@ -2201,7 +1779,7 @@ def build_trees_scanned(
             jnp.int32(tree_offset), lrs, is_cat_dev,
             jnp.float32(min_rows), jnp.float32(min_split_improvement),
             jnp.float32(max_abs_leaf), jnp.float32(col_sample_rate), leaf_reg,
-            mono_dev, bins_b,
+            bins_b,
         ),
         mult=n_trees,
         sat_from=lambda o: o[3],
@@ -2476,46 +2054,11 @@ def build_tree(
     )
 
     # Monotone constraints carry per-node [lo, hi] bound state level to
-    # level. With the fused Pallas lane active the whole constrained tree
-    # runs as ONE whole-tree program (the ISSUE-15 closure: the feasibility
-    # mask lives in the kernel grid step and the bound state rides the
-    # level carry — see _fused_levels); with the fuse gate off, the legacy
-    # per-level host loop below is today's path bit-for-bit.
-    # level — a separate per-level loop (constrained builds trade the fused
-    # dispatch for correctness; the default path is untouched).
+    # level — a separate per-level loop (constrained builds trade the
+    # whole-tree dispatch for it; the default path is untouched).
     split_shard = _split_shard_on()
     if monotone is not None and np.any(np.asarray(monotone) != 0):
         mono_dev = jnp.asarray(np.asarray(monotone, np.int32))
-        if _split_fuse_on() and use_fused_trees(max_depth):
-            prog = _tree_program(
-                max_depth, n_bins, node_cap, cat_cols, n_cols_real=C,
-                n_cols_pad=Cp, mono=True, max_leaves=max_leaves, efb=efb,
-            )
-            BUILD_STATS["dispatches"] += 1
-            BUILD_STATS["trees_built"] += 1
-            import time as _time
-
-            _t0 = _time.perf_counter()
-            _, preds, varimp, records, _sat = _run_counted(
-                prog,
-                (
-                    bins_u8, preds, varimp, w, wy, wh, key, cols_enabled_dev,
-                    is_cat_dev,
-                    jnp.float32(min_rows), jnp.float32(min_split_improvement),
-                    jnp.float32(learn_rate), jnp.float32(max_abs_leaf),
-                    jnp.float32(col_sample_rate), leaf_reg, mono_dev, bins_b,
-                ),
-                sat_from=lambda o: o[4],
-            )
-            _FUSED_SECONDS.inc(_time.perf_counter() - _t0)
-            for rec in records:
-                tree.levels.append(TreeLevel(**rec))
-            return tree, preds, varimp
-        if _split_fuse_on():
-            # fuse gate on but the whole-tree program is off
-            # (H2O3_TPU_WHOLE_TREE=0 / depth cap): the per-level mono loop
-            # below runs the unfused scan — make that visible
-            _FUSED_FALLBACKS.inc(reason="mono")
         nid = jnp.zeros(bins_u8.shape[0], jnp.int32)
         node_lo = jnp.full(1, -jnp.inf, jnp.float32)
         node_hi = jnp.full(1, jnp.inf, jnp.float32)
@@ -2570,7 +2113,7 @@ def build_tree(
                 is_cat_dev,
                 jnp.float32(min_rows), jnp.float32(min_split_improvement),
                 jnp.float32(learn_rate), jnp.float32(max_abs_leaf),
-                jnp.float32(col_sample_rate), leaf_reg, None, bins_b,
+                jnp.float32(col_sample_rate), leaf_reg, bins_b,
             ),
             sat_from=lambda o: o[4],
         )
@@ -2580,14 +2123,12 @@ def build_tree(
         return tree, preds, varimp
 
     nid = jnp.zeros(bins_u8.shape[0], jnp.int32)
-    split_fuse = _split_fuse_active(cat_cols, split_shard)
     for depth in range(max_depth + 1):
         n_pad = min(1 << depth, node_cap)
         n_pad_next = min(2 * n_pad, node_cap)
         force_leaf = depth == max_depth
         step = _level_step(
-            n_pad, n_pad_next, n_bins, force_leaf, cat_cols, split_shard,
-            split_fuse,
+            n_pad, n_pad_next, n_bins, force_leaf, cat_cols, split_shard
         )
         lkey = jax.random.fold_in(key, depth)
         BUILD_STATS["dispatches"] += 1

@@ -244,7 +244,7 @@ def _uplift_level(n_pad, n_pad_next, n_bins, force_leaf, metric):
 
 def _uplift_tree_program(max_depth: int, n_bins: int, node_cap: int,
                          metric: str):
-    """Whole-tree uplift program (ISSUE 16: the last fused-matrix closure).
+    """Whole-tree uplift program (ISSUE 16).
 
     All levels of one uplift tree trace into a single jitted dispatch —
     the 4-lane (wt, wyt, wc, wyc) scan runs through the same unrolled
@@ -283,11 +283,7 @@ def _uplift_tree_program(max_depth: int, n_bins: int, node_cap: int,
 def _build_uplift_tree(bins_u8, wt, y, wc, *, n_bins, is_cat_cols, max_depth,
                        min_rows, min_split_improvement, col_sample_rate,
                        preds, key, varimp, metric, node_cap=1024):
-    from h2o3_tpu.models.tree.shared_tree import (
-        _split_fuse_active,
-        _split_shard_on,
-        use_fused_trees,
-    )
+    from h2o3_tpu.models.tree.shared_tree import use_fused_trees
 
     is_cat_dev = jnp.asarray(np.asarray(is_cat_cols, bool))
     wyt = wt * y
@@ -304,10 +300,7 @@ def _build_uplift_tree(bins_u8, wt, y, wc, *, n_bins, is_cat_cols, max_depth,
         for rec in records:
             tree.levels.append(TreeLevel(**rec))
         return tree, preds, varimp
-    # legacy per-level host loop (H2O3_TPU_WHOLE_TREE=0 / depth cap): the
-    # only remaining structural fallback — tally it per tree when the fuse
-    # gate wanted the fused lane (ISSUE 15/16 observability)
-    _split_fuse_active((), _split_shard_on(), uplift=True)
+    # per-level host loop (H2O3_TPU_WHOLE_TREE=0 / depth cap)
     nid = jnp.zeros(bins_u8.shape[0], jnp.int32)
     for depth in range(max_depth + 1):
         n_pad = min(1 << depth, node_cap)

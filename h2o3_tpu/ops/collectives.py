@@ -127,8 +127,7 @@ def record_hbm(path: str, nbytes: float) -> None:
     histogram+split phases (``tree_hist_hbm_bytes_total{path}``): one write
     per materialized intermediate plus one read per consumed one, recorded
     where the intermediates are created and replayed per dispatch by
-    shared_tree._run_counted — the fused pipeline's acceptance metric. Rides
-    the same tally as the collective bytes under an ``hbm/`` phase prefix."""
+    shared_tree._run_counted. Rides the same tally as the collective bytes under an ``hbm/`` phase prefix."""
     record_collective("hbm/" + path, nbytes)
 
 

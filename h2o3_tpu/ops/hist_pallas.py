@@ -27,20 +27,11 @@ carrying it would be 33% more MXU work (see shared_tree._split_scan) —
 while uplift trees run their 4 treatment/control lanes. Kernel cost is
 ∝ S, so each consumer pays exactly for what it reads.
 
-Two output modes:
-
-- **dense** (default, back-compat): (C, n_nodes·n_bins, S) per shard — the
-  layout the scatter/matmul paths emit. Reaching it costs two
-  reshape/transpose "unscramble" passes over the full tensor in HBM.
-- **blocked** (``blocked=True``, the fused split pipeline): the kernel's
-  native tile layout, shipped untouched — ``(n_ct, NN·S, CT·Bpad)`` where
-  block ``[i_ct]`` holds column tile ``i_ct`` (columns ``i_ct·CT ..``),
-  rows are ``node·S + stat`` and lanes are ``bin·CT + col_in_tile``. No
-  unscramble pass runs at all: the cross-device ``psum_scatter`` shards
-  axis 0 (contiguous column ranges, exactly what the sharded split merge
-  needs) and the split kernel (``ops/split_pallas.py``) consumes the very
-  same tiles block-by-block in VMEM. The :class:`HistLayout` returned by
-  :func:`plan_layout` is the single source of truth for the geometry.
+The output is (C, n_nodes·n_bins, S) per shard — the layout the
+scatter/matmul paths emit. The kernel itself writes (node·S + stat) rows by
+column-tile-major (bin·CT + col_in_tile) lanes; two reshape/transpose
+"unscramble" passes over the full tensor in HBM bring that to the dense
+layout. :func:`plan_layout` is the single source of the tile geometry.
 """
 
 from __future__ import annotations
@@ -164,7 +155,7 @@ def _run_tile_sweep(c, n_nodes, n_bins, ns, interpret: bool) -> tuple:
     for tiles in _sweep_grid(c, n_nodes):
         fn = lambda: hist_pallas_local(
             bins, nid, stats, n_nodes, n_bins, interpret=interpret,
-            blocked=True, tiles=tiles,
+            tiles=tiles,
         )
         try:
             jax.block_until_ready(fn())  # compile
@@ -252,207 +243,42 @@ def tiles_for(c: int, n_nodes: int, n_bins: int, ns: int) -> tuple:
 
 @dataclass(frozen=True)
 class HistLayout:
-    """Static geometry of a blocked histogram tensor (see module docstring).
+    """Static tile geometry of the kernel's padded output for a problem
+    shape: ``(n_nt·nt·ns, n_ct·ct·bpad)`` float32, rows ``node·ns + stat``,
+    lanes column-tile-major ``bin·ct + col_in_tile``. ``n_nt·nt >= n_nodes``,
+    ``n_ct·ct >= C`` and ``bpad >= n_bins`` are tile padding. Padded BIN and
+    NODE cells are exactly zero (no row ever lands there); padded COLUMNS
+    carry the u8 pad code 0 and are sliced off by the unscramble."""
 
-    The blocked tensor is ``(n_ct, NN·ns, ct·bpad)`` float32 with
-    ``blk[i_ct, node·ns + stat, bin·ct + j] ==
-    dense[i_ct·ct + j, node, bin, stat]`` — column tiles on axis 0 (so a
-    ``psum_scatter`` over axis 0 hands each device a contiguous column
-    range), node-major rows, bin-major lanes. ``NN >= n_nodes`` and
-    ``cpad = n_ct·ct >= C`` and ``bpad >= n_bins`` are tile padding. Padded
-    BIN and NODE cells are exactly zero (no row ever lands there). Padded
-    COLUMNS carry the u8 pad code 0, i.e. their whole mass sits in the NA
-    bin — their data bins are zero, so no candidate there passes min_rows
-    with min_rows > 0, and split consumers additionally mask them through
-    the column mask (the PR-5 pattern), so they can never win a split.
-    """
-
-    c: int          # real feature columns
-    n_nodes: int    # real tree nodes
-    n_bins: int     # real bins (bin 0 = NA)
     ns: int         # stat lanes
     ct: int         # columns per tile
     bpad: int       # padded bins per tile (ct*bpad % 128 == 0)
     nt: int         # nodes per tile
-    n_ct: int       # column tiles (multiple of n_shards)
+    n_ct: int       # column tiles
     n_nt: int       # node tiles
-    tiles: tuple    # the (row, col, node) tile triple this plan came from
-
-    @property
-    def cpad(self) -> int:
-        return self.n_ct * self.ct
-
-    @property
-    def nn(self) -> int:  # padded node count
-        return self.n_nt * self.nt
-
-    @property
-    def shape(self) -> tuple[int, int, int]:
-        return (self.n_ct, self.nn * self.ns, self.ct * self.bpad)
 
     @property
     def nbytes(self) -> int:
-        import math
-
-        return 4 * math.prod(self.shape)
-
-    def local(self, n_shards: int) -> "HistLayout":
-        """Layout of one device's block after a psum_scatter over axis 0.
-
-        The local block covers the REAL columns that fall inside its range;
-        ``c`` is kept as the full padded-local width (cpad/P) — callers mask
-        pad columns via the column mask, exactly like the dense sharded
-        scan."""
-        import dataclasses
-
-        assert self.n_ct % n_shards == 0, (self.n_ct, n_shards)
-        n_ct_loc = self.n_ct // n_shards
-        return dataclasses.replace(
-            self, c=n_ct_loc * self.ct, n_ct=n_ct_loc
-        )
+        return 4 * (self.n_nt * self.nt * self.ns) * (
+            self.n_ct * self.ct * self.bpad)
 
 
 def plan_layout(
     c: int, n_nodes: int, n_bins: int, ns: int,
-    tiles: tuple[int, int, int] | None = None, n_shards: int = 1,
+    tiles: tuple[int, int, int] | None = None,
 ) -> HistLayout:
-    """The blocked-histogram geometry for a problem shape.
-
-    ``n_shards > 1`` rounds the column-tile count up to a multiple of the
-    shard count so a tiled ``psum_scatter`` over axis 0 gives every device
-    whole tiles (= a contiguous column range — load-bearing for the winner
-    merge's lowest-global-index tie-break)."""
-    tiles = tuple(tiles or _tiles())
-    _, col_tile, node_tile = tiles
+    """The kernel's tile geometry for a problem shape."""
+    _, col_tile, node_tile = tuple(tiles or _tiles())
     nt = min(node_tile, n_nodes)
     ct = min(col_tile, c)
-    if n_shards > 1:
-        # the scatter hands each device WHOLE tiles: cap the tile at
-        # ceil(C/P) columns so real columns spread over every device (a
-        # wider tile on a narrow frame would park all real columns on
-        # device 0 and pad the tensor with all-zero tiles for the rest)
-        ct = min(ct, max(1, _cdiv(c, n_shards)))
     # pad bins so the lane dimension CT·Bpad is a multiple of 128
     bpad = _cdiv(n_bins, 16) * 16
     while (ct * bpad) % 128:
         bpad += 16
-    n_ct = _cdiv(c, ct)
-    if n_shards > 1:
-        n_ct = _cdiv(n_ct, n_shards) * n_shards
-    n_nt = _cdiv(n_nodes, nt)
     return HistLayout(
-        c=c, n_nodes=n_nodes, n_bins=n_bins, ns=ns,
-        ct=ct, bpad=bpad, nt=nt, n_ct=n_ct, n_nt=n_nt, tiles=tiles,
+        ns=ns, ct=ct, bpad=bpad, nt=nt, n_ct=_cdiv(c, ct),
+        n_nt=_cdiv(n_nodes, nt),
     )
-
-
-def blocked_from_dense(dense, layout: HistLayout):
-    """(C, n_nodes·n_bins, S) → blocked. The CPU-correctness lane for the
-    fused split pipeline when the local histogram impl is scatter/matmul
-    (H2O3_TPU_HIST override): the Pallas kernel emits blocked natively."""
-    L = layout
-    d = dense.reshape(L.c, L.n_nodes, L.n_bins, L.ns)
-    d = jnp.pad(d, ((0, L.cpad - L.c), (0, L.nn - L.n_nodes),
-                    (0, L.bpad - L.n_bins), (0, 0)))
-    d = d.reshape(L.n_ct, L.ct, L.nn, L.bpad, L.ns)
-    d = jnp.transpose(d, (0, 2, 4, 3, 1))  # (n_ct, NN, S, bpad, ct)
-    return d.reshape(L.shape)
-
-
-def dense_from_blocked(blk, layout: HistLayout):
-    """Blocked → (C, n_nodes·n_bins, S) (tests / fallback consumers)."""
-    L = layout
-    d = blk.reshape(L.n_ct, L.nn, L.ns, L.bpad, L.ct)
-    d = jnp.transpose(d, (0, 4, 1, 3, 2))  # (n_ct, ct, NN, bpad, S)
-    d = d.reshape(L.cpad, L.nn, L.bpad, L.ns)[: L.c, : L.n_nodes, : L.n_bins]
-    return d.reshape(L.c, L.n_nodes * L.n_bins, L.ns)
-
-
-def blocked_cols_dense(blk, layout: HistLayout, cols: tuple[int, ...]):
-    """Dense (N, len(cols), n_bins, S) view of a static column subset.
-
-    The categorical-fallback hook of the fused split pipeline: the mean-sort
-    categorical branch needs its columns as an ordinary (N, Cc, B, S)
-    tensor. Only the tiles containing those columns are gathered and
-    unscrambled — O(Cc·N·B·S) HBM, not the full histogram."""
-    L = layout
-    tile_ids = sorted({c // L.ct for c in cols})
-    pos = {t: i for i, t in enumerate(tile_ids)}
-    sub = blk[jnp.asarray(tile_ids)]  # (T, NN*ns, ct*bpad)
-    sub = sub.reshape(len(tile_ids), L.nn, L.ns, L.bpad, L.ct)
-    # (T, ct, NN, bpad, ns) → rows per (tile, col-in-tile)
-    sub = jnp.transpose(sub, (0, 4, 1, 3, 2))
-    sub = sub.reshape(len(tile_ids) * L.ct, L.nn, L.bpad, L.ns)
-    rows = jnp.asarray([pos[c // L.ct] * L.ct + c % L.ct for c in cols])
-    out = sub[rows][:, : L.n_nodes, : L.n_bins, :]  # (Cc, N, B, S)
-    return jnp.transpose(out, (1, 0, 2, 3))
-
-
-def blocked_node_totals(blk, layout: HistLayout):
-    """Per-node {stat} totals from GLOBAL column 0 of a blocked histogram:
-    (n_nodes, S). Column 0 lives in tile 0, lane positions ``bin·ct + 0`` —
-    every row lights exactly one bin per column, so any single column's bin
-    sum is the node total (the replicated `_split_scan` uses column 0)."""
-    L = layout
-    t0 = blk[0].reshape(L.nn, L.ns, L.bpad, L.ct)[:, :, :, 0]  # (NN, S, bpad)
-    return t0.sum(axis=2)[: L.n_nodes]
-
-
-def relayout_nodes(layout: HistLayout, n_nodes_to: int) -> HistLayout:
-    """The layout of the SAME columns/bins re-planned for a different node
-    count (node tiling re-derived from the stored tile triple; the column
-    tiling — including any shard rounding baked into n_ct — is kept)."""
-    import dataclasses
-
-    p = plan_layout(layout.c, n_nodes_to, layout.n_bins, layout.ns,
-                    tiles=layout.tiles)
-    return dataclasses.replace(
-        layout, n_nodes=n_nodes_to, nt=p.nt, n_nt=p.n_nt
-    )
-
-
-def blocked_pad_nodes(blk, layout: HistLayout, n_nodes_to: int) -> tuple:
-    """Zero-pad the node axis to ``n_nodes_to`` (returns (blk2, layout2)).
-
-    Used by the saturated-region carry in the fused tree builder: the first
-    saturated level's parent frontier may be node_cap/2 wide and the
-    while_loop needs a loop-invariant shape."""
-    L = layout
-    L2 = relayout_nodes(L, n_nodes_to)
-    v = blk.reshape(L.n_ct, L.nn, L.ns, L.ct * L.bpad)
-    v = jnp.pad(v, ((0, 0), (0, L2.nn - L.nn), (0, 0), (0, 0)))
-    return v.reshape(L2.shape), L2
-
-
-def blocked_coarsen(blk, layout: HistLayout, ds: int) -> tuple:
-    """Sum adjacent data-bin groups of ``2**ds`` (NA bin passes through) —
-    ``shared_tree._coarsen_hist`` for the blocked layout. Returns
-    (blk2, layout2) at the coarsened bin count; the bin axis is a pure
-    lane-reshape of the tile, so no transpose pass touches HBM."""
-    import dataclasses
-
-    if ds == 0:
-        return blk, layout
-    L = layout
-    v = blk.reshape(L.n_ct, L.nn, L.ns, L.bpad, L.ct)
-    na = v[:, :, :, :1, :]
-    D = L.n_bins - 1
-    data = v[:, :, :, 1 : 1 + D, :]
-    group = 1 << ds
-    Dc = -(-D // group)
-    pad = Dc * group - D
-    if pad:
-        data = jnp.pad(data, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-    data = data.reshape(L.n_ct, L.nn, L.ns, Dc, group, L.ct).sum(4)
-    nb_c = Dc + 1
-    p = plan_layout(L.c, L.n_nodes, nb_c, L.ns, tiles=L.tiles)
-    L2 = dataclasses.replace(L, n_bins=nb_c, bpad=p.bpad)
-    out = jnp.concatenate(
-        [na, data,
-         jnp.zeros(data.shape[:3] + (L2.bpad - nb_c, L.ct), blk.dtype)],
-        axis=3,
-    )
-    return out.reshape(L2.shape), L2
 
 
 def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, *, nt, ct, bpad, ns):
@@ -504,44 +330,27 @@ def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, *, nt, ct, bpad, ns):
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("n_nodes", "n_bins", "interpret", "blocked", "tiles",
-                     "n_shards"),
+    jax.jit, static_argnames=("n_nodes", "n_bins", "interpret", "tiles"),
 )
 def hist_pallas_local(
     bins_u8, nid, stats, n_nodes: int, n_bins: int, interpret: bool = False,
-    blocked: bool = False, tiles: tuple | None = None, n_shards: int = 1,
+    tiles: tuple | None = None,
 ):
-    """Shard-local Pallas histogram.
+    """Shard-local Pallas histogram: (C, n_nodes*n_bins, S) float32.
 
     ``stats`` is the (n, S) stat matrix (S static from its shape). Drop-in
     replacement for ``_hist_matmul_local`` / ``_hist_scatter_local``.
     ``interpret=True`` runs the kernel in the Pallas interpreter (CPU CI).
-
-    ``blocked=False`` (default): returns (C, n_nodes*n_bins, S) float32 —
-    reached through two unscramble passes over the full tensor in HBM.
-    ``blocked=True``: returns the kernel's native tile layout
-    (:class:`HistLayout`, see :func:`plan_layout`) with NO unscramble pass —
-    the fused split pipeline consumes the tiles directly. ``tiles`` is the
-    static (row, col, node) tile triple (callers resolve the
-    ``H2O3_TPU_PALLAS_TILES`` knob via :func:`_tiles` so each tile choice
-    compiles its own executable). ``n_shards`` pads the column-tile count
-    for a downstream tiled psum_scatter (blocked mode only).
+    ``tiles`` is the static (row, col, node) tile triple (callers resolve
+    the ``H2O3_TPU_PALLAS_TILES`` knob via :func:`_tiles` so each tile
+    choice compiles its own executable).
     """
     n, c = bins_u8.shape
     ns = stats.shape[1]
     row_tile = (tiles or _tiles())[0]
-    # TILE GEOMETRY (ct/bpad/nt) comes from the sharded plan so the blocks
-    # match what the downstream scatter/split kernel expects, but the grid
-    # runs at the NATURAL tile count: the shard-rounding pad (blocked mode,
-    # n_shards > 1) is applied to the OUTPUT tensor below — zero tiles cost
-    # a cheap hist-sized pad instead of extra kernel grid work (the dense
-    # pipeline pads its histogram the same way)
-    lay_sh = plan_layout(c, n_nodes, n_bins, ns, tiles=tiles,
-                         n_shards=n_shards if blocked else 1)
-    nt, ct, bpad = lay_sh.nt, lay_sh.ct, lay_sh.bpad
-    n_nt = lay_sh.n_nt
-    n_ct = _cdiv(c, ct)  # natural (pre-shard-rounding) tile count
+    lay = plan_layout(c, n_nodes, n_bins, ns, tiles=tiles)
+    nt, ct, bpad = lay.nt, lay.ct, lay.bpad
+    n_nt, n_ct = lay.n_nt, lay.n_ct
     cpad = n_ct * ct
     n_r = max(_cdiv(n, row_tile), 1)
     npad = n_r * row_tile
@@ -573,40 +382,6 @@ def hist_pallas_local(
         ),
         transcendentals=0,
     )
-    if blocked:
-        blk_shape = (n_ct, lay_sh.nn * ns, ct * bpad)
-        out = pl.pallas_call(
-            kernel,
-            grid=(n_nt, n_ct, n_r),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, row_tile, ct),
-                    lambda nt_, ct_, r_: (ct_, r_, 0),
-                    memory_space=pltpu.VMEM,
-                ),
-                pl.BlockSpec(
-                    (row_tile, 1), lambda nt_, ct_, r_: (r_, 0),
-                    memory_space=pltpu.VMEM,
-                ),
-                pl.BlockSpec(
-                    (row_tile, ns), lambda nt_, ct_, r_: (r_, 0),
-                    memory_space=pltpu.VMEM,
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, nt * ns, ct * bpad),
-                lambda nt_, ct_, r_: (ct_, nt_, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            out_shape=jax.ShapeDtypeStruct(blk_shape, jnp.float32),
-            cost_estimate=cost,
-            interpret=interpret,
-            name="hist_pallas_blocked",  # the prefix is the trace readers' key
-        )(bins3, nid2, stats)
-        if lay_sh.n_ct > n_ct:
-            out = jnp.pad(out, ((0, lay_sh.n_ct - n_ct), (0, 0), (0, 0)))
-        return out
-
     out = pl.pallas_call(
         kernel,
         grid=(n_nt, n_ct, n_r),

@@ -125,8 +125,8 @@ def _select_local():
     kernel (hist_pallas.py) on TPU. ``H2O3_TPU_HIST=matmul`` forces the
     plain-XLA MXU path, ``=scatter`` forces the scatter path, and
     ``=pallas`` forces the Pallas kernel (in the interpreter on CPU — the
-    fused-pipeline parity/CI lane) on ANY backend, so A/B sweeps can reach
-    all three local impls everywhere.
+    CI lane) on ANY backend, so A/B sweeps can reach all three local impls
+    everywhere.
     """
     from h2o3_tpu import config
 
@@ -276,7 +276,7 @@ def _hist_matmul_local(bins_u8, nid, stats, n_nodes: int, n_bins: int):
 
 def histogram_in_jit(
     bins_u8, nid, stats, n_nodes: int, n_bins: int, mesh=None,
-    *, col_sharded: bool = False, fused: bool = False,
+    *, col_sharded: bool = False,
 ):
     """Cross-device histogram, traceable inside a jitted program.
 
@@ -294,18 +294,6 @@ def histogram_in_jit(
     are bit-identical to the same slice of the replicated reduction, which
     is what lets the downstream per-block winner merge reproduce the
     replicated argmax exactly.
-
-    ``fused=True`` (the ``H2O3_TPU_SPLIT_FUSE`` pipeline) returns
-    ``(blk, layout)`` instead: the histogram in the Pallas kernel's NATIVE
-    blocked tile layout (``hist_pallas.HistLayout``) with NO unscramble
-    pass — the split kernel (``ops/split_pallas.py``) consumes the tiles
-    directly in VMEM. Composes with ``col_sharded``: the reduce-scatter
-    then runs over axis 0 (whole column tiles → contiguous column ranges
-    per device) and the returned block is each device's 1/P slice; the full
-    histogram never exists replicated anywhere. When the selected local
-    impl is scatter/matmul (CPU CI, H2O3_TPU_HIST overrides) the dense
-    result is re-blocked locally — a correctness lane, counted honestly by
-    the HBM model.
     """
     mesh = mesh or get_mesh()
     local = _select_local()
@@ -314,12 +302,6 @@ def histogram_in_jit(
     n_col = n_col_shards(mesh)
     C = bins_u8.shape[1]
     Cp = pad_cols_to_shards(C, mesh) if col_sharded else C
-
-    if fused:
-        return _histogram_in_jit_fused(
-            bins_u8, nid, stats, n_nodes, n_bins, mesh, local,
-            col_sharded=col_sharded,
-        )
 
     from h2o3_tpu.ops import collectives
 
@@ -351,7 +333,7 @@ def histogram_in_jit(
 
     smat = jnp.stack(list(stats), axis=1)  # (n, S)
 
-    # HBM model of the unfused pipeline (see record_hbm): the dense tensor
+    # HBM model of hist + split (see record_hbm): the dense tensor
     # is written once and its (possibly column-sharded) slice re-read by the
     # split scan; the Pallas local impl additionally pays its two unscramble
     # passes over the padded kernel output. Terminal force-leaf levels skip
@@ -384,75 +366,6 @@ def histogram_in_jit(
         return jnp.transpose(
             h.reshape(h.shape[0], n_nodes, n_bins, S), (1, 0, 2, 3)
         )  # (n_nodes, C[p], n_bins, S)
-
-
-def _histogram_in_jit_fused(
-    bins_u8, nid, stats, n_nodes: int, n_bins: int, mesh, local,
-    *, col_sharded: bool,
-):
-    """Blocked-layout histogram body: see ``histogram_in_jit(fused=True)``."""
-    from h2o3_tpu.ops.hist_pallas import (
-        blocked_from_dense,
-        hist_pallas_local,
-        plan_layout,
-        tiles_for,
-    )
-
-    S = len(stats)
-    n_dev = int(mesh.devices.size)
-    n_col = n_col_shards(mesh)
-    C = bins_u8.shape[1]
-    is_pallas = _local_is_pallas(local)
-    layout = plan_layout(
-        C, n_nodes, n_bins, S, tiles=tiles_for(C, n_nodes, n_bins, S),
-        n_shards=n_col if col_sharded else 1,
-    )
-
-    from h2o3_tpu.ops import collectives
-
-    def body(b, n, s):
-        s = jnp.where((n >= 0)[:, None], s, 0.0)
-        if is_pallas:
-            h = hist_pallas_local(
-                b, n, s, n_nodes, n_bins,
-                interpret=jax.default_backend() == "cpu",
-                blocked=True, tiles=layout.tiles,
-                n_shards=n_col if col_sharded else 1,
-            )
-        else:
-            h = blocked_from_dense(
-                _maybe_i16(local)(b, n, s, n_nodes, n_bins), layout)
-        # whole-column-tile reduce through the collective lane (quantized /
-        # hierarchical when on, stock otherwise; 2-D meshes stage the exact
-        # rows-axis psum first) — it records the hist_reduce tally per lane
-        if not col_sharded:
-            return collectives.psum(
-                h, n_dev=n_dev, phase="hist_reduce", mesh=mesh)
-        return collectives.psum_scatter(
-            h, n_dev=n_dev, phase="hist_reduce", mesh=mesh)
-
-    smat = jnp.stack(list(stats), axis=1)
-    # HBM model (see record_hbm): the blocked tensor is written once by the
-    # kernel and its (possibly 1/P) slice read once by the split kernel —
-    # no unscramble pass exists. The dense-impl lane re-blocks locally and
-    # pays for the dense intermediate it materializes.
-    blk_scan = layout.nbytes / n_col if col_sharded else layout.nbytes
-    if is_pallas:
-        record_hbm("fused", layout.nbytes + blk_scan)
-    else:
-        dense_b = C * n_nodes * n_bins * S * 4
-        record_hbm("fused_via_dense", 2 * dense_b + layout.nbytes + blk_scan)
-
-    rspec = row_pspec(mesh)
-    with jax.named_scope("ph_hist"):
-        blk = shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(rspec, rspec, rspec),
-            out_specs=col_block_spec(0, mesh) if col_sharded else P(),
-            check_vma=False,
-        )(bins_u8, nid, smat)
-    return blk, layout
 
 
 _BUILD_HIST_PROG: dict = {}

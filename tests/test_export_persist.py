@@ -190,7 +190,9 @@ def test_binary_save_load_roundtrip(tmp_path, builder, kw):
 
 def test_generic_model_reimport_scores_live(tmp_path):
     """hex.generic successor: a tmojo zip re-imported as a live model
-    predicts identically to the original in-cluster model."""
+    predicts identically to the original in-cluster model: probabilities to
+    float32 rounding, and every label the same — the artifact scores in
+    float64, so the carried max-F1 threshold must not sit on a score."""
     df = _df(seed=14)
     fr = Frame.from_pandas(df)
     m = GBM(ntrees=5, max_depth=3, seed=3).train(y="y", training_frame=fr)
@@ -205,8 +207,45 @@ def test_generic_model_reimport_scores_live(tmp_path):
     )
     la = pa.vec("predict").to_numpy()
     lb = pb.vec("predict").to_numpy()
-    assert (la == lb).mean() > 0.999  # labels use the carried F1 threshold
+    np.testing.assert_array_equal(la, lb)
+    assert 0 < la.mean() < 1  # both classes are labelled
     assert g.output["source_algo"] == "gbm"
+
+
+def test_generic_model_carries_the_live_threshold(tmp_path):
+    """The threshold a binomial model labels with travels unchanged through
+    ``download_mojo`` / ``import_mojo`` and the offline scorer, and it
+    separates the scores: the max-F1 threshold of a tree model's metrics
+    table is one of its (tied) scores, so the labels' threshold lies strictly
+    between that score and the next one below, and a score that comes back
+    one float32 ulp off (another precision, another backend) keeps its
+    label."""
+    from h2o3_tpu.genmodel import MojoModel
+
+    df = _df(seed=14)
+    fr = Frame.from_pandas(df)
+    m = GBM(ntrees=5, max_depth=3, seed=3).train(y="y", training_frame=fr)
+    path = str(tmp_path / "g.zip")
+    m.download_mojo(path)
+    thr = m.training_metrics.default_threshold
+    g = h2o3_tpu.import_mojo(path)
+    off = MojoModel.load(path)
+    assert g.training_metrics.default_threshold == thr
+    assert off.meta["default_threshold"] == thr
+
+    p = m.predict(fr).vec("pos").to_numpy().astype(np.float32)
+    scores = np.unique(p)
+    f1_score = m.training_metrics._v["max_criteria"]["max_f1"]["threshold"]
+    k = int(np.searchsorted(scores, np.float32(f1_score)))
+    assert scores[k] == np.float32(f1_score) and k > 0  # it IS a score
+    assert scores[k - 1] < thr < scores[k]
+    live = m.predict(fr).vec("predict").to_numpy()
+    for nudged in (np.nextafter(p, np.float32(0)), np.nextafter(p, np.float32(1))):
+        np.testing.assert_array_equal((nudged >= thr).astype(live.dtype), live)
+    offline = off.predict(df.drop(columns="y"))
+    dom = list(m.output["response_domain"])
+    np.testing.assert_array_equal(
+        np.asarray([dom.index(v) for v in offline["predict"]]), live)
 
 
 def test_pojo_standalone_scoring(tmp_path):

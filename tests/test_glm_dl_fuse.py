@@ -272,7 +272,7 @@ def _free_compile_state():
     the ISSUE-15 suites add dozens of programs (fused multinomial on three
     sub-meshes, dropout lanes) to a long-lived tier-1 process that this
     jaxlib's CPU backend can otherwise crash compiling into (see the
-    test_split_pallas twin of this helper); later tests re-read the
+    test_hist_pallas twin of this helper); later tests re-read the
     persistent compile cache, so the wall cost is small."""
     jax.clear_caches()
 

@@ -5,6 +5,9 @@ adversarial shape grid: tile boundaries, NA bin occupancy, categorical
 codes, ragged row counts, retired rows, and the 2-term bf16 split's
 accuracy bound."""
 
+import contextlib
+import os
+
 import numpy as np
 import pytest
 
@@ -13,6 +16,28 @@ import jax.numpy as jnp
 
 from h2o3_tpu.ops.hist_pallas import NODE_TILE, ROW_TILE, hist_pallas_local
 from h2o3_tpu.ops.histogram import _hist_scatter_local
+
+
+@contextlib.contextmanager
+def _env(**kv):
+    old = {k: os.environ.get(k) for k in kv}
+    os.environ.update({k: str(v) for k, v in kv.items()})
+    try:
+        yield
+    finally:
+        for k, v in old.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
+def _free_compile_state():
+    """Drop in-memory compiled executables after a compile-heavy test: past
+    several hundred whole-tree-sized programs in one tier-1 process this
+    jaxlib's CPU backend can segfault inside XLA codegen on the NEXT large
+    compile (fresh-process compiles of the identical HLO are fine)."""
+    jax.clear_caches()
 
 
 def _make_case(n, c, n_nodes, n_bins, seed, na_frac=0.1, retired_frac=0.1,
@@ -125,97 +150,6 @@ def test_pallas_categorical_codes_roundtrip():
     assert abs(float(np.asarray(got)[0, :, 0].sum()) - n) < 1e-3
 
 
-class TestBinAdaptivity:
-    """Per-level bin coarsening (DHistogram re-binning analog) — the
-    coarsened histogram must equal the coarsened full histogram, and the
-    adaptive tree must match the full-bin tree's quality with full-res
-    recorded thresholds."""
-
-    def test_coarsen_hist_matches_hist_of_coarse_bins(self):
-        import jax.numpy as jnp
-
-        from h2o3_tpu.models.tree.shared_tree import (
-            _coarse_nbins, _coarsen_bins, _coarsen_hist,
-        )
-        from h2o3_tpu.ops.histogram import histogram_in_jit
-
-        rng = np.random.default_rng(0)
-        n, c, nb = 4096, 3, 255
-        bins = jnp.asarray(rng.integers(0, nb, (n, c)).astype(np.uint8))
-        nid = jnp.asarray(rng.integers(0, 4, n).astype(np.int32))
-        w = jnp.ones(n, jnp.float32)
-        wy = jnp.asarray(rng.normal(size=n).astype(np.float32))
-        full = histogram_in_jit(bins, nid, (w, wy, w), 4, nb)
-        for s in (1, 2):
-            nb_c = _coarse_nbins(nb, s)
-            direct = histogram_in_jit(
-                _coarsen_bins(bins, s), nid, (w, wy, w), 4, nb_c
-            )
-            via = _coarsen_hist(full, s)
-            np.testing.assert_allclose(
-                np.asarray(via), np.asarray(direct), rtol=1e-5, atol=1e-4
-            )
-
-    @pytest.mark.slow  # ~40 s; adaptivity is default-off (measured slower on
-    # v5e) so the quality scenario runs nightly-style, the cheap coarsen
-    # equivalence below stays in the default tier
-    def test_adaptive_tree_quality_and_full_res_thresholds(self, monkeypatch):
-        import jax
-        import jax.numpy as jnp
-
-        from h2o3_tpu.models.tree import shared_tree as st
-        from h2o3_tpu.models.tree.distributions import grad_hess
-
-        rng = np.random.default_rng(1)
-        n, c = 8192, 6
-        X = rng.normal(size=(n, c)).astype(np.float32)
-        y = (X[:, 0] + 0.6 * X[:, 1] ** 2 + 0.3 * rng.normal(size=n) > 0.4)
-        # quantile-ish binning to 255 data bins
-        bins = np.zeros((n, c), np.uint8)
-        for j in range(c):
-            q = np.quantile(X[:, j], np.linspace(0, 1, 255)[1:-1])
-            bins[:, j] = np.searchsorted(q, X[:, j]) + 1
-        bins_d = jnp.asarray(bins)
-        w = jnp.ones(n, jnp.float32)
-        yy = jnp.asarray(y.astype(np.float32))
-
-        def auc_of(preds):
-            from sklearn.metrics import roc_auc_score
-
-            return roc_auc_score(y, np.asarray(preds))
-
-        def train(adapt):
-            monkeypatch.setenv("H2O3_TPU_BIN_ADAPT", "1" if adapt else "0")
-            st._STEP_CACHE.clear()
-            F, vi, stacked = st.build_trees_scanned(
-                bins_d, w, yy, jnp.zeros(n, jnp.float32),
-                jnp.zeros(c, jnp.float32), jax.random.PRNGKey(0), 10,
-                grad_fn=lambda F_, y_, w_: grad_hess("bernoulli", F_, y_, w_, 0.0),
-                grad_key=("adapt_test", adapt),
-                sample_rate=1.0, n_bins=255, is_cat_cols=np.zeros(c, bool),
-                max_depth=6, min_rows=10.0, min_split_improvement=1e-5,
-                learn_rates=np.full(10, 0.3, np.float32),
-                max_abs_leaf=float("inf"),
-                col_sample_rate=1.0, col_sample_rate_per_tree=1.0,
-            )
-            trees = st.trees_from_stacked(stacked, 10)
-            return np.asarray(F), trees
-
-        try:
-            f_off, _ = train(False)
-            f_on, trees_on = train(True)
-        finally:
-            st._STEP_CACHE.clear()
-        a_off, a_on = auc_of(f_off), auc_of(f_on)
-        assert a_on > a_off - 0.01, (a_on, a_off)
-        # recorded thresholds are FULL-resolution: replaying the adaptive
-        # trees against the full-res bins reproduces the training scores
-        preds = jnp.zeros(n, jnp.float32)
-        for t in trees_on:
-            _, preds = t.replay(bins_d, jnp.zeros(n, jnp.int32), preds)
-        np.testing.assert_allclose(np.asarray(preds), f_on, rtol=1e-5, atol=1e-5)
-
-
 def test_scatter_chunked_matches_unchunked(monkeypatch):
     """The lax.scan row-chunked scatter (memory bound for big shards) must
     agree with the single-chunk path it replaces. Chunk forced tiny so the
@@ -235,3 +169,164 @@ def test_scatter_chunked_matches_unchunked(monkeypatch):
     monkeypatch.setattr(H, "_SCATTER_ROW_CHUNK", 96)  # 1000 -> 11 chunks + pad
     out = H._hist_scatter_local(bins, nid, stats, n_nodes, n_bins)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6)
+
+
+def test_tile_autotuner_sweeps_once_per_bucket(tmp_path, monkeypatch):
+    """H2O3_TPU_PALLAS_TILES=auto (ISSUE 15 / ROADMAP 4b): the first
+    resolve of a shape bucket runs ONE micro-sweep, a same-bucket resolve
+    adds zero (counter-pinned), the winner persists to the compile-cache
+    dir (a fresh in-process cache reads it back sweep-free), and explicit
+    'ROW,COL,NODE' values bypass the tuner unchanged. The grid shrinks to
+    two candidates here — the test pins the CACHING contract, not sweep
+    quality, and the full grid's 12 interpret-mode compiles would bloat
+    the tier-1 process (see _free_compile_state)."""
+    from h2o3_tpu.ops import hist_pallas as hp
+    from h2o3_tpu.utils import metrics as mx
+
+    monkeypatch.setattr(
+        hp, "_sweep_grid", lambda c, n: [(256, 4, 32), (512, 8, 64)])
+    with _env(H2O3_TPU_PALLAS_TILES="auto",
+              JAX_COMPILATION_CACHE_DIR=str(tmp_path)):
+        s0 = mx.counter_value("pallas_tile_sweeps_total")
+        tiles = hp.tiles_for(12, 64, 32, 3)
+        assert mx.counter_value("pallas_tile_sweeps_total") == s0 + 1
+        assert len(tiles) == 3 and all(v > 0 for v in tiles)
+        # same bucket (cols round to 16, nodes/bins to pow2): zero sweeps
+        assert hp.tiles_for(10, 50, 30, 3) == tiles
+        assert mx.counter_value("pallas_tile_sweeps_total") == s0 + 1
+        # cold in-process cache, warm persistent store: still zero sweeps
+        hp._TUNED_TILES.clear()
+        assert hp.tiles_for(12, 64, 32, 3) == tiles
+        assert mx.counter_value("pallas_tile_sweeps_total") == s0 + 1
+    with _env(H2O3_TPU_PALLAS_TILES="256,4,32"):
+        assert hp.tiles_for(12, 64, 32, 3) == (256, 4, 32)
+        assert mx.counter_value("pallas_tile_sweeps_total") == s0 + 1
+    _free_compile_state()
+
+
+def test_pallas_tiles_knob():
+    """H2O3_TPU_PALLAS_TILES reshapes the kernel grid (the sweep hook) and
+    the result still matches the default-tile kernel within the bf16
+    envelope; a malformed spec fails loudly."""
+    from h2o3_tpu.ops import hist_pallas as hp
+
+    rng = np.random.default_rng(21)
+    n, c, N, B = 1000, 11, 8, 17
+    bins = jnp.asarray(rng.integers(0, B, (n, c)).astype(np.uint8))
+    nid = jnp.asarray(rng.integers(0, N, n).astype(np.int32))
+    stats = jnp.asarray(
+        np.stack([np.ones(n), rng.normal(size=n), np.ones(n)], 1)
+        .astype(np.float32))
+
+    base = hp.hist_pallas_local(
+        bins, nid, stats, N, B, interpret=True, tiles=hp._tiles())
+    with _env(H2O3_TPU_PALLAS_TILES="256,4,32"):
+        tiles = hp._tiles()
+        assert tiles == (256, 4, 32)
+        lay = hp.plan_layout(c, N, B, 3, tiles=tiles)
+        assert lay.ct == 4 and lay.nt == 8  # nt clamps to n_nodes
+        swept = hp.hist_pallas_local(
+            bins, nid, stats, N, B, interpret=True, tiles=tiles)
+    np.testing.assert_allclose(
+        np.asarray(swept), np.asarray(base), rtol=1e-4, atol=1e-3)
+    with _env(H2O3_TPU_PALLAS_TILES="16,0"):
+        with pytest.raises(ValueError):
+            hp._tiles()
+
+
+@pytest.mark.parametrize("c,n_nodes,n_bins,ns", [
+    (28, 1, 255, 3), (32, 64, 256, 3), (32, 2048, 256, 3), (5, 80, 17, 4)])
+def test_plan_layout_is_the_kernels_geometry(c, n_nodes, n_bins, ns):
+    """``plan_layout`` is what ``hist_pallas_local`` tiles by and what the
+    modelled-bytes tally (``path=pallas_unfused``) sizes the kernel's padded
+    output from: whole tiles cover the problem, the lane dimension is a
+    multiple of 128, and ``nbytes`` is the float32 size of that output."""
+    from h2o3_tpu.ops import hist_pallas as hp
+
+    lay = hp.plan_layout(c, n_nodes, n_bins, ns)
+    assert lay.nt == min(hp.NODE_TILE, n_nodes) and lay.ct == min(hp.COL_TILE, c)
+    assert (lay.ct * lay.bpad) % 128 == 0 and n_bins <= lay.bpad < n_bins + 128
+    assert (lay.n_nt - 1) * lay.nt < n_nodes <= lay.n_nt * lay.nt
+    assert (lay.n_ct - 1) * lay.ct < c <= lay.n_ct * lay.ct
+    rows, lanes = lay.n_nt * lay.nt * ns, lay.n_ct * lay.ct * lay.bpad
+    assert lay.nbytes == 4 * rows * lanes
+    out = jax.eval_shape(
+        lambda b, n, s: hp.hist_pallas_local(b, n, s, n_nodes, n_bins,
+                                             interpret=True),
+        jax.ShapeDtypeStruct((64, c), jnp.uint8),
+        jax.ShapeDtypeStruct((64,), jnp.int32),
+        jax.ShapeDtypeStruct((64, ns), jnp.float32))
+    assert out.shape == (c, n_nodes * n_bins, ns)  # what the unscramble leaves
+
+
+def _hbm_paths() -> dict:
+    """Every ``tree_hist_hbm_bytes_total`` sample, by its ``path`` label."""
+    from h2o3_tpu.utils import metrics as mx
+
+    fam = mx.REGISTRY._families["tree_hist_hbm_bytes_total"]
+    return {lab["path"]: float(v) for lab, v in fam.samples() if "path" in lab}
+
+
+@pytest.mark.parametrize("hist,path", [("", "dense"), ("pallas", "pallas_unfused")])
+def test_hist_hbm_counter_paths_of_a_default_build(hist, path):
+    """The modelled-bytes tally of a default GBM build writes one histogram
+    path — ``dense`` (the CPU's scatter) or ``pallas_unfused`` (the chip's
+    kernel, here in the interpreter) — and ``rebin`` for the binning pass;
+    a second build on the same frame re-reads the cached codes and adds no
+    ``rebin`` bytes. ``tree_rebin_bytes_per_call`` reads ``path=rebin``."""
+    import pandas as pd
+
+    from h2o3_tpu.frame.frame import Frame
+    from h2o3_tpu.models.tree import GBM
+
+    rng = np.random.default_rng(12)
+    n = 1024
+    df = pd.DataFrame(rng.normal(size=(n, 4)), columns=list("abcd"))
+    df["y"] = df["a"] - df["b"] + 0.1 * rng.normal(size=n)
+    fr = Frame.from_pandas(df)
+
+    def build():
+        before = _hbm_paths()
+        GBM(ntrees=2, max_depth=3, seed=3).train(y="y", training_frame=fr)
+        return {k: v - before.get(k, 0.0) for k, v in _hbm_paths().items()
+                if v != before.get(k, 0.0)}
+
+    with _env(H2O3_TPU_HIST=hist):
+        first, second = build(), build()
+    assert set(first) == {path, "rebin"}, first
+    assert first["rebin"] == 5.0 * fr.npad * 4  # one f32 read + one u8 write
+    assert set(second) == {path} and second[path] == first[path], second
+    _free_compile_state()
+
+
+def test_kernel_key_follows_tiles_and_hist_override():
+    """``_kernel_key()`` is what keeps a cached tree program from serving
+    another kernel configuration: the tile triple, the raw tile spec
+    ('auto' resolves to the built-in triple, so only the raw spec tells it
+    from ''), ``H2O3_TPU_HIST`` and ``HIST_I16`` — and nothing else."""
+    from h2o3_tpu.models.tree import shared_tree as st
+    from h2o3_tpu.ops import hist_pallas as hp
+
+    with _env(H2O3_TPU_PALLAS_TILES="", H2O3_TPU_HIST="",
+              H2O3_TPU_HIST_I16="0"):
+        base = st._kernel_key()
+        assert base == ((hp.ROW_TILE, hp.COL_TILE, hp.NODE_TILE), "", "", False)
+        keys = {base}
+        for knob, value in (("H2O3_TPU_PALLAS_TILES", "256,4,32"),
+                            ("H2O3_TPU_PALLAS_TILES", "auto"),
+                            ("H2O3_TPU_HIST", "matmul"),
+                            ("H2O3_TPU_HIST", "pallas"),
+                            ("H2O3_TPU_HIST_I16", "1")):
+            with _env(**{knob: value}):
+                keys.add(st._kernel_key())
+        assert len(keys) == 6, keys
+        with _env(H2O3_TPU_PALLAS_TILES="auto"):
+            assert st._kernel_key()[0] == base[0]  # same triple, other key
+        # a level program is cached under it: a flip compiles a new one
+        st._level_step(1, 2, 16, False)
+        n0 = len(st._STEP_CACHE)
+        st._level_step(1, 2, 16, False)
+        assert len(st._STEP_CACHE) == n0
+        with _env(H2O3_TPU_HIST="matmul"):
+            st._level_step(1, 2, 16, False)
+        assert len(st._STEP_CACHE) == n0 + 1

@@ -338,3 +338,48 @@ def test_gbm_streaming_kill_and_resume_matches_uninterrupted(tmp_path):
         resumed.training_metrics.logloss, full.training_metrics.logloss,
         atol=1e-6)
     np.testing.assert_allclose(_p1(resumed, fr), _p1(full, fr), atol=1e-6)
+
+
+def test_streamed_mono_matches_resident():
+    """Satellite: the streamed-GBM gate accepts monotone builds — the
+    bound state is per-node, so it rides the host level loop across row
+    blocks. Split decisions must equal the resident mono build's
+    level-for-level (same integer-tie regime as the oocore pins), preds
+    within the block-summation envelope."""
+    import pandas as pd
+
+    from h2o3_tpu.frame.frame import Frame
+    from h2o3_tpu.models.tree import GBM
+
+    rng = np.random.default_rng(41)
+    n = 4096
+    df = pd.DataFrame({
+        "a": rng.integers(0, 50, n).astype(np.float64),
+        "b": rng.normal(size=n),
+        "c": rng.normal(size=n),
+    })
+    df["y"] = (df["a"] * 0.1 + 0.5 * df["b"]
+               + 0.1 * rng.normal(size=n)).astype(np.float64)
+    kw = dict(ntrees=4, max_depth=3, seed=7,
+              monotone_constraints={"a": 1})
+
+    def run(window):
+        env = {"H2O3_TPU_HBM_WINDOW_BYTES": window} if window else {}
+        with _env(**env):
+            fr = Frame.from_pandas(df)
+            m = GBM(**kw).train(y="y", training_frame=fr)
+            pr = m.predict(fr)
+            return m, pr.vec(pr.names[-1]).to_numpy()
+
+    m_res, p_res = run(None)
+    # ~8 blocks through a 1/8th window
+    bytes_per_row = 3 + 28
+    m_str, p_str = run(str(n * bytes_per_row // 8))
+    np.testing.assert_allclose(p_str, p_res, rtol=1e-5, atol=1e-5)
+    for g_res, g_str in zip(m_res.output["trees"], m_str.output["trees"]):
+        for lv_r, lv_s in zip(g_res[0].to_host().levels,
+                              g_str[0].to_host().levels):
+            np.testing.assert_array_equal(lv_r.split_col, lv_s.split_col)
+            np.testing.assert_array_equal(lv_r.split_bin, lv_s.split_bin)
+    # drop the one-shot executables (see test_hist_pallas._free_compile_state)
+    jax.clear_caches()

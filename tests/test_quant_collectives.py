@@ -340,8 +340,7 @@ def test_sat_region_tally_counts_executed_not_nsat():
     with _use_mesh(8):
         n_pad = _pad_rows(600)
         rng = np.random.default_rng(11)
-        shifts = st._bin_shifts(8, 16, ())
-        assert st._sat_region(8, 8, shifts)[1] >= 2  # region must exist
+        assert st._sat_region(8, 8)[1] >= 2  # region must exist
         # early-exit data: one informative column with two values — after
         # the depth-0 split both children are single-bin pure nodes
         bins_small = rng.integers(1, 3, (n_pad, 3)).astype(np.uint8)

@@ -77,8 +77,10 @@ def _assert_trees_bit_equal(a: st.Tree, b: st.Tree, what: str):
 
 
 def _build_one(bins_np, t_np, *, split_shard: int, max_depth=3, n_bins=16,
-               node_cap=2048, min_rows=1.0, env=None, is_cat=None, seed=5):
-    """build_tree under the given H2O3_TPU_SPLIT_SHARD, on the CURRENT mesh."""
+               node_cap=2048, min_rows=1.0, env=None, is_cat=None, seed=5,
+               monotone=None):
+    """build_tree under the given H2O3_TPU_SPLIT_SHARD, on the CURRENT mesh.
+    ``monotone`` sends the build down the per-level mono loop."""
     n, C = bins_np.shape
     with _env(H2O3_TPU_SPLIT_SHARD=split_shard, **(env or {})):
         bins = pm.shard_rows(jnp.asarray(bins_np))
@@ -98,6 +100,7 @@ def _build_one(bins_np, t_np, *, split_shard: int, max_depth=3, n_bins=16,
             key=jax.random.PRNGKey(seed),
             varimp=jnp.zeros(C, jnp.float32),
             node_cap=node_cap,
+            monotone=monotone,
         )
         return tree, np.asarray(preds), np.asarray(varimp)
 
@@ -180,24 +183,158 @@ def test_parity_both_force_leaf_paths(subtract):
     assert _bits(p1) == _bits(p0) and _bits(v1) == _bits(v0)
 
 
-def test_parity_coarsened_saturated_levels():
-    """Deep tree with a small node_cap and bin adaptivity on: the saturated
-    while_loop region runs at COARSENED bins — the sharded scan must stay
-    bit-equal through the coarsen + sibling-subtraction carry."""
+@pytest.mark.parametrize("subtract", ["1", "0"])
+def test_parity_saturated_levels(subtract):
+    """Deep tree with a small node_cap: the sharded scan must stay bit-equal
+    through the saturated while_loop region — with its sibling-subtraction
+    carry, and in the direct scheme, whose loop threads a dummy parent carry
+    (the only sharded pins on that loop)."""
     n_pad = _pad_rows(600)
     rng = np.random.default_rng(11)
     bins = rng.integers(0, 255, (n_pad, 6)).astype(np.uint8)
     t = rng.normal(size=n_pad).astype(np.float32)
-    env = {"H2O3_TPU_BIN_ADAPT": "1", "H2O3_TPU_SHAPE_BUCKETS": "0"}
+    env = {"H2O3_TPU_SHAPE_BUCKETS": "0", "H2O3_TPU_HIST_SUBTRACT": subtract}
     kw = dict(max_depth=8, n_bins=255, node_cap=8)
     t1, p1, v1 = _build_one(bins, t, split_shard=1, env=env, **kw)
     t0, p0, v0 = _build_one(bins, t, split_shard=0, env=env, **kw)
     # the saturated region must actually exist for this shape, or the test
-    # is not exercising the coarsened while_loop at all
-    shifts = st._bin_shifts(8, 255, ())
-    assert st._sat_region(8, 8, shifts)[1] >= 2
-    _assert_trees_bit_equal(t1, t0, "coarsened-sat")
+    # is not exercising the while_loop at all
+    assert st._sat_region(8, 8) == (3, 5)
+    _assert_trees_bit_equal(t1, t0, f"saturated/subtract={subtract}")
     assert _bits(p1) == _bits(p0) and _bits(v1) == _bits(v0)
+
+
+# ---------------------------------------------------------------------------
+# the per-level monotone loop (every monotone build's path) and categorical
+# columns on a sharded mesh: the sharded scan against the replicated one on
+# the adversarial data that, until ISSUE 30, only a comparison with the
+# interpret-only fused split kernel covered
+
+
+def _replay_column_sweep(tree: st.Tree, C: int, col: int, n_bins: int = 16):
+    """The tree's prediction as a function of ``col``'s bin (1..n_bins-1;
+    bin 0 is the NA slot and direction-free), every other column held at
+    bin 8."""
+    probe = np.full((n_bins, C), 8, np.uint8)
+    probe[:, col] = np.arange(n_bins)
+    _, pp = tree.replay(jnp.asarray(probe), jnp.zeros(n_bins, jnp.int32),
+                        jnp.zeros(n_bins, jnp.float32))
+    return np.asarray(pp)[1:]
+
+
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_mono_tie_break(k):
+    """Constant target, every column a duplicate, five columns constrained:
+    every candidate is feasible (all child values are equal) and every gain
+    is exactly 0.0, so only lowest-global-index tie-breaking picks the
+    winner — in the mono scan's masked argmax and in the sharded merge."""
+    with _use_mesh(k):
+        n_pad = _pad_rows(960)
+        bins, t = _tie_data(n_pad, C=13, n_bins=16, dup_all=True)
+        mono = np.zeros(13, np.int32)
+        mono[[0, 4, 9]] = 1
+        mono[[2, 7]] = -1
+        t1, p1, v1 = _build_one(bins, t, split_shard=1, monotone=mono)
+        t0, p0, v0 = _build_one(bins, t, split_shard=0, monotone=mono)
+        _assert_trees_bit_equal(t1, t0, f"mono-ties/{k}dev")
+        assert _bits(p1) == _bits(p0) and _bits(v1) == _bits(v0)
+        assert int(np.asarray(t1.levels[0].split_col)[0]) == 0
+
+
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_mono_constrained_signal(k):
+    """A real signal that VIOLATES the constraint: the target falls with
+    column 0, which is constrained +1. The sharded mono scan must match the
+    replicated one bit for bit (integer-exact sums) and the fitted function
+    must be non-decreasing along column 0, while an unconstrained build of
+    the same data is not (so the constraint did the work)."""
+    with _use_mesh(k):
+        n_pad = _pad_rows(960)
+        rng = np.random.default_rng(31)
+        bins = rng.integers(1, 16, (n_pad, 6)).astype(np.uint8)
+        t = (16.0 - bins[:, 0].astype(np.float32)
+             + rng.integers(-2, 3, n_pad).astype(np.float32))
+        mono = np.zeros(6, np.int32)
+        mono[0] = 1
+        t1, p1, v1 = _build_one(bins, t, split_shard=1, monotone=mono,
+                                max_depth=4)
+        t0, p0, v0 = _build_one(bins, t, split_shard=0, monotone=mono,
+                                max_depth=4)
+        _assert_trees_bit_equal(t1, t0, f"mono-signal/{k}dev")
+        assert _bits(p1) == _bits(p0) and _bits(v1) == _bits(v0)
+        sweep = _replay_column_sweep(t1, 6, 0)
+        assert (np.diff(sweep) >= -1e-6).all(), sweep
+        free, _, _ = _build_one(bins, t, split_shard=1, max_depth=4)
+        assert (np.diff(_replay_column_sweep(free, 6, 0)) < -1e-3).any()
+
+
+@pytest.mark.parametrize("k", [2, 8])
+def test_mono_with_categoricals_sharded(k):
+    """A monotone build on a frame that also has categorical columns, on a
+    sharded mesh: every block runs the mean-sort branch on its local
+    columns and the feasibility mask on its numeric ones; categorical
+    winners carry ``mono_col`` 0, so their children inherit the parent's
+    bounds. Bit parity with the replicated scan, a categorical split used
+    somewhere, and the constrained column still monotone."""
+    with _use_mesh(k):
+        n_pad = _pad_rows(960)
+        rng = np.random.default_rng(43)
+        bins = rng.integers(1, 16, (n_pad, 7)).astype(np.uint8)
+        bins[:, 2] = rng.integers(0, 7, n_pad)   # cat col, 6 levels
+        bins[:, 5] = rng.integers(0, 5, n_pad)   # cat col, 4 levels
+        is_cat = np.zeros(7, bool)
+        is_cat[[2, 5]] = True
+        t = (3.0 * (bins[:, 2] % 3).astype(np.float32)
+             - bins[:, 0].astype(np.float32)
+             + rng.integers(-2, 3, n_pad).astype(np.float32))
+        mono = np.zeros(7, np.int32)
+        mono[0] = 1
+        kw = dict(is_cat=is_cat, monotone=mono, max_depth=4)
+        t1, p1, v1 = _build_one(bins, t, split_shard=1, **kw)
+        t0, p0, v0 = _build_one(bins, t, split_shard=0, **kw)
+        _assert_trees_bit_equal(t1, t0, f"mono-cat/{k}dev")
+        assert _bits(p1) == _bits(p0) and _bits(v1) == _bits(v0)
+        assert any(
+            np.asarray(lv.is_cat)[~np.asarray(lv.leaf_now) & m].any()
+            for lv, m in zip(t1.to_host().levels, t1.real_level_masks())
+        )
+        sweep = _replay_column_sweep(t1, 7, 0)
+        assert (np.diff(sweep) >= -1e-6).all(), sweep
+
+
+@pytest.mark.parametrize("k", [2, 8])
+def test_cat_sharded_tie_break(k):
+    """Duplicated categorical columns spanning column blocks plus duplicated
+    numeric columns: the winner merge must still be lowest-global-index,
+    bit-equal to the replicated scan, with the (N, B) membership mask riding
+    the winner gather."""
+    with _use_mesh(k):
+        n_pad = _pad_rows(960)
+        rng = np.random.default_rng(37)
+        base_cat = rng.integers(0, 7, n_pad).astype(np.uint8)
+        base_num = rng.integers(1, 16, n_pad).astype(np.uint8)
+        # 10 columns: cat duplicates at 1,4,8 / numeric duplicates elsewhere
+        bins = np.tile(base_num[:, None], (1, 10))
+        is_cat = np.zeros(10, bool)
+        for c in (1, 4, 8):
+            bins[:, c] = base_cat
+            is_cat[c] = True
+        t = rng.integers(-3, 4, n_pad).astype(np.float32)
+        t1, p1, v1 = _build_one(bins, t, split_shard=1, is_cat=is_cat,
+                                max_depth=4)
+        t0, p0, v0 = _build_one(bins, t, split_shard=0, is_cat=is_cat,
+                                max_depth=4)
+        _assert_trees_bit_equal(t1, t0, f"cat-sharded-ties/{k}dev")
+        assert _bits(p1) == _bits(p0) and _bits(v1) == _bits(v0)
+        # a categorical split must actually win somewhere, and among the
+        # duplicated columns of a kind only the LOWEST index may appear
+        used = {True: set(), False: set()}
+        for lv, m in zip(t1.to_host().levels, t1.real_level_masks()):
+            sel = ~np.asarray(lv.leaf_now) & m
+            for cat in (True, False):
+                pick = sel & (np.asarray(lv.is_cat) == cat)
+                used[cat] |= set(np.asarray(lv.split_col)[pick].tolist())
+        assert used[True] == {1} and used[False] <= {0}, used
 
 
 def test_parity_categorical_and_model_level():

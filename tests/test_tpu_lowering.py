@@ -4,12 +4,12 @@ chip_smoke.py refuse to run without a chip?
 
 ``jax.export`` with ``platforms=["tpu"]`` runs the Pallas→Mosaic lowering on
 the CPU and reports an unsupported primitive in seconds — the check that
-would have caught ``H2O3_TPU_SPLIT_FUSE=auto`` selecting a kernel
-(``ops/split_pallas.py``) that had only ever run in the interpreter. Two
-halves: every Pallas kernel the chip default selects lowers at the headline
-geometry, and the fuse gate stays off on every backend for which the split
-kernel does not. A lowering verdict is not a run: ``chip_smoke.py`` is the
-proof that the program executes.
+would have caught PR 6's default selecting a split kernel that had only
+ever run in the interpreter (deleted in ISSUE 30). The Pallas kernel the
+chip default selects lowers at the headline geometry and at the widths of
+the other tree builders, and so does the whole tree chunk program, with
+that kernel and no other in it. A lowering verdict is not a run:
+``chip_smoke.py`` is the proof that the program executes.
 
 The cache-placement and smoke-rehearsal tests at the end start fresh
 interpreters (jax reads JAX_COMPILATION_CACHE_DIR at import).
@@ -28,7 +28,6 @@ import pytest
 from h2o3_tpu.models.tree import shared_tree as st
 from h2o3_tpu.ops import hist_pallas as hp
 from h2o3_tpu.ops import histogram as hg
-from h2o3_tpu.ops.split_pallas import split_candidates
 
 # the headline shape after shape bucketing: 28 -> 32 columns, 255 -> 256
 # bins, the GBM/DRF stat lanes {w, wy, wh}, default tiles
@@ -37,87 +36,190 @@ TILES = (hp.ROW_TILE, hp.COL_TILE, hp.NODE_TILE)
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _hist_args():
+def _hist_args(lanes=LANES):
     return (jax.ShapeDtypeStruct((ROWS, COLS), jnp.uint8),
             jax.ShapeDtypeStruct((ROWS,), jnp.int32),
-            jax.ShapeDtypeStruct((ROWS, LANES), jnp.float32))
+            jax.ShapeDtypeStruct((ROWS, lanes), jnp.float32))
 
 
 def _export_tpu(f, *args) -> str:
     return jax.export.export(jax.jit(f), platforms=["tpu"])(*args).mlir_module()
 
 
-@pytest.mark.parametrize("n_nodes", [1, 64])
-def test_default_histogram_kernel_lowers_for_tpu(monkeypatch, n_nodes):
+@pytest.mark.parametrize("n_nodes,lanes", [
+    (1, LANES), (64, LANES), (2048, LANES), (64, 4), (1024, 4)])
+def test_default_histogram_kernel_lowers_for_tpu(monkeypatch, n_nodes, lanes):
     """The local histogram impl the chip selects (``_select_local`` with the
     chip's branches taken): the Pallas kernel, compiled — not interpreted —
-    in the dense output mode the default lane consumes."""
+    at the frontier widths of a depth-6 GBM (1, 64), at DRF's ``node_cap``
+    (2048: 32 node tiles) and with uplift's four statistic lanes, at a
+    shallow frontier and at uplift's own ``node_cap`` (1024)."""
     monkeypatch.delenv("H2O3_TPU_HIST", raising=False)
     monkeypatch.delenv("H2O3_TPU_PALLAS_TILES", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     local = hg._select_local()
     assert hg._local_is_pallas(local)
     module = _export_tpu(
-        lambda b, n, s: local(b, n, s, n_nodes, BINS), *_hist_args())
+        lambda b, n, s: local(b, n, s, n_nodes, BINS), *_hist_args(lanes))
     assert "tpu_custom_call" in module
 
 
-@pytest.mark.parametrize("n_nodes", [1, 64])
-def test_blocked_histogram_kernel_lowers_for_tpu(n_nodes):
-    """The blocked output mode (what the tile autotuner sweeps) lowers too:
-    only the split kernel stands between the chip and the fused pipeline."""
-    f = functools.partial(
-        hp.hist_pallas_local, n_nodes=n_nodes, n_bins=BINS, interpret=False,
-        blocked=True, tiles=TILES)
-    assert "tpu_custom_call" in _export_tpu(f, *_hist_args())
-
-
-@pytest.mark.parametrize("blocked,name", [
-    (False, "hist_pallas_dense"), (True, "hist_pallas_blocked")])
-def test_histogram_kernels_keep_their_names_in_the_lowered_text(blocked, name):
+def test_histogram_kernel_keeps_its_name_in_the_lowered_text():
     """The profiler names a kernel's device events after its ``pallas_call``
     (``%hist_pallas_dense.66 = ... custom-call``), and the benchmark's
-    ``hist_kernel_roofline_pct`` finds them by ``^%?hist_pallas``: both
-    layouts carry a stable name with that prefix."""
+    ``hist_kernel_roofline_pct`` finds them by ``^%?hist_pallas``: the
+    kernel carries a stable name with that prefix."""
     import re
 
     f = functools.partial(
         hp.hist_pallas_local, n_nodes=64, n_bins=BINS, interpret=False,
-        blocked=blocked, tiles=TILES)
+        tiles=TILES)
     names = re.findall(r'kernel_name = "([^"]+)"', _export_tpu(f, *_hist_args()))
-    assert names == [name]
+    assert names == ["hist_pallas_dense"]
     assert re.match(r"^%?hist_pallas", names[0])
 
 
-def _split_lowers(platform: str) -> bool:
-    L = hp.plan_layout(COLS, 64, BINS, LANES, tiles=TILES)
-    f = functools.partial(split_candidates, layout=L, interpret=False)
+def _chunk_program(monkeypatch, max_depth, node_cap, rows=ROWS, sharding=None):
+    """The jitted 5-tree ``build_trees_scanned`` chunk program at 28 columns
+    and 255 bins with the chip's branches taken, and its operands as
+    shapes: what a ``gbm_higgs`` call dispatches, captured where
+    ``_run_counted`` would run it."""
+    import numpy as np
+
+    from h2o3_tpu.models.tree.distributions import grad_hess
+
+    monkeypatch.delenv("H2O3_TPU_HIST", raising=False)
+    monkeypatch.delenv("H2O3_TPU_PALLAS_TILES", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    captured = {}
+
+    def capture(fn, args, mult=1, sat_from=None):
+        captured.update(fn=fn, args=args)
+        raise LookupError("captured")
+
+    monkeypatch.setattr(st, "_run_counted", capture)
+    S = jax.ShapeDtypeStruct
+    cols = 28
+    with pytest.raises(LookupError, match="captured"):
+        st.build_trees_scanned(
+            S((rows, cols), jnp.uint8), S((rows,), jnp.float32),
+            S((rows,), jnp.float32), S((rows,), jnp.float32),
+            S((cols,), jnp.float32), jax.random.PRNGKey(42), 5,
+            grad_fn=lambda F, y, w: grad_hess("bernoulli", F, y, w, 0.0),
+            grad_key=("lowering", max_depth), sample_rate=1.0, n_bins=255,
+            is_cat_cols=np.zeros(cols, bool), max_depth=max_depth,
+            min_rows=10.0, min_split_improvement=1e-5,
+            learn_rates=np.full(5, 0.1), max_abs_leaf=np.inf,
+            col_sample_rate=1.0, col_sample_rate_per_tree=1.0,
+            node_cap=node_cap,
+        )
+    shapes = jax.tree_util.tree_map(
+        lambda a: S(np.shape(a), a.dtype if isinstance(a, S)
+                    else jnp.asarray(a).dtype, sharding=sharding),
+        captured["args"])
+    return captured["fn"], shapes
+
+
+@pytest.mark.parametrize("max_depth,node_cap", [(6, 2048), (20, 2048)])
+def test_tree_chunk_program_lowers_for_tpu(monkeypatch, max_depth, node_cap):
+    """The whole ``build_trees_scanned`` chunk program (the dispatch behind
+    every ``gbm_higgs`` tree, five trees a chunk) lowers for the TPU with
+    the chip's branches taken, and the only Pallas kernel in its text is
+    ``hist_pallas_dense``. Depth 6 is the benchmark's shape; depth 20 with
+    ``node_cap`` 2048 is a DRF's — the saturated ``lax.while_loop`` that has
+    never compiled on a chip (ROADMAP B-R3): one loop for the levels, one
+    for the scan over trees."""
+    import re
+
+    fn, shapes = _chunk_program(monkeypatch, max_depth, node_cap)
+    module = _export_tpu(fn, *shapes)
+    kernels = set(re.findall(r'kernel_name = "([^"]+)"', module))
+    assert kernels == {"hist_pallas_dense"}, kernels
+    want_loops = 1 if st._sat_region(max_depth, node_cap)[1] == 0 else 2
+    assert module.count("stablehlo.while") == want_loops
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e host: the TPU's compiler is installed
+    here and compiles for a chip that is not attached. Only this file's
+    worker loads the TPU's library, and only once a test asks for it."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
     try:
-        jax.export.export(
-            jax.jit(lambda b, t: f(b, t, 10.0)), platforms=[platform])(
-            jax.ShapeDtypeStruct(L.shape, jnp.float32),
-            jax.ShapeDtypeStruct((64, LANES), jnp.float32))
-    except (NotImplementedError, ValueError):
-        return False
-    return True
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: nothing to pin
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
 
 
-@pytest.mark.parametrize("backend", ["cpu", "tpu"])
-def test_fuse_gate_is_off_wherever_the_split_kernel_does_not_lower(
-        monkeypatch, backend):
-    """``_split_fuse_on()`` under 'auto' must not select a kernel the
-    compiler refuses. If a rewrite makes ``split_candidates`` lower for a
-    backend, this test stops constraining the gate there — flipping the
-    default then needs a chip run and a cell, not this file."""
-    if _split_lowers(backend):
-        pytest.skip(f"split_candidates lowers for {backend}: gate is free")
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    for auto in ("auto", ""):
-        monkeypatch.setenv("H2O3_TPU_SPLIT_FUSE", auto)
-        assert st._split_fuse_on() is False
-        assert st._split_fuse_active((), split_shard=False) is False
-    monkeypatch.setenv("H2O3_TPU_SPLIT_FUSE", "1")  # still means "force it"
-    assert st._split_fuse_on() is True
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip would be written to the persistent
+    cache and could never be read back without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("max_depth,fits", [(6, True), (7, True), (8, False)])
+def test_tree_chunk_program_compiles_for_a_described_v5e(
+        monkeypatch, v5e_chip, no_compile_cache, max_depth, fits):
+    """What the chip's own compiler says of the chunk program, with no chip
+    (a compile is not a run). Depth 6 — the benchmark's cells — and depth 7
+    compile. **From depth 8 on the program is REFUSED** (ROADMAP B-R3): the
+    first level that builds 64 nodes (one full node tile, ``f32[192, 8192]``)
+    has its kernel's output placed in VMEM by XLA inside the tree program
+    and overruns the kernel's 16 MB of scoped VMEM by 676 KB, although the
+    same kernel compiles alone at 64 and at 2,048 nodes. A depth-8 GBM and
+    every DRF at its default depth would fail at compile time on a v5e; the
+    ``fits=False`` case is the pin to flip when that is repaired."""
+    from jax.sharding import Mesh, SingleDeviceSharding
+
+    import numpy as np
+
+    from h2o3_tpu.parallel import mesh as pm
+
+    old = pm._mesh
+    pm.set_mesh(Mesh(np.array([v5e_chip]), (pm.ROWS_AXIS,)))
+    try:
+        fn, shapes = _chunk_program(
+            monkeypatch, max_depth, 2048, rows=65_536,
+            sharding=SingleDeviceSharding(v5e_chip))
+        lowered = fn.lower(*shapes)
+    finally:
+        pm.set_mesh(old)
+    if fits:
+        text = lowered.compile().as_text()
+        assert text.count("tpu_custom_call") >= max_depth  # a kernel a level
+    else:
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="RESOURCE_EXHAUSTED.*vmem.*hist_pallas_dense"):
+            lowered.compile()
+
+
+@pytest.mark.parametrize("n_nodes,lanes", [(64, LANES), (2048, LANES), (1024, 4)])
+def test_histogram_kernel_compiles_alone_for_a_described_v5e(
+        v5e_chip, no_compile_cache, n_nodes, lanes):
+    """The kernel by itself fits the chip's VMEM at a full node tile, at
+    DRF's ``node_cap`` and at uplift's with four lanes: the refusal above is
+    the tree program's, not the kernel's."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(v5e_chip)
+    f = functools.partial(
+        hp.hist_pallas_local, n_nodes=n_nodes, n_bins=BINS, interpret=False,
+        tiles=TILES)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+            for a in _hist_args(lanes)]
+    assert "tpu_custom_call" in jax.jit(f).lower(*args).compile().as_text()
 
 
 def test_tile_sweep_skips_only_candidates_that_do_not_fit(monkeypatch):
@@ -129,8 +231,7 @@ def test_tile_sweep_skips_only_candidates_that_do_not_fit(monkeypatch):
     monkeypatch.setattr(hp, "_sweep_grid", lambda c, n: grid)
 
     def fake(refuse, exc):
-        def hist(bins, nid, stats, n_nodes, n_bins, *, interpret, blocked,
-                 tiles):
+        def hist(bins, nid, stats, n_nodes, n_bins, *, interpret, tiles):
             if tiles in refuse:
                 raise exc
             return jnp.zeros(())

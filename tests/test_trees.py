@@ -528,35 +528,43 @@ def test_monotone_constraints_enforced():
                 pd.DataFrame({"x": x, "y": np.abs(y)})))
 
 
-@pytest.mark.slow
-def test_fused_whole_tree_deep_matches_per_level(monkeypatch):
-    """Depth beyond the old 12-level fused cap (VERDICT r3 weak #7): the
-    unrolled whole-tree program at depth 13 must equal the per-level
-    dispatch loop bit-for-bit (same inputs, same keys)."""
+@pytest.mark.parametrize("k,depth,node_cap", [
+    (1, 8, 16), (2, 8, 16), (8, 8, 16),
+    pytest.param(1, 13, 2048, marks=pytest.mark.slow),
+])
+def test_fused_whole_tree_deep_matches_per_level(monkeypatch, k, depth,
+                                                 node_cap):
+    """The whole-tree program — growth levels unrolled, the node_cap-
+    saturated run as a ``lax.while_loop`` — must equal the per-level
+    dispatch loop bit-for-bit (same inputs, same keys) on 1-, 2- and
+    8-device meshes (sharded scan on >1), and at a depth beyond the old
+    12-level fused cap (VERDICT r3 weak #7)."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import Mesh
 
     from h2o3_tpu.models.tree import shared_tree as st
+    from h2o3_tpu.parallel import mesh as pm
 
     rng = np.random.default_rng(5)
     n, c = 4096, 5
-    bins = jnp.asarray(rng.integers(1, 32, (n, c)).astype(np.uint8))
-    w = jnp.ones(n, jnp.float32)
-    t = jnp.asarray(rng.normal(size=n).astype(np.float32))
-    h = jnp.ones(n, jnp.float32)
     key = jax.random.PRNGKey(3)
-    depth = 13
+    assert st._sat_region(depth, node_cap)[1] >= 2  # the loop is in play
 
     def run(force_per_level: bool):
-        preds = jnp.zeros(n, jnp.float32)
+        bins = pm.shard_rows(jnp.asarray(bins_np))
+        w = pm.shard_rows(jnp.ones(n, jnp.float32))
+        t = pm.shard_rows(jnp.asarray(t_np))
+        h = pm.shard_rows(jnp.ones(n, jnp.float32))
+        preds = pm.shard_rows(jnp.zeros(n, jnp.float32))
         vi = jnp.zeros(c, jnp.float32)
         if force_per_level:
-            nid = jnp.zeros(n, jnp.int32)
-            tree = st.Tree()
+            nid = pm.shard_rows(jnp.zeros(n, jnp.int32))
             for d in range(depth + 1):
-                n_pad = min(1 << d, 2048)
-                n_pad_next = min(2 * n_pad, 2048)
-                step = st._level_step(n_pad, n_pad_next, 32, d == depth, ())
+                n_pad = min(1 << d, node_cap)
+                n_pad_next = min(2 * n_pad, node_cap)
+                step = st._level_step(n_pad, n_pad_next, 32, d == depth, (),
+                                      st._split_shard_on())
                 nid, preds, vi, n_split, rec = step(
                     bins, nid, preds, vi, w, w * t, h,
                     jax.random.fold_in(key, d),
@@ -564,22 +572,24 @@ def test_fused_whole_tree_deep_matches_per_level(monkeypatch):
                     jnp.float32(10.0), jnp.float32(1e-5), jnp.float32(0.1),
                     jnp.float32(np.inf), jnp.float32(1.0), None,
                 )
-                tree.levels.append(st.TreeLevel(**rec))
             return preds, vi
-        prog = st._tree_program(depth, 32, 2048, ())
-        _, preds, vi, _ = prog(
+        prog = st._tree_program(depth, 32, node_cap, ())
+        _, preds, vi, _, sat_iters = prog(
             bins, preds, vi, w, w * t, h, key,
             jnp.ones(c, jnp.float32), jnp.zeros(c, bool),
             jnp.float32(10.0), jnp.float32(1e-5), jnp.float32(0.1),
             jnp.float32(np.inf), jnp.float32(1.0), None,
         )
+        assert int(sat_iters) >= 1
         return preds, vi
 
-    # per-level builds every histogram from scratch at full bins; the fused
-    # program uses sibling subtraction and bin adaptivity — equality must
-    # hold exactly when both are OFF
+    bins_np = rng.integers(1, 32, (n, c)).astype(np.uint8)
+    t_np = rng.normal(size=n).astype(np.float32)
+    # per-level builds every histogram from scratch; the whole-tree program
+    # uses sibling subtraction — equality must hold exactly when it is OFF
     monkeypatch.setenv("H2O3_TPU_HIST_SUBTRACT", "0")
-    monkeypatch.setenv("H2O3_TPU_BIN_ADAPT", "0")
+    old = pm._mesh
+    pm.set_mesh(Mesh(np.array(jax.devices("cpu")[:k]), (pm.ROWS_AXIS,)))
     st._STEP_CACHE.clear()
     try:
         p1, v1 = run(force_per_level=False)
@@ -587,7 +597,115 @@ def test_fused_whole_tree_deep_matches_per_level(monkeypatch):
         np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
         np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
     finally:
+        pm.set_mesh(old)
         st._STEP_CACHE.clear()  # drop subtract=False programs for later tests
+
+
+def test_split_scan_tracks_f64_reference():
+    """The XLA ``_split_scan`` on a float32 histogram against a numpy
+    float64 prefix scan of the same data: the chosen split's child
+    statistics within 5e-5 relative, its gain within 5e-4 of the float64
+    gain at the SAME candidate (gains subtract nearly-equal numbers), and
+    the chosen gain within 5e-4 of the float64 optimum over every column,
+    bin and NA direction."""
+    import jax.numpy as jnp
+
+    from h2o3_tpu.models.tree.shared_tree import _split_scan
+
+    rng = np.random.default_rng(9)
+    n, c, N, B = 4096, 6, 16, 64
+    bins = rng.integers(1, B, size=(n, c)).astype(np.uint8)
+    bins[rng.random((n, c)) < 0.1] = 0  # NA bin occupied
+    nid = rng.integers(0, N, size=n)
+    w = rng.random(n).astype(np.float32)
+    t = rng.normal(size=n).astype(np.float32)
+    stats = np.stack([w, w * t, w], axis=1).astype(np.float32)
+
+    ref = np.zeros((N, c, B, 3), np.float64)
+    for col in range(c):
+        np.add.at(ref[:, col], (nid, bins[:, col]), stats.astype(np.float64))
+    min_rows = 10.0
+    sp = _split_scan(
+        jnp.asarray(ref.astype(np.float32)), jnp.zeros(c, bool),
+        jnp.ones((N, c), jnp.float32), min_rows, 0.0)
+
+    na = ref[:, :, :1, :]
+    cum = np.cumsum(ref[:, :, 1:, :], axis=2)
+    left = cum[:, :, :-1, :]
+    right = cum[:, :, -1:, :] - left
+    tot = ref.sum(axis=2)[:, 0, :]
+
+    def fit(s):
+        return -np.where(s[..., 0] > 0,
+                         s[..., 1] ** 2 / np.maximum(s[..., 0], 1e-300), 0.0)
+
+    def gains(L, R):  # (N, c, B-2)
+        ok = (L[..., 0] >= min_rows) & (R[..., 0] >= min_rows)
+        return np.where(ok, fit(tot)[:, None, None] - fit(L) - fit(R), -np.inf)
+
+    g_nl, g_nr = gains(left + na, right), gains(left, right + na)
+    nodes = np.arange(N)
+    col_i, t_i = np.asarray(sp["col"]), np.asarray(sp["split_bin"]) - 1
+    nal = np.asarray(sp["na_left"])
+    L64 = (left + np.where(nal[:, None, None, None], na, 0.0))[nodes, col_i, t_i]
+    R64 = (right + np.where(~nal[:, None, None, None], na, 0.0))[nodes, col_i, t_i]
+    for got, want in ((sp["Lst"], L64), (sp["Rst"], R64)):
+        err = np.abs(np.asarray(got) - want) / np.maximum(np.abs(want), 1.0)
+        assert err.max() < 5e-5, f"child stats rel err {err.max():.2e}"
+    got_gain = np.asarray(sp["gain"], np.float64)
+    at_same = np.where(nal, g_nl[nodes, col_i, t_i], g_nr[nodes, col_i, t_i])
+    best = np.maximum(g_nl, g_nr).reshape(N, -1).max(axis=1)
+    assert np.isfinite(best).all()
+    scale = np.maximum(np.abs(best), 1.0)
+    assert (np.abs(got_gain - at_same) / scale).max() < 5e-4
+    assert (np.abs(got_gain - best) / scale).max() < 5e-4
+
+
+def test_monotone_lossguide_is_refused():
+    """Monotone builds run the per-level loop, which carries no leaf
+    budget: ``grow_policy=lossguide`` with constraints is refused up front
+    (and the message names no removed knob)."""
+    rng = np.random.default_rng(3)
+    n = 400
+    x = rng.normal(size=n)
+    fr = Frame.from_pandas(pd.DataFrame(
+        {"x": x, "z": rng.normal(size=n), "y": x + 0.1 * rng.normal(size=n)}))
+    kw = dict(ntrees=2, max_depth=3, grow_policy="lossguide", max_leaves=4)
+    with pytest.raises(Exception, match="monotone_constraints") as e:
+        GBM(monotone_constraints={"x": 1}, **kw).train(
+            y="y", training_frame=fr)
+    assert "SPLIT_FUSE" not in str(e.value)
+    m = GBM(**kw).train(y="y", training_frame=fr)  # without: it builds
+    assert all(g[0].n_leaves <= 4 for g in m.output["trees"])
+
+
+def test_efb_skips_monotone_builds(monkeypatch):
+    """``H2O3_TPU_TREE_EFB=1`` bundles exclusive columns for the whole-tree
+    program only: a monotone build (per-level loop) must skip bundling —
+    ``tree_cols_bundled_total`` stays put — and still honour its
+    constraint; the same frame without the constraint bundles."""
+    from h2o3_tpu.utils import metrics as mx
+
+    rng = np.random.default_rng(8)
+    n = 1500
+    which = rng.integers(0, 4, n)
+    df = pd.DataFrame({f"o{j}": (which == j).astype(np.float64)
+                       for j in range(4)})
+    df["x"] = rng.normal(size=n)
+    df["y"] = df["x"] + which + 0.1 * rng.normal(size=n)
+    fr = Frame.from_pandas(df)
+    monkeypatch.setenv("H2O3_TPU_TREE_EFB", "1")
+    kw = dict(ntrees=3, max_depth=3, seed=4)
+    b0 = mx.counter_value("tree_cols_bundled_total")
+    m = GBM(monotone_constraints={"x": 1}, **kw).train(
+        y="y", training_frame=fr)
+    assert mx.counter_value("tree_cols_bundled_total") == b0
+    sweep = pd.DataFrame({**{f"o{j}": np.zeros(50) for j in range(4)},
+                          "x": np.linspace(-3, 3, 50)})
+    p = m.predict(Frame.from_pandas(sweep)).vec("predict").to_numpy()
+    assert (np.diff(p) >= -1e-6).all()
+    GBM(**kw).train(y="y", training_frame=fr)
+    assert mx.counter_value("tree_cols_bundled_total") > b0
 
 
 def test_gains_lift_and_ks_match_reference():

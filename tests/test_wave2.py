@@ -492,17 +492,16 @@ def _uplift_frame(n=4000, seed=17):
         df, column_types={"y": "enum", "treatment": "enum"})
 
 
-def test_uplift_fused_fallback_quiet():
-    """Uplift's 4-lane scan now rides the fused whole-tree program: the
-    tree_fused_fallbacks_total{reason=uplift} counter must stay quiet."""
-    from h2o3_tpu.models import UpliftDRF
+def test_uplift_runs_the_whole_tree_program():
+    """Uplift's 4-lane scan rides a whole-tree program: a default build
+    compiles one ``uplift_tree`` program and no per-level step."""
+    from h2o3_tpu.models import UpliftDRF, uplift
 
     fr = _uplift_frame()
-    f0 = mx.counter_value("tree_fused_fallbacks_total", reason="uplift")
+    uplift._STEP_CACHE.clear()
     UpliftDRF(ntrees=4, max_depth=3, treatment_column="treatment",
               uplift_metric="KL", seed=11).train(y="y", training_frame=fr)
-    assert mx.counter_value(
-        "tree_fused_fallbacks_total", reason="uplift") == f0
+    assert [k[0] for k in uplift._STEP_CACHE] == ["uplift_tree"]
 
 
 def test_uplift_fused_matches_legacy_loop():

@@ -12,16 +12,9 @@ barely matters — bin count and tile sizes are the levers.
         # one JSON line per mode with fused_tree_s + psum_bytes_per_tree,
         # then a {"split_ab": ...} summary line. Runs on any backend (the
         # 8-device CPU mesh is the CI proxy; queue on TPU for real numbers).
-    python tools/bench_kernel_sweep.py --fused-ab [--rows N]
-        # fused-vs-unfused Pallas split pipeline A/B (H2O3_TPU_SPLIT_FUSE,
-        # ISSUE 6): both modes pin H2O3_TPU_HIST=pallas (interpret mode on
-        # CPU — slow but like-for-like), one JSON line per mode with
-        # fused_tree_s + hist_hbm_bytes_per_tree (the modeled HBM traffic
-        # of the hist+split phases), then a {"fused_ab": ...} summary.
-
     python tools/bench_kernel_sweep.py --fallback-ab [--rows N]
-        # fallback-matrix closure A/B (ISSUE 15): monotone GBM, multinomial
-        # GLM and dropout DL each run the NOW-fused lane vs the forced
+        # fallback-matrix closure A/B (ISSUE 15): multinomial GLM and
+        # dropout DL each run the NOW-fused lane vs the forced
         # fallback it replaces (kill-switch knobs), with parity pins and
         # dispatch/wall ratios in a {"fallback_ab": ...} summary line.
 
@@ -138,87 +131,6 @@ def split_ab(rows: int = 10_000, cols: int = 28, depth: int = 6,
             "time_ratio_replicated_over_sharded": round(
                 results["replicated"]["fused_tree_s"]
                 / max(results["sharded"]["fused_tree_s"], 1e-9), 3),
-        }}), flush=True)
-
-
-def fused_ab(rows: int = 4_000, cols: int = 28, depth: int = 6,
-             trees: int = 2) -> None:
-    """A/B the fused Pallas histogram→split pipeline (H2O3_TPU_SPLIT_FUSE)
-    against the unfused Pallas path on the SAME mesh and data: per-tree
-    fused seconds (median of 3 timed chunk dispatches after a compile
-    warmup) plus the modeled hist+split HBM bytes per tree
-    (tree_hist_hbm_bytes_total — the traffic the fusion removes). Both
-    modes pin H2O3_TPU_HIST=pallas so the comparison isolates the split
-    pipeline; on CPU both run the Pallas interpreter (like-for-like proxy —
-    queue on TPU for real numbers). The env toggle works in-process because
-    the tree program caches key on the mode (_kernel_key)."""
-    import jax
-    import jax.numpy as jnp
-
-    from h2o3_tpu.models.tree import shared_tree as st
-    from h2o3_tpu.parallel.mesh import get_mesh, pad_to_shards, shard_rows
-    from h2o3_tpu.utils import metrics as mx
-
-    os.environ["H2O3_TPU_HIST"] = "pallas"
-    n = pad_to_shards(rows)
-    rng = np.random.default_rng(0)
-    bins = shard_rows(jnp.asarray(
-        rng.integers(0, 128, (n, cols)).astype(np.uint8)))
-    y = shard_rows(jnp.asarray(rng.normal(size=n).astype(np.float32)))
-    w = shard_rows(jnp.ones(n, jnp.float32))
-
-    def grad_fn(F, y_, w_):  # gaussian residuals, unit hessian
-        return y_ - F, jnp.ones_like(F)
-
-    hbm_paths = ("fused", "pallas_unfused", "dense", "fused_via_dense")
-    results = {}
-    for mode in ("1", "0"):
-        os.environ["H2O3_TPU_SPLIT_FUSE"] = mode
-        times = []
-        b0 = {p: mx.counter_value("tree_hist_hbm_bytes_total", path=p)
-              for p in hbm_paths}
-        for rep in range(4):  # rep 0 = compile warmup
-            preds = shard_rows(jnp.zeros(n, jnp.float32))
-            varimp = jnp.zeros(cols, jnp.float32)
-            t0 = time.perf_counter()
-            out = st.build_trees_scanned(
-                bins, w, y, preds, varimp, jax.random.PRNGKey(7), trees,
-                grad_fn=grad_fn, grad_key="gaussian-fab", sample_rate=1.0,
-                n_bins=128, is_cat_cols=np.zeros(cols, bool),
-                max_depth=depth, min_rows=10.0, min_split_improvement=1e-5,
-                learn_rates=np.full(trees, 0.1, np.float32),
-                max_abs_leaf=float("inf"), col_sample_rate=1.0,
-                col_sample_rate_per_tree=1.0,
-            )
-            jax.block_until_ready(out[0])
-            if rep:
-                times.append(time.perf_counter() - t0)
-        built = 4 * trees
-        hbm = sum(
-            mx.counter_value("tree_hist_hbm_bytes_total", path=p) - b0[p]
-            for p in hbm_paths
-        )
-        rec = {
-            "phase": "fused_ab",
-            "mode": "fused" if mode == "1" else "unfused",
-            "backend": jax.default_backend(),
-            "n_devices": get_mesh().devices.size,
-            "rows": n, "cols": cols, "depth": depth, "trees": trees,
-            "fused_tree_s": round(sorted(times)[len(times) // 2] / trees, 4),
-            "hist_hbm_bytes_per_tree": round(hbm / built, 1),
-        }
-        print(json.dumps(rec), flush=True)
-        results[rec["mode"]] = rec
-    os.environ.pop("H2O3_TPU_SPLIT_FUSE", None)
-    os.environ.pop("H2O3_TPU_HIST", None)
-    if len(results) == 2 and results["fused"]["hist_hbm_bytes_per_tree"] > 0:
-        print(json.dumps({"fused_ab": {
-            "hbm_ratio_unfused_over_fused": round(
-                results["unfused"]["hist_hbm_bytes_per_tree"]
-                / results["fused"]["hist_hbm_bytes_per_tree"], 2),
-            "time_ratio_unfused_over_fused": round(
-                results["unfused"]["fused_tree_s"]
-                / max(results["fused"]["fused_tree_s"], 1e-9), 3),
         }}), flush=True)
 
 
@@ -583,20 +495,18 @@ def oocore_ab(rows: int = 120_000, cols: int = 12) -> None:
 
 def fallback_ab(rows: int = 8_000, cols: int = 12) -> None:
     """Fallback-matrix closure A/B (ISSUE 15): for each production shape
-    that used to hit a slow lane — monotone GBM, multinomial GLM, dropout
-    DL — run the NOW-fused lane against the forced fallback it replaces
-    (the respective kill-switch knob), on the SAME mesh and data. Per mode:
-    wall seconds + host dispatches; then a {"fallback_ab": ...} summary
-    with the parity pins (mono preds allclose fused-vs-fallback on the
-    integer-exact data, GLM coef delta <= 2e-3, DL preds <= 1e-4 vs the
-    =ctl same-masks control) and the dispatch/wall ratios. The tree lanes
-    pin H2O3_TPU_HIST=pallas so the comparison isolates the pipeline."""
+    that used to hit a slow lane — multinomial GLM, dropout DL — run the
+    NOW-fused lane against the forced fallback it replaces (the respective
+    kill-switch knob), on the SAME mesh and data. Per mode: wall seconds +
+    host dispatches; then a {"fallback_ab": ...} summary with the parity
+    pins (GLM coef delta <= 2e-3, DL preds <= 1e-4 vs the =ctl same-masks
+    control) and the dispatch/wall ratios. (Monotone GBM has one lane, the
+    per-level loop: nothing to compare.)"""
     import jax
 
     from h2o3_tpu.frame.frame import Frame
     from h2o3_tpu.models.deeplearning import DeepLearning
     from h2o3_tpu.models.glm import GLM
-    from h2o3_tpu.models.tree import GBM
     from h2o3_tpu.parallel.mesh import get_mesh
     from h2o3_tpu.utils import metrics as mx
 
@@ -611,42 +521,10 @@ def fallback_ab(rows: int = 8_000, cols: int = 12) -> None:
         dt = time.perf_counter() - t0
         return out, dt, int(mx.counter_value(counter) - d0)
 
-    # ---- (a) monotone GBM: fused whole-tree lane vs the legacy per-level
-    # mono loop (H2O3_TPU_SPLIT_FUSE=0) ----
     rng = np.random.default_rng(0)
-    df = {"a": rng.integers(0, 50, rows).astype(np.float64)}
-    for i in range(cols - 1):
-        df[f"x{i}"] = rng.normal(size=rows)
     import pandas as pd
 
-    dfp = pd.DataFrame(df)
-    dfp["label"] = (dfp["a"] * 0.1 + 0.5 * dfp["x0"]
-                    + 0.1 * rng.normal(size=rows))
-    fr_m = Frame.from_pandas(dfp)
-    kw_m = dict(ntrees=8, max_depth=5, seed=7,
-                monotone_constraints={"a": 1})
-    os.environ["H2O3_TPU_HIST"] = "pallas"
-    preds = {}
-    for mode, fuse in (("fused", "1"), ("fallback", "0")):
-        os.environ["H2O3_TPU_SPLIT_FUSE"] = fuse
-
-        def run_m():
-            m = GBM(**kw_m).train(y="label", training_frame=fr_m)
-            pr = m.predict(fr_m)
-            return pr.vec(pr.names[-1]).to_numpy()
-
-        p, dt, disp = timed(run_m, "tree_dispatches_total")
-        preds[mode] = p
-        rec = {"phase": "fallback_ab", "case": "mono_gbm", "mode": mode,
-               "n_devices": n_dev, "rows": rows,
-               "train_s": round(dt, 4), "dispatches": disp}
-        print(json.dumps(rec), flush=True)
-        summary[f"mono_{mode}"] = rec
-    os.environ.pop("H2O3_TPU_SPLIT_FUSE", None)
-    os.environ.pop("H2O3_TPU_HIST", None)
-    mono_delta = float(np.max(np.abs(preds["fused"] - preds["fallback"])))
-
-    # ---- (b) multinomial GLM: fused class-scan chunk vs the host f64
+    # ---- (a) multinomial GLM: fused class-scan chunk vs the host f64
     # cycling loop (H2O3_TPU_GLM_FUSE=0) ----
     K = 3
     X = rng.normal(size=(rows, 5)).astype(np.float32)
@@ -680,7 +558,7 @@ def fallback_ab(rows: int = 8_000, cols: int = 12) -> None:
     os.environ.pop("H2O3_TPU_GLM_FUSE", None)
     glm_delta = float(np.max(np.abs(betas["fused"] - betas["fallback"])))
 
-    # ---- (c) dropout DL: sharded-grad lane vs the =ctl same-masks
+    # ---- (b) dropout DL: sharded-grad lane vs the =ctl same-masks
     # replicated control (the parity pin) AND the =0 replicated lane (the
     # wall-clock fallback it replaces) ----
     fr_d = _ab_frame(rows, cols)
@@ -711,20 +589,13 @@ def fallback_ab(rows: int = 8_000, cols: int = 12) -> None:
 
     print(json.dumps({"fallback_ab": {
         # parity pins
-        "mono_pred_max_delta": round(mono_delta, 9),
         "glm_coef_max_delta": round(glm_delta, 7),
         "dl_ctl_pred_max_delta": round(dl_ctl_delta, 7),
         # dispatch contracts (the raw-speed coverage claim)
-        "mono_dispatch_ratio_fallback_over_fused": round(
-            summary["mono_fallback"]["dispatches"]
-            / max(summary["mono_fused"]["dispatches"], 1), 2),
         "glm_dispatch_ratio_fallback_over_fused": round(
             summary["glm_fallback"]["dispatches"]
             / max(summary["glm_fused"]["dispatches"], 1), 2),
         # wall ratios (fused must be no worse than the lane it replaces)
-        "mono_time_ratio_fused_over_fallback": round(
-            summary["mono_fused"]["train_s"]
-            / max(summary["mono_fallback"]["train_s"], 1e-9), 3),
         "glm_time_ratio_fused_over_fallback": round(
             summary["glm_fused"]["train_s"]
             / max(summary["glm_fallback"]["train_s"], 1e-9), 3),
@@ -1245,8 +1116,6 @@ if __name__ == "__main__":
         kw["rows"] = int(sys.argv[sys.argv.index("--rows") + 1])
     if "--split-ab" in sys.argv:
         split_ab(**kw)
-    elif "--fused-ab" in sys.argv:
-        fused_ab(**kw)
     elif "--glm-ab" in sys.argv:
         glm_ab(**kw)
     elif "--dl-ab" in sys.argv:

@@ -230,8 +230,7 @@ def _fallback_ab_ok(here: str, now: float):
     """Sanity-check the newest recent FALLBACK_AB_*.jsonl
     (bench_kernel_sweep --fallback-ab, the ISSUE-15 fallback-matrix
     closure A/B). Returns None when no recent artifact exists (no
-    opinion), else True/False. Checks the acceptance pins: mono GBM preds
-    fused-vs-fallback within the block-sum envelope, multinomial GLM coef
+    opinion), else True/False. Checks the acceptance pins: multinomial GLM coef
     parity <= 2e-3, dropout-DL trajectory parity <= 1e-4 vs the same-masks
     ctl control, the multinomial dispatch drop >= 3x, and the fused lanes'
     wall no worse than the fallback they replace (1.10x proxy-noise
@@ -258,12 +257,8 @@ def _fallback_ab_ok(here: str, now: float):
         if not summary:
             print(f"{name}: NO fallback_ab summary line")
             return False
-        mono_d = float(summary.get("mono_pred_max_delta", float("nan")))
         glm_d = float(summary.get("glm_coef_max_delta", float("nan")))
         dl_d = float(summary.get("dl_ctl_pred_max_delta", float("nan")))
-        if not mono_d <= 1e-4:
-            print(f"{name}: mono pred delta {mono_d} > 1e-4")
-            return False
         if not glm_d <= 2e-3:
             print(f"{name}: multinomial coef delta {glm_d} > 2e-3")
             return False
@@ -275,14 +270,13 @@ def _fallback_ab_ok(here: str, now: float):
         if not gr >= 3.0:
             print(f"{name}: multinomial dispatch ratio {gr} < 3x")
             return False
-        for k in ("mono_time_ratio_fused_over_fallback",
-                  "glm_time_ratio_fused_over_fallback",
+        for k in ("glm_time_ratio_fused_over_fallback",
                   "dl_time_ratio_fused_over_fallback"):
             r = float(summary.get(k) or 0)
             if not 0 < r <= 1.10:
                 print(f"{name}: {k}={r} outside (0, 1.10]")
                 return False
-        print(f"{name}: mono-delta={mono_d} glm-delta={glm_d} "
+        print(f"{name}: glm-delta={glm_d} "
               f"dl-delta={dl_d} glm-dispatch-ratio={gr} ok")
         return True
     except OSError as e:
