@@ -786,14 +786,15 @@ def _partition_dense(
         active, jnp.where(retired, -1, child + jnp.where(go_left, 0, 1)), -1)
     with jax.named_scope("ph_pred"):  # the prediction update, under ph_part
         new_preds = preds + jnp.where(active & retired, leaf, 0.0)
-    # The barrier keeps the node ids a 1-D lane. The histogram kernel takes
-    # them as an (n, 1) operand in (8, 128) tiles — 128 lanes a row — and
-    # XLA's layout assignment otherwise carries that tiling back through
-    # every elementwise producer: the selects above and the next level's
-    # pair bookkeeping then run on 2 GB arrays. Behind the barrier the one
-    # relayout copy sits in front of the kernel and the rest stays on (n,)
-    # lanes (a layout constraint does the same on one device, but the SPMD
-    # partitioner gathers its operand across a row-sharded mesh).
+    # The barrier keeps the node ids a 1-D lane. Until ISSUE 31 the
+    # histogram kernel took them as an (n, 1) operand in (8, 128) tiles —
+    # 128 lanes a row — and XLA's layout assignment carried that tiling back
+    # through every elementwise producer: the selects above and the next
+    # level's pair bookkeeping then ran on 2 GB arrays (a layout constraint
+    # does the same on one device, but the SPMD partitioner gathers its
+    # operand across a row-sharded mesh). The kernel now takes (1, n), a
+    # bitcast of this lane; whether the program is as fast without the
+    # barrier has not been measured on a chip (ROADMAP A5).
     new_nid = jax.lax.optimization_barrier(new_nid.astype(jnp.int32))
     return new_nid, new_preds
 

@@ -13,13 +13,25 @@ This kernel never materializes that transient:
 - grid = (node_tiles, col_tiles, row_chunks), row-fastest, so the output
   block for one (node_tile, col_tile) stays resident in VMEM while every row
   chunk accumulates into it;
-- per step, the (R, CT·B) indicator tile and the (R, NT·S) stat-scaled
-  node-one-hot are built in VMEM by iota-compare (VPU) and immediately
-  contracted on the MXU — one f32 dot per step, all S stats fused into the
-  M dimension;
+- the kernel's operands carry the ROWS ON THEIR LANES — codes (n_ct, CT, n)
+  int32, node ids (1, n), statistics (S, n) — so a step's blocks are dense
+  in HBM (an (n, 1) operand is tiled to 128 lanes a row: 48x the bytes to
+  stream) and every broadcast a one-hot needs runs down the sublanes and
+  costs no lane shuffle. The stat-scaled node one-hot is (S·NT, R); a
+  column's bin indicator is a (Bpad, R) compare that the MXU takes as a
+  transposed 0/1 mask — it never exists as data;
+- the indicator goes through the MXU ONCE per step: the 2-term bf16 split of
+  the statistics is stacked on the M dimension ([hi; lo], 2·S·NT rows), one
+  contraction per column, and the two halves of the result are added;
 - rows with nid outside the tile (or nid = -1: retired/padding) match no
-  one-hot column and contribute zero, so node tiling and row padding need no
+  one-hot row and contribute zero, so node tiling and row padding need no
   masking anywhere.
+
+What binds a step is its instruction schedule, not HBM or the MXU's FLOPs
+(PERF.md §3 says how to read it from the compiler with no chip): until
+ISSUE 31 both operands were lane-tiled with ``jnp.tile`` — lane rotations
+that kept the XLU full and spilled ~1,300 vregs a step — and under that
+schedule sat the 576 KB a step of 128-lane-padded operand blocks.
 
 ``S`` (the stat-lane count) is caller-defined: the GBM/DRF path runs S=3
 {w, wy, wh} — the wy² lane of H2O's DHistogram cancels in the gain and
@@ -28,15 +40,17 @@ while uplift trees run their 4 treatment/control lanes. Kernel cost is
 ∝ S, so each consumer pays exactly for what it reads.
 
 The output is (C, n_nodes·n_bins, S) per shard — the layout the
-scatter/matmul paths emit. The kernel itself writes (node·S + stat) rows by
-column-tile-major (bin·CT + col_in_tile) lanes; two reshape/transpose
-"unscramble" passes over the full tensor in HBM bring that to the dense
-layout. :func:`plan_layout` is the single source of the tile geometry.
+scatter/matmul paths emit. The kernel itself writes, per node tile,
+(stat·NT + node) rows by column-major (col_in_tile·Bpad + bin) lanes; one
+reshape/transpose "unscramble" pass over the full tensor in HBM brings that
+to the dense layout. :func:`plan_layout` is the single source of the tile
+geometry.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 
 import jax
@@ -47,6 +61,12 @@ from jax.experimental.pallas import tpu as pltpu
 ROW_TILE = 512  # rows per grid step
 COL_TILE = 8  # feature columns per grid step
 NODE_TILE = 64  # tree nodes per grid step (S·NT = 192-256 M-rows on the MXU)
+# A grid step takes ALL the columns while its output block stays under this
+# (28 columns x 256 bins: up to 32 nodes): a quarter of the steps, no column
+# padded to the tile, the node one-hot built once a row tile. A full node
+# tile's block (5.5 MB, twice for the pipeline, once more where XLA places
+# the kernel's output in VMEM) overruns the kernel's 16 MB of scoped VMEM.
+WIDE_BLOCK_BYTES = 3 << 20
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -60,7 +80,9 @@ def _tiles() -> tuple[int, int, int]:
     instead of monkeypatching module globals).
     Callers pass the resolved tuple into :func:`hist_pallas_local` /
     :func:`plan_layout` as a static argument, so every tile choice gets its
-    own jit cache entry — no stale-executable footgun.
+    own jit cache entry — no stale-executable footgun. The column tile is
+    the one a step takes where the output block of ALL the columns would
+    pass ``WIDE_BLOCK_BYTES`` (:func:`plan_layout`).
 
     ``'auto'`` is the SHAPE-AWARE autotuner (ISSUE 15): this shapeless
     accessor then returns the built-in defaults; shape-aware call sites
@@ -72,10 +94,11 @@ def _tiles() -> tuple[int, int, int]:
     if not spec or spec == "auto":
         return (ROW_TILE, COL_TILE, NODE_TILE)
     parts = [int(x) for x in spec.split(",")]
-    if len(parts) != 3 or any(p <= 0 for p in parts):
+    if len(parts) != 3 or any(p <= 0 for p in parts) or parts[0] % 128:
         raise ValueError(
             f"H2O3_TPU_PALLAS_TILES must be 'ROW,COL,NODE' positive ints "
-            f"or 'auto', got {spec!r}"
+            f"(ROW a multiple of 128: the rows are the lanes of the kernel's "
+            f"blocks) or 'auto', got {spec!r}"
         )
     return tuple(parts)
 
@@ -244,11 +267,12 @@ def tiles_for(c: int, n_nodes: int, n_bins: int, ns: int) -> tuple:
 @dataclass(frozen=True)
 class HistLayout:
     """Static tile geometry of the kernel's padded output for a problem
-    shape: ``(n_nt·nt·ns, n_ct·ct·bpad)`` float32, rows ``node·ns + stat``,
-    lanes column-tile-major ``bin·ct + col_in_tile``. ``n_nt·nt >= n_nodes``,
-    ``n_ct·ct >= C`` and ``bpad >= n_bins`` are tile padding. Padded BIN and
-    NODE cells are exactly zero (no row ever lands there); padded COLUMNS
-    carry the u8 pad code 0 and are sliced off by the unscramble."""
+    shape: ``(n_nt·nt·ns, n_ct·ct·bpad)`` float32, rows ``(node_tile, stat,
+    node_in_tile)``, lanes ``(col_tile, col_in_tile, bin)``. ``n_nt·nt >=
+    n_nodes``, ``n_ct·ct >= C`` and ``bpad >= n_bins`` are tile padding.
+    Padded BIN and NODE cells are exactly zero (no row ever lands there);
+    padded COLUMNS carry the u8 pad code 0 and are sliced off by the
+    unscramble."""
 
     ns: int         # stat lanes
     ct: int         # columns per tile
@@ -267,14 +291,22 @@ def plan_layout(
     c: int, n_nodes: int, n_bins: int, ns: int,
     tiles: tuple[int, int, int] | None = None,
 ) -> HistLayout:
-    """The kernel's tile geometry for a problem shape."""
+    """The kernel's tile geometry for a problem shape: the node tile clamped
+    to the frontier, and all the columns a step while that output block is
+    under ``WIDE_BLOCK_BYTES``, else the triple's column tile."""
     _, col_tile, node_tile = tuple(tiles or _tiles())
     nt = min(node_tile, n_nodes)
+
+    def bins_padded(ct):  # the lane dimension CT·Bpad is a multiple of 128
+        bpad = _cdiv(n_bins, 16) * 16
+        while (ct * bpad) % 128:
+            bpad += 16
+        return bpad
+
     ct = min(col_tile, c)
-    # pad bins so the lane dimension CT·Bpad is a multiple of 128
-    bpad = _cdiv(n_bins, 16) * 16
-    while (ct * bpad) % 128:
-        bpad += 16
+    if 4 * nt * ns * c * bins_padded(c) <= WIDE_BLOCK_BYTES:
+        ct = c
+    bpad = bins_padded(ct)
     return HistLayout(
         ns=ns, ct=ct, bpad=bpad, nt=nt, n_ct=_cdiv(c, ct),
         n_nt=_cdiv(n_nodes, nt),
@@ -285,48 +317,52 @@ def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, *, nt, ct, bpad, ns):
     i_nt = pl.program_id(0)
     i_r = pl.program_id(2)
 
-    r = bins_ref.shape[1]  # bins block is (1, R, CT)
-    # Everything is built directly in 2D with lane-iota arithmetic: Mosaic
-    # cannot relayout (R, k, m) → (R, k·m) for small trailing dims.
+    r = bins_ref.shape[2]  # bins block is (1, CT, R)
+    m = nt * ns
+    # All three operands arrive with the ROWS ON THE LANES, so everything
+    # below broadcasts down the sublanes: a lane broadcast of an (R, 1)
+    # column, or a lane-tiling of a sub-128-lane pattern (jnp.tile), is one
+    # XLU shuffle per vreg, and the XLU then bounds the step. The column
+    # tile arrives via the BlockSpec from the (n_ct, CT, npad) layout.
+    nid_t = nid_ref[:]  # (1, R)
+    stats_t = stats_ref[:]  # (S, R)
+    bins_t = bins_ref[0]  # (CT, R) int32
 
-    # stat-scaled node one-hot, nodes of this tile only: (R, NT·S) with
-    # column j ↦ (node = j//S, stat = j%S)
-    node_base = i_nt * nt
-    node_j = node_base + jax.lax.broadcasted_iota(jnp.int32, (r, nt * ns), 1) // ns
-    nid_match = (nid_ref[:] == node_j).astype(jnp.float32)  # (R,1) broadcasts
-    stat_tile = jnp.tile(stats_ref[:], (1, nt))  # (R, NT·S): [s0..s_{S-1}]×NT
-    a = nid_match * stat_tile
-
-    # (R, CT·Bpad) 0/1 bin indicator, lane j ↦ (bin = j//CT, col = j%CT) —
-    # the tile-order jnp.tile lays out [c0..c(CT-1)] × Bpad blocks. The column
-    # tile arrives via the BlockSpec from the (n_ct, npad, CT) layout
-    # (lane-dim dynamic slices at non-128 offsets are not expressible
-    # in-kernel, and a (R, CT) block would violate the lane-divisibility rule).
-    bins_ct = bins_ref[0].astype(jnp.int32)  # (R, CT)
-    colrep = jnp.tile(bins_ct, (1, bpad))  # (R, CT·Bpad)
-    bin_j = jax.lax.broadcasted_iota(jnp.int32, (r, ct * bpad), 1) // ct
-    e = (colrep == bin_j).astype(jnp.bfloat16)  # 0/1: exact in bf16
+    # stat-scaled node one-hot, nodes of this tile only: (S·NT, R), row
+    # s·NT + j ↦ (stat s, node j) — the one-hot is made once, scaled S times
+    node_j = i_nt * nt + jax.lax.broadcasted_iota(jnp.int32, (nt, r), 0)
+    nid_match = (nid_t == node_j).astype(jnp.float32)
+    a = jnp.concatenate(
+        [nid_match * stats_t[s:s + 1, :] for s in range(ns)], axis=0)
 
     # Manual 2-term bf16 split of the stats operand (~16 mantissa bits, ≈
     # Precision.HIGH, which Mosaic doesn't support): the indicator operand is
-    # exact in bf16, so only `a` needs decomposing — 2 MXU passes instead of
-    # HIGHEST's 6. Single-pass bf16 measurably corrupts split gains (2e-3).
-    a_hi = a.astype(jnp.bfloat16)
-    a_lo = (a - a_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    dims = (((0,), (0,)), ((), ()))
-    contrib = jax.lax.dot_general(
-        a_hi, e, dims, preferred_element_type=jnp.float32
-    ) + jax.lax.dot_general(
-        a_lo, e, dims, preferred_element_type=jnp.float32
-    )  # (NT·S, CT·Bpad)
+    # exact in bf16, so only `a` needs decomposing. The two terms are stacked
+    # on M so the indicator is pushed into the MXU once, not once per term.
+    # Single-pass bf16 measurably corrupts split gains (2e-3).
+    a_hi = a.astype(jnp.bfloat16).astype(jnp.float32)
+    lhs = jnp.concatenate([a_hi, a - a_hi], axis=0).astype(jnp.bfloat16)
 
     @pl.when(i_r == 0)
     def _():
-        out_ref[...] = contrib.reshape(out_ref.shape)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(i_r > 0)
-    def _():
-        out_ref[...] = out_ref[...] + contrib.reshape(out_ref.shape)
+    # One contraction per 128-lane-aligned group of columns (a column at 256
+    # bins), so a group's indicator lives in registers from its compare to
+    # its push and the output is addressed in aligned lane slices.
+    cg = 128 // math.gcd(bpad, 128)
+    bin_i = jnp.concatenate(
+        [jax.lax.broadcasted_iota(jnp.int32, (bpad, r), 0)] * cg, axis=0)
+    for g in range(ct // cg):
+        codes = jnp.concatenate(
+            [jnp.broadcast_to(bins_t[c:c + 1, :], (bpad, r))
+             for c in range(g * cg, (g + 1) * cg)], axis=0)
+        e_t = (codes == bin_i).astype(jnp.bfloat16)  # (cg·Bpad, R), 0/1: exact
+        both = jax.lax.dot_general(
+            lhs, e_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (2·S·NT, cg·Bpad)
+        lanes = slice(g * cg * bpad, (g + 1) * cg * bpad)
+        out_ref[:, lanes] = out_ref[:, lanes] + (both[:m] + both[m:])
 
 
 @functools.partial(
@@ -361,10 +397,15 @@ def hist_pallas_local(
         stats = jnp.pad(stats, ((0, npad - n), (0, 0)))
     if cpad != c:
         bins_u8 = jnp.pad(bins_u8, ((0, 0), (0, cpad - c)))
-    # (npad, cpad) → (n_ct, npad, CT): each grid step's column tile is the
-    # (full) last dim of its block, satisfying Mosaic's lane-divisibility rule
-    bins3 = jnp.transpose(bins_u8.reshape(npad, n_ct, ct), (1, 0, 2))
-    nid2 = nid.reshape(npad, 1)
+    # The kernel's operands carry the rows on their LANES: (npad, cpad) codes
+    # → (n_ct, CT, npad) int32 (a grid step's column tile is the full
+    # second-to-last dim of its block), node ids (1, npad), statistics
+    # (S, npad). An (npad, 1) or (npad, S) operand is tiled to 128 lanes a row
+    # in HBM: a step would stream 576 KB for 12 KB of data, and that DMA, not
+    # the step's instructions, would bound the kernel (PERF.md §6, PR 31).
+    bins3 = jnp.transpose(bins_u8.astype(jnp.int32)).reshape(n_ct, ct, npad)
+    nid2 = nid.reshape(1, npad)
+    stats_t = jnp.transpose(stats)
 
     kernel = functools.partial(_hist_kernel, nt=nt, ct=ct, bpad=bpad, ns=ns)
     out_bytes = 4 * n_nt * nt * ns * cpad * bpad
@@ -376,7 +417,7 @@ def hist_pallas_local(
         # chunks — 2·n_r − 1 accesses, not 1 (the old estimate undercounted
         # the dominant term and skewed the scheduler).
         bytes_accessed=int(
-            npad * cpad * n_nt
+            npad * cpad * 4 * n_nt
             + npad * (ns + 1) * 4 * n_nt * n_ct
             + out_bytes * (2 * n_r - 1)
         ),
@@ -387,16 +428,16 @@ def hist_pallas_local(
         grid=(n_nt, n_ct, n_r),
         in_specs=[
             pl.BlockSpec(
-                (1, row_tile, ct),
-                lambda nt_, ct_, r_: (ct_, r_, 0),
+                (1, ct, row_tile),
+                lambda nt_, ct_, r_: (ct_, 0, r_),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (row_tile, 1), lambda nt_, ct_, r_: (r_, 0),
+                (1, row_tile), lambda nt_, ct_, r_: (0, r_),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (row_tile, ns), lambda nt_, ct_, r_: (r_, 0),
+                (ns, row_tile), lambda nt_, ct_, r_: (0, r_),
                 memory_space=pltpu.VMEM,
             ),
         ],
@@ -408,10 +449,10 @@ def hist_pallas_local(
         cost_estimate=cost,
         interpret=interpret,
         name="hist_pallas_dense",  # the prefix is the trace readers' key
-    )(bins3, nid2, stats)
+    )(bins3, nid2, stats_t)
 
-    # unscramble: out rows = node·S+stat, lanes = ct-tile-major [bin//CT, col%CT]
-    h5 = out.reshape(n_nt * nt, ns, n_ct, bpad, ct)
-    h5 = jnp.transpose(h5, (2, 4, 0, 3, 1))  # (n_ct, ct, Npad, Bpad, S)
-    h = h5.reshape(cpad, n_nt * nt, bpad, ns)[:c, :n_nodes, :n_bins, :]
+    # unscramble: rows (node_tile, stat, node), lanes (col_tile, col, bin)
+    h6 = out.reshape(n_nt, ns, nt, n_ct, ct, bpad)
+    h6 = jnp.transpose(h6, (3, 4, 0, 2, 5, 1))  # (n_ct, ct, n_nt, nt, Bpad, S)
+    h = h6.reshape(cpad, n_nt * nt, bpad, ns)[:c, :n_nodes, :n_bins, :]
     return h.reshape(c, n_nodes * n_bins, ns)

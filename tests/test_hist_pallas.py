@@ -87,6 +87,125 @@ def test_pallas_matches_scatter(n, c, n_nodes, n_bins):
     assert err.max() < 5e-5, f"max rel err {err.max():.2e}"
 
 
+def _reference_row_tiled(bins, nid, stats, n_nodes, n_bins):
+    """What the kernel computes, in plain ``jax.numpy``: for every ROW_TILE
+    rows the stat-scaled node one-hot, split into two bf16 terms, two
+    float32 dots against the bin one-hot of every column, the two results
+    added, and the row tiles added in order."""
+    n, c = bins.shape
+    ns = stats.shape[1]
+    hist = jnp.zeros((n_nodes * ns, c * n_bins), jnp.float32)
+    for lo in range(0, n, ROW_TILE):
+        b, i, s = (x[lo:lo + ROW_TILE] for x in (bins, nid, stats))
+        node_1h = (i[:, None] == jnp.arange(n_nodes)[None, :]).astype(jnp.float32)
+        a = (node_1h[:, :, None] * s[:, None, :]).reshape(len(i), n_nodes * ns)
+        a_hi = a.astype(jnp.bfloat16).astype(jnp.float32)
+        e = (b.astype(jnp.int32)[:, :, None] == jnp.arange(n_bins)[None, None, :])
+        e = e.astype(jnp.float32).reshape(len(i), c * n_bins)
+        a_lo = (a - a_hi).astype(jnp.bfloat16).astype(jnp.float32)
+        hist = hist + (jnp.dot(a_hi.T, e, precision="highest")
+                       + jnp.dot(a_lo.T, e, precision="highest"))
+    h = hist.reshape(n_nodes, ns, c, n_bins)
+    return jnp.transpose(h, (2, 0, 3, 1)).reshape(c, n_nodes * n_bins, ns)
+
+
+def _pr30_kernel(bins_ref, nid_ref, stats_ref, out_ref, *, nt, ct, bpad, ns):
+    """The kernel's grid step as it was until ISSUE 31, kept here as the
+    bit-for-bit reference: both operands lane-tiled with ``jnp.tile``, rows
+    on the sublanes, the indicator contracted twice."""
+    from jax.experimental import pallas as pl
+
+    i_nt, i_r = pl.program_id(0), pl.program_id(2)
+    r = bins_ref.shape[1]
+    node_j = i_nt * nt + jax.lax.broadcasted_iota(jnp.int32, (r, nt * ns), 1) // ns
+    a = (nid_ref[:] == node_j).astype(jnp.float32) * jnp.tile(stats_ref[:], (1, nt))
+    colrep = jnp.tile(bins_ref[0].astype(jnp.int32), (1, bpad))
+    bin_j = jax.lax.broadcasted_iota(jnp.int32, (r, ct * bpad), 1) // ct
+    e = (colrep == bin_j).astype(jnp.bfloat16)
+    a_hi = a.astype(jnp.bfloat16)
+    a_lo = (a - a_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    dims = (((0,), (0,)), ((), ()))
+    contrib = jax.lax.dot_general(
+        a_hi, e, dims, preferred_element_type=jnp.float32
+    ) + jax.lax.dot_general(a_lo, e, dims, preferred_element_type=jnp.float32)
+
+    @pl.when(i_r == 0)
+    def _():
+        out_ref[...] = contrib
+
+    @pl.when(i_r > 0)
+    def _():
+        out_ref[...] = out_ref[...] + contrib
+
+
+def _pr30_hist(bins_u8, nid, stats, n_nodes, n_bins):
+    """``hist_pallas_local`` around :func:`_pr30_kernel` (interpreter): its
+    (npad, 1) / (npad, S) / (n_ct, npad, CT) operands and its unscramble."""
+    import functools
+
+    from jax.experimental import pallas as pl
+
+    from h2o3_tpu.ops.hist_pallas import plan_layout
+
+    n, c = bins_u8.shape
+    ns = stats.shape[1]
+    lay = plan_layout(c, n_nodes, n_bins, ns)
+    nt, ct, bpad, n_nt, n_ct = lay.nt, lay.ct, lay.bpad, lay.n_nt, lay.n_ct
+    n_r = -(-n // ROW_TILE)
+    npad, cpad = n_r * ROW_TILE, n_ct * ct
+    bins_u8 = jnp.pad(bins_u8, ((0, npad - n), (0, cpad - c)))
+    nid = jnp.pad(nid, (0, npad - n), constant_values=-1)
+    stats = jnp.pad(stats, ((0, npad - n), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_pr30_kernel, nt=nt, ct=ct, bpad=bpad, ns=ns),
+        grid=(n_nt, n_ct, n_r),
+        in_specs=[pl.BlockSpec((1, ROW_TILE, ct), lambda a, b, r: (b, r, 0)),
+                  pl.BlockSpec((ROW_TILE, 1), lambda a, b, r: (r, 0)),
+                  pl.BlockSpec((ROW_TILE, ns), lambda a, b, r: (r, 0))],
+        out_specs=pl.BlockSpec((nt * ns, ct * bpad), lambda a, b, r: (a, b)),
+        out_shape=jax.ShapeDtypeStruct((n_nt * nt * ns, cpad * bpad), jnp.float32),
+        interpret=True,
+    )(jnp.transpose(bins_u8.reshape(npad, n_ct, ct), (1, 0, 2)),
+      nid.reshape(npad, 1), stats)
+    h = jnp.transpose(out.reshape(n_nt * nt, ns, n_ct, bpad, ct), (2, 4, 0, 3, 1))
+    h = h.reshape(cpad, n_nt * nt, bpad, ns)[:c, :n_nodes, :n_bins, :]
+    return h.reshape(c, n_nodes * n_bins, ns)
+
+
+@pytest.mark.parametrize("ns", [3, 4])
+@pytest.mark.parametrize("c,n_bins", [(28, 256), (5, 20)])
+@pytest.mark.parametrize("n_nodes", [1, 16, 64, 130])
+def test_pallas_moved_lanes_and_nothing_else(n_nodes, c, n_bins, ns):
+    """ISSUE 31 rebuilt the grid step (rows on the lanes, one trip of the
+    indicator through the MXU): over the frontier widths of the cells (1,
+    16), a full node tile (64) and more than two (130), S = 3 and 4, the
+    cells' 28 x 256 and a shape the lane rule pads (5 x 20), with a row
+    count that is no multiple of the row tile and retired rows, every cell
+    of the histogram is the old step's float32 — a cell's sum is over the
+    same rows whatever lane it lives in. Bit for bit in all but a handful
+    of cells: the step now contracts ``(2·S·NT, R) x (Bpad, R)`` where it
+    contracted ``(R, S·NT) x (R, CT·Bpad)`` twice, and the CPU's own dot (as
+    the chip's compiler, PERF.md §6) orders the partial sums of a 512-row
+    contraction by the operands' shapes, so a cell in ten thousand rounds
+    the other way. The plain ``jax.numpy`` reference says what the sum is."""
+    n = 2 * ROW_TILE + 77
+    rng = np.random.default_rng(n_nodes + c + ns)
+    bins = rng.integers(0, n_bins, (n, c)).astype(np.uint8)
+    nid = rng.integers(0, n_nodes, n).astype(np.int32)
+    nid[rng.random(n) < 0.1] = -1
+    stats = rng.normal(size=(n, ns)).astype(np.float32)
+    stats[nid < 0] = 0.0
+    args = (jnp.asarray(bins), jnp.asarray(nid), jnp.asarray(stats))
+    got = np.asarray(hist_pallas_local(*args, n_nodes, n_bins, interpret=True))
+    assert got.shape == (c, n_nodes * n_bins, ns)
+    old = np.asarray(_pr30_hist(*args, n_nodes, n_bins))
+    np.testing.assert_allclose(got, old, rtol=1e-6, atol=1e-6)
+    assert (got.view(np.int32) != old.view(np.int32)).mean() < 1e-3
+    want = np.asarray(_reference_row_tiled(*args, n_nodes, n_bins))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-5)
+    _free_compile_state()
+
+
 def test_pallas_f64_accuracy_bound():
     """The kernel's result tracks a float64 scatter reference to ≤5e-5 rel
     (measured ~1.5e-5) — the accuracy envelope of the 2-term bf16 MXU
@@ -207,7 +326,8 @@ def test_tile_autotuner_sweeps_once_per_bucket(tmp_path, monkeypatch):
 def test_pallas_tiles_knob():
     """H2O3_TPU_PALLAS_TILES reshapes the kernel grid (the sweep hook) and
     the result still matches the default-tile kernel within the bf16
-    envelope; a malformed spec fails loudly."""
+    envelope; a malformed spec fails loudly. The triple's column tile is the
+    one a step takes where all the columns' output block would not fit."""
     from h2o3_tpu.ops import hist_pallas as hp
 
     rng = np.random.default_rng(21)
@@ -224,7 +344,9 @@ def test_pallas_tiles_knob():
         tiles = hp._tiles()
         assert tiles == (256, 4, 32)
         lay = hp.plan_layout(c, N, B, 3, tiles=tiles)
-        assert lay.ct == 4 and lay.nt == 8  # nt clamps to n_nodes
+        assert lay.ct == c and lay.nt == 8  # nt clamps to n_nodes; 70 KB: wide
+        big = hp.plan_layout(64, 2048, 256, 3, tiles=tiles)
+        assert (big.ct, big.nt, big.n_ct, big.n_nt) == (4, 32, 16, 64)
         swept = hp.hist_pallas_local(
             bins, nid, stats, N, B, interpret=True, tiles=tiles)
     np.testing.assert_allclose(
@@ -234,17 +356,29 @@ def test_pallas_tiles_knob():
             hp._tiles()
 
 
-@pytest.mark.parametrize("c,n_nodes,n_bins,ns", [
-    (28, 1, 255, 3), (32, 64, 256, 3), (32, 2048, 256, 3), (5, 80, 17, 4)])
-def test_plan_layout_is_the_kernels_geometry(c, n_nodes, n_bins, ns):
+@pytest.mark.parametrize("c,n_nodes,n_bins,ns,wide", [
+    (28, 1, 255, 3, True), (28, 32, 256, 3, True), (28, 64, 256, 3, False),
+    (32, 64, 256, 3, False), (32, 2048, 256, 3, False), (7, 8, 64, 3, True),
+    (5, 80, 17, 4, True), (300, 4, 256, 3, False)])
+def test_plan_layout_is_the_kernels_geometry(c, n_nodes, n_bins, ns, wide):
     """``plan_layout`` is what ``hist_pallas_local`` tiles by and what the
     modelled-bytes tally (``path=pallas_unfused``) sizes the kernel's padded
     output from: whole tiles cover the problem, the lane dimension is a
-    multiple of 128, and ``nbytes`` is the float32 size of that output."""
+    multiple of 128, and ``nbytes`` is the float32 size of that output. A
+    step takes all the columns (no column padded: 28 stay 28) while its
+    output block is under ``WIDE_BLOCK_BYTES`` — the cells' frontiers up to
+    32 nodes — and ``COL_TILE`` of them from a full node tile on; 7 columns
+    of 64 bins are a shape the lane rule pads (7·64 is no multiple of 128:
+    128 bins a column)."""
     from h2o3_tpu.ops import hist_pallas as hp
 
     lay = hp.plan_layout(c, n_nodes, n_bins, ns)
-    assert lay.nt == min(hp.NODE_TILE, n_nodes) and lay.ct == min(hp.COL_TILE, c)
+    assert lay.nt == min(hp.NODE_TILE, n_nodes)
+    assert lay.ct == (c if wide else min(hp.COL_TILE, c))
+    if not wide:  # all the columns' block at their own bin padding: too big
+        assert 4 * lay.nt * ns * c * (-(-n_bins // 16) * 16) > hp.WIDE_BLOCK_BYTES
+    else:
+        assert lay.nbytes // lay.n_nt <= hp.WIDE_BLOCK_BYTES and lay.n_ct == 1
     assert (lay.ct * lay.bpad) % 128 == 0 and n_bins <= lay.bpad < n_bins + 128
     assert (lay.n_nt - 1) * lay.nt < n_nodes <= lay.n_nt * lay.nt
     assert (lay.n_ct - 1) * lay.ct < c <= lay.n_ct * lay.ct

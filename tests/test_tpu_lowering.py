@@ -79,6 +79,45 @@ def test_histogram_kernel_keeps_its_name_in_the_lowered_text():
     assert re.match(r"^%?hist_pallas", names[0])
 
 
+def _mosaic_body(module: str) -> str:
+    """The Mosaic body of the one ``tpu_custom_call`` in an exported module,
+    as text (it travels as base64 MLIR bytecode in the call's config)."""
+    import base64
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    (body,) = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', module)
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return str(ir.Module.parse(base64.b64decode(body)))
+
+
+@pytest.mark.parametrize("n_nodes", [1, 16, 64])
+def test_histogram_kernel_step_has_no_lane_tiling(n_nodes):
+    """The regression ISSUE 31 removed, pinned without the compiler's
+    schedule dump: ``jnp.tile`` of a sub-128-lane pattern (the (R, 8) codes
+    256 times, the (R, S) statistics NT times) reaches Mosaic as
+    ``tpu.repeat`` and becomes one lane rotation a vreg — the XLU was full
+    in 60% of the old step's bundles. The step builds its one-hots with the
+    rows on the lanes: no ``tpu.repeat``, no transposed-LHS contraction, and
+    operand blocks (1, R) / (S, R) / (1, CT, R), not (R, 1) / (R, S)."""
+    f = functools.partial(
+        hp.hist_pallas_local, n_nodes=n_nodes, n_bins=BINS, interpret=False,
+        tiles=TILES)
+    body = _mosaic_body(_export_tpu(f, *_hist_args()))
+    assert "tpu.repeat" not in body
+    r = hp.ROW_TILE
+    ct = hp.plan_layout(COLS, n_nodes, BINS, LANES, tiles=TILES).ct
+    assert ct == (COLS if n_nodes < 64 else hp.COL_TILE)  # all columns a step
+    for block in (f"memref<1x{r}xi32", f"memref<{LANES}x{r}xf32",
+                  f"memref<1x{ct}x{r}xi32"):
+        assert block in body, block
+    assert f"memref<{r}x1xi32" not in body
+    assert body.count("tpu.matmul") == ct  # one contraction a column
+
+
 def _chunk_program(monkeypatch, max_depth, node_cap, rows=ROWS, sharding=None):
     """The jitted 5-tree ``build_trees_scanned`` chunk program at 28 columns
     and 255 bins with the chip's branches taken, and its operands as
@@ -126,8 +165,8 @@ def test_tree_chunk_program_lowers_for_tpu(monkeypatch, max_depth, node_cap):
     every ``gbm_higgs`` tree, five trees a chunk) lowers for the TPU with
     the chip's branches taken, and the only Pallas kernel in its text is
     ``hist_pallas_dense``. Depth 6 is the benchmark's shape; depth 20 with
-    ``node_cap`` 2048 is a DRF's — the saturated ``lax.while_loop`` that has
-    never compiled on a chip (ROADMAP B-R3): one loop for the levels, one
+    ``node_cap`` 2048 is a DRF's — the saturated ``lax.while_loop`` (ROADMAP
+    B-R3: first trained on a chip in ISSUE 31): one loop for the levels, one
     for the scan over trees."""
     import re
 
@@ -169,18 +208,18 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("max_depth,fits", [(6, True), (7, True), (8, False)])
+@pytest.mark.parametrize("max_depth", [6, 7, 8, 20])
 def test_tree_chunk_program_compiles_for_a_described_v5e(
-        monkeypatch, v5e_chip, no_compile_cache, max_depth, fits):
+        monkeypatch, v5e_chip, no_compile_cache, max_depth):
     """What the chip's own compiler says of the chunk program, with no chip
     (a compile is not a run). Depth 6 — the benchmark's cells — and depth 7
-    compile. **From depth 8 on the program is REFUSED** (ROADMAP B-R3): the
-    first level that builds 64 nodes (one full node tile, ``f32[192, 8192]``)
-    has its kernel's output placed in VMEM by XLA inside the tree program
-    and overruns the kernel's 16 MB of scoped VMEM by 676 KB, although the
-    same kernel compiles alone at 64 and at 2,048 nodes. A depth-8 GBM and
-    every DRF at its default depth would fail at compile time on a v5e; the
-    ``fits=False`` case is the pin to flip when that is repaired."""
+    compile, and since ISSUE 31 so do depth 8 and a DRF's depth 20 at
+    ``node_cap`` 2048. Until then the program was REFUSED from depth 8 on
+    (ROADMAP B-R3): the first level that builds 64 nodes (one full node
+    tile) has its kernel's ``f32[192, 8192]`` output placed in VMEM by XLA
+    inside the tree program, and with the old grid step's 4 MB lane-tiled
+    code block and its copies the kernel overran its 16 MB of scoped VMEM by
+    676 KB (``RESOURCE_EXHAUSTED`` from ``lowered.compile()``)."""
     from jax.sharding import Mesh, SingleDeviceSharding
 
     import numpy as np
@@ -196,21 +235,20 @@ def test_tree_chunk_program_compiles_for_a_described_v5e(
         lowered = fn.lower(*shapes)
     finally:
         pm.set_mesh(old)
-    if fits:
-        text = lowered.compile().as_text()
-        assert text.count("tpu_custom_call") >= max_depth  # a kernel a level
-    else:
-        with pytest.raises(jax.errors.JaxRuntimeError,
-                           match="RESOURCE_EXHAUSTED.*vmem.*hist_pallas_dense"):
-            lowered.compile()
+    text = lowered.compile().as_text()
+    # a kernel a level, up to the level that fills node_cap (2048 = 2**11:
+    # the levels from there on are one while loop)
+    assert text.count("tpu_custom_call") >= min(max_depth, 12)
 
 
-@pytest.mark.parametrize("n_nodes,lanes", [(64, LANES), (2048, LANES), (1024, 4)])
+@pytest.mark.parametrize("n_nodes,lanes", [
+    (64, LANES), (2048, LANES), (64, 4), (1024, 4)])
 def test_histogram_kernel_compiles_alone_for_a_described_v5e(
         v5e_chip, no_compile_cache, n_nodes, lanes):
     """The kernel by itself fits the chip's VMEM at a full node tile, at
-    DRF's ``node_cap`` and at uplift's with four lanes: the refusal above is
-    the tree program's, not the kernel's."""
+    DRF's ``node_cap``, and with uplift's four lanes at a full node tile
+    (the widest stacked operand: 2·4·64 = 512 M-rows) and at its
+    ``node_cap``."""
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(v5e_chip)

@@ -2,9 +2,13 @@
 """Pallas histogram kernel tile sweep on the REAL TPU: measures hist time per (ROW_TILE, COL_TILE, n_bins, n_nodes) so the
 next kernel iteration picks tiles from data, not guesses.
 
-The kernel's per-step cost is dominated by the VPU indicator build
-(∝ ROWS·CT·Bpad) and the MXU dot (M = 4·nt); below 64 nodes the node count
-barely matters — bin count and tile sizes are the levers.
+A grid step is bound by its instruction schedule (PERF.md §3: how to read it
+from the compiler with no chip): below 16 nodes by pushing the 0/1 bin
+indicator (∝ ROWS·CT·Bpad) into the MXU as weights, one push a cycle, and
+its two compares a push on the VPU; from a full node tile on by the MXU's
+result pops (M = 2·S·nt rows: the 2-term split is stacked on M). The lane
+shuffles that bound it until ISSUE 31 are gone — bin count and tile sizes
+are the levers.
 
     python tools/bench_kernel_sweep.py        # prints one JSON line per cfg
     python tools/bench_kernel_sweep.py --split-ab [--rows N]
