@@ -24,10 +24,11 @@ from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models.model_base import ScoreKeeper, stopping_metric_direction
 from h2o3_tpu.models.tree.binning import bin_frame, fit_bins, fit_bins_for
 from h2o3_tpu.models.tree.gbm import SharedTreeModel, SharedTreeParams
-from h2o3_tpu.models.tree.shared_tree import Tree, build_tree
+from h2o3_tpu.models.tree.shared_tree import Tree, bootstrap_mask, build_tree
 from h2o3_tpu.models import metrics as MM
 from h2o3_tpu.models.model_base import ModelBuilder
 from h2o3_tpu.utils import faults
+from h2o3_tpu.utils import metrics as _mx
 from h2o3_tpu.utils.log import Log
 
 
@@ -43,6 +44,28 @@ class DRFParams(SharedTreeParams):
 
 class DRFModel(SharedTreeModel):
     algo = "drf"
+
+    def inbag_rows(self, tree_index: int) -> np.ndarray:
+        """``(npad,)`` bool: the training rows in tree ``tree_index``'s bag
+        (pad rows never), re-derived from what the model stores — the row
+        key, the sample rate and the frame's padded length — through
+        :func:`shared_tree.bootstrap_mask`, the function the builder's
+        program called. A bagged row whose weight is zero (a weights column,
+        a missing response) still counted for nothing."""
+        bag = self.output["bootstrap"]
+        first, n = bag["first_tree"], self.output["ntrees_actual"]
+        if not first <= tree_index < n:
+            raise ValueError(
+                f"tree {tree_index}: this model drew the bags of trees "
+                f"{first}..{n - 1} (earlier ones are its checkpoint's)")
+        npad = bag["npad"]
+        if bag["sample_rate"] >= 1.0:
+            mask = np.ones(npad, bool)
+        else:
+            mask = np.asarray(bootstrap_mask(
+                jnp.asarray(bag["row_key"]), tree_index, bag["sample_rate"],
+                (npad,)))
+        return mask & (np.arange(npad) < bag["nrow"])
 
     def _predict_raw(self, frame: Frame) -> np.ndarray:
         return np.asarray(self._predict_raw_dev(frame))
@@ -74,6 +97,7 @@ class DRF(ModelBuilder):
                        nrow, K, classification, varimp_dev, history):
         """Interval-snapshot factory (see GBM._partial_model)."""
         out = {
+            "bootstrap": self._bootstrap,
             "bin_spec": spec,
             "trees": [list(g) for g in trees],
             "n_tree_classes": n_out,
@@ -125,21 +149,25 @@ class DRF(ModelBuilder):
             mtries = C
         col_rate = min(1.0, mtries / C)
 
-        y_np = yv.to_numpy().astype(np.float64)
-        w_np = np.zeros(npad, np.float32)
-        w_np[: train.nrow] = 1.0
-        if p.weights_column:
-            w_np[: train.nrow] *= np.nan_to_num(
-                train.vec(p.weights_column).to_numpy()
-            ).astype(np.float32)
-        w_np[: train.nrow] *= (y_np >= 0) if classification else ~np.isnan(y_np)
-        ybuf = np.zeros(npad, np.float32)
-        ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
-        w = jnp.asarray(w_np)
-        y = jnp.asarray(ybuf)
+        # response / weights on device (span drf.response_lanes, as GBM's:
+        # starts with the label's pull, ends in enqueued uploads)
+        with _mx.span("drf.response_lanes"):
+            y_np = yv.to_numpy().astype(np.float64)
+            w_np = np.zeros(npad, np.float32)
+            w_np[: train.nrow] = 1.0
+            if p.weights_column:
+                w_np[: train.nrow] *= np.nan_to_num(
+                    train.vec(p.weights_column).to_numpy()
+                ).astype(np.float32)
+            w_np[: train.nrow] *= (y_np >= 0) if classification else ~np.isnan(y_np)
+            ybuf = np.zeros(npad, np.float32)
+            ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
+            w = jnp.asarray(w_np)
+            y = jnp.asarray(ybuf)
         wn, yn = w_np, ybuf  # host copies already exist — never pull from device
 
         rngkey = jax.random.PRNGKey(abs(p.seed) if p.seed and p.seed > 0 else 5678)
+        row_key = rngkey  # pristine: the bags are keyed by it and the tree's index
 
         n_out = K if K > 1 else 1
         F = [jnp.zeros(npad, jnp.float32) for _ in range(n_out)]
@@ -188,6 +216,13 @@ class DRF(ModelBuilder):
                 for _ in range(start_trees):
                     rngkey, _ = jax.random.split(rngkey)
 
+        # what DRFModel.inbag_rows re-derives a tree's bag from
+        self._bootstrap = {
+            "row_key": np.asarray(row_key), "sample_rate": float(p.sample_rate),
+            "npad": int(npad), "nrow": int(train.nrow),
+            "first_tree": int(start_trees),
+        }
+
         # Chunk-scanned path (see gbm.py / build_trees_scanned): one device
         # dispatch per scoring interval per class, on every backend. The
         # bootstrap row mask is keyed by the shared row_key so all K
@@ -219,26 +254,32 @@ class DRF(ModelBuilder):
                 chunk = min(interval, cap, p.ntrees - m_done)
                 chunk_trees: list[list[Tree]] = [[] for _ in range(chunk)]
                 for k in range(n_out):
-                    F[k], varimp_dev, stacked = build_trees_scanned(
-                        bins, w, targets[k], F[k], varimp_dev,
-                        jax.random.fold_in(rngkey, 7919 + k), chunk,
-                        row_key=rngkey,
-                        tree_offset=m_done,
-                        grad_fn=lambda F_, y_, w_: (y_, w_),  # leaf = node mean
-                        grad_key=("drf",),
-                        sample_rate=p.sample_rate,
-                        n_bins=n_bins,
-                        is_cat_cols=spec.is_cat,
-                        max_depth=p.max_depth,
-                        min_rows=p.min_rows,
-                        min_split_improvement=p.min_split_improvement,
-                        learn_rates=np.ones(chunk, np.float32),
-                        max_abs_leaf=float("inf"),
-                        col_sample_rate=col_rate,
-                        col_sample_rate_per_tree=1.0,
-                    )
-                    for ti, tr in enumerate(trees_from_stacked(stacked, chunk)):
-                        chunk_trees[ti].append(tr)
+                    with _mx.span("drf.build_tree", trees=chunk,
+                                  tree_offset=m_done):
+                        F[k], varimp_dev, stacked = build_trees_scanned(
+                            bins, w, targets[k], F[k], varimp_dev,
+                            jax.random.fold_in(rngkey, 7919 + k), chunk,
+                            row_key=row_key,
+                            tree_offset=m_done,
+                            grad_fn=lambda F_, y_, w_: (y_, w_),  # leaf = node mean
+                            grad_key=("drf",),
+                            sample_rate=p.sample_rate,
+                            n_bins=n_bins,
+                            is_cat_cols=spec.is_cat,
+                            max_depth=p.max_depth,
+                            min_rows=p.min_rows,
+                            min_split_improvement=p.min_split_improvement,
+                            learn_rates=np.ones(chunk, np.float32),
+                            max_abs_leaf=float("inf"),
+                            col_sample_rate=col_rate,
+                            col_sample_rate_per_tree=1.0,
+                        )
+                    # waits for the chunk — unless build_tree already did: a
+                    # tree with a saturated region syncs on its executed
+                    # levels (_run_counted), and the busy time shows there
+                    with _mx.span("drf.pull_records", trees=chunk):
+                        for ti, tr in enumerate(trees_from_stacked(stacked, chunk)):
+                            chunk_trees[ti].append(tr)
                     if Fv is not None:
                         Fv[k] = replay_batch(bins_v, stacked, Fv[k])
                 trees.extend(chunk_trees)
@@ -278,28 +319,29 @@ class DRF(ModelBuilder):
         for m in range(start_trees if not use_scan else p.ntrees, p.ntrees):
             if job.stop_requested and m > start_trees:
                 break  # always ≥1 tree (see scan loop comment)
-            rngkey, sk = jax.random.split(rngkey)
-            mask = jax.random.bernoulli(sk, p.sample_rate, (npad,)).astype(jnp.float32)
-            w_tree = w * mask
+            rngkey, _ = jax.random.split(rngkey)
+            mask = bootstrap_mask(row_key, m, p.sample_rate, (npad,))
+            w_tree = w * mask.astype(jnp.float32)
             group = []
             tree_key = jax.random.fold_in(rngkey, m)
             for k in range(n_out):
-                tree, fk, varimp_dev = build_tree(
-                    bins,
-                    w_tree,
-                    targets[k],
-                    w_tree,  # hessian = weight → leaf = node mean
-                    n_bins=n_bins,
-                    is_cat_cols=spec.is_cat,
-                    max_depth=p.max_depth,
-                    min_rows=p.min_rows,
-                    min_split_improvement=p.min_split_improvement,
-                    learn_rate=1.0,
-                    preds=F[k],
-                    key=jax.random.fold_in(tree_key, k),
-                    varimp=varimp_dev,
-                    col_sample_rate=col_rate,
-                )
+                with _mx.span("drf.build_tree", tree=m):
+                    tree, fk, varimp_dev = build_tree(
+                        bins,
+                        w_tree,
+                        targets[k],
+                        w_tree,  # hessian = weight → leaf = node mean
+                        n_bins=n_bins,
+                        is_cat_cols=spec.is_cat,
+                        max_depth=p.max_depth,
+                        min_rows=p.min_rows,
+                        min_split_improvement=p.min_split_improvement,
+                        learn_rate=1.0,
+                        preds=F[k],
+                        key=jax.random.fold_in(tree_key, k),
+                        varimp=varimp_dev,
+                        col_sample_rate=col_rate,
+                    )
                 group.append(tree)
                 F[k] = fk
             trees.append(group)
@@ -340,6 +382,7 @@ class DRF(ModelBuilder):
             job.update(0.05 + 0.9 * (m + 1) / p.ntrees)
 
         out = {
+            "bootstrap": self._bootstrap,
             "bin_spec": spec,
             "trees": trees,
             "n_tree_classes": n_out,
@@ -352,13 +395,17 @@ class DRF(ModelBuilder):
         model.scoring_history = history
         nt = max(len(trees), 1)
         dom = out["response_domain"]
-        model.training_metrics = self._metrics_from_F(
-            F, yn, wn, train.nrow, nt, K, classification, domain=dom
-        )
-        if valid is not None:
-            model.validation_metrics = self._metrics_from_F(
-                Fv, yv_np, wv_np, valid.nrow, nt, K, classification, domain=dom
+        # from the running sums F (no replay of the trees), over ALL rows of
+        # the frame, in-bag and out: H2O reports out-of-bag training metrics
+        # (ROADMAP B-R); ends in the statistics' pull
+        with _mx.span("model.score_metrics", algo=self.algo):
+            model.training_metrics = self._metrics_from_F(
+                F, yn, wn, train.nrow, nt, K, classification, domain=dom
             )
+            if valid is not None:
+                model.validation_metrics = self._metrics_from_F(
+                    Fv, yv_np, wv_np, valid.nrow, nt, K, classification, domain=dom
+                )
         from h2o3_tpu.models.calibration import maybe_fit_calibration
 
         maybe_fit_calibration(self, model)
@@ -384,7 +431,9 @@ class DRF(ModelBuilder):
         return MM.regression_metrics(yn[:nrow], avg[0], wn[:nrow])
 
     def _train_metric(self, F, yn, wn, nrow, ntrees, K, classification, metric_name) -> float:
-        m = self._metrics_from_F(F, yn, wn, nrow, ntrees, K, classification)
+        """Span ``drf.train_metric``: ends in the pull of the statistics."""
+        with _mx.span("drf.train_metric", metric=metric_name):
+            m = self._metrics_from_F(F, yn, wn, nrow, ntrees, K, classification)
         v = m._v.get(metric_name)
         if v is None:
             v = m._v.get("logloss" if classification else "rmse")

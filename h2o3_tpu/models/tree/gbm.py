@@ -98,6 +98,15 @@ class SharedTreeModel(Model):
         "na_left", "leaf_now", "leaf_val", "child_base",
     )
 
+    def offered_columns(self, tree_index: int, tree_class: int = 0) -> list:
+        """Per level of the tree, ``(nodes, C)`` bool: the columns each node
+        was offered for its split (exactly ``mtries`` of them; none on the
+        terminal level, which scans nothing), as the builder's split scan
+        saw them — ``split_col`` of a decided node is one of its row's."""
+        C = len(self.output["names"])
+        levels = self.output["trees"][tree_index][tree_class].levels
+        return [np.asarray(lv.col_offer)[:, :C] for lv in levels]
+
     def _replay_all(self, frame: Frame) -> np.ndarray:
         out = self._replay_all_dev(frame)
         return np.asarray(out)[: frame.nrow]

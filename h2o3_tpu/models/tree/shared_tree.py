@@ -145,6 +145,19 @@ _PART_LEVELS = _metrics.counter(
     "programs, by formulation", always=True)
 
 
+# Node tiles (ISSUE 32): the histogram op's unit of work at a level is a tile
+# of ``hist_pallas.NODE_TILE`` (64) node slots over every row — one kernel
+# grid pass. ``histogram_in_jit`` tallies ceil(nodes / 64) where it is traced
+# into a program and ``_run_counted`` replays the tally at each dispatch like
+# the HBM bytes (a scanned chunk by its trees, the saturated region by the
+# levels that ran), so kernel seconds / this counter is the cost of a node
+# tile: 20 ms at one node, 144 ms at 64 (PERF.md §5).
+_NODE_TILES = _metrics.counter(
+    "tree_node_tiles_total",
+    "node tiles of 64 node slots the tree programs asked the histogram op "
+    "to build (one pass over the rows each)", always=True)
+
+
 def count_partition_levels(n: int) -> None:
     """``n`` partition passes of a program dispatched outside
     :func:`_run_counted`."""
@@ -227,6 +240,8 @@ def _run_counted(fn, args, mult: int = 1, sat_from=None):
             _HIST_HBM_BYTES.inc(b * m, path=ph[4:])
         elif ph.startswith("part/"):
             _PART_LEVELS.inc(b * m, path=ph[5:])
+        elif ph == "node_tiles":
+            _NODE_TILES.inc(b * m)
         else:
             _COLL_BYTES.inc(b * m, phase=ph)
             _COLL_BYTES.inc(b * m, phase=ph, lane=lane)
@@ -828,6 +843,7 @@ def _leaf_decide(
     ok, gain, node_w, node_wy, node_wh, split_col, split_bin,
     is_cat_n, cat_mask, na_left, learn_rate, max_abs_leaf, n_pad,
     node_lo=None, node_hi=None, reg_lambda=None, reg_alpha=None,
+    *, col_offer,
 ):
     """Leaf decision + child-id assignment + the replayable record — the
     partition-free head of :func:`_finish_level`, shared with the
@@ -838,6 +854,10 @@ def _leaf_decide(
     scalars): leaf = soft_threshold(Σwy, α) / (Σwh + λ) — xgboost's
     w* = −soft(G, α)/(H + λ) with our sign convention (wy ≡ −G, wh ≡ H).
     None keeps the unregularized trace byte-identical (the H2O GBM path).
+
+    ``col_offer`` ((n_pad, C) 0/1, :func:`_offered_columns`' mask as the
+    split scan saw it; all zero on a level that scans nothing) is kept in
+    the record beside ``split_col``: which columns each node was offered.
     """
     leaf_now = ~ok
     if reg_lambda is not None:
@@ -866,6 +886,7 @@ def _leaf_decide(
         "leaf_val": leaf_val,
         "child_base": child_base,
         "gain": gain,
+        "col_offer": col_offer > 0,
     }
     return leaf_now, leaf_val, child_base, cs, n_split, record
 
@@ -874,7 +895,7 @@ def _finish_level(
     bins_u8, nid, preds, varimp, ok, gain, node_w, node_wy, node_wh,
     split_col, split_bin, is_cat_n, cat_mask, na_left,
     learn_rate, max_abs_leaf, n_pad, node_lo=None, node_hi=None,
-    reg_lambda=None, reg_alpha=None, any_cat: bool = True,
+    reg_lambda=None, reg_alpha=None, any_cat: bool = True, col_offer=None,
 ):
     """Shared tail of every level: leaf decision, child-id assignment,
     varimp scatter, partition update, and the replayable record.
@@ -883,13 +904,16 @@ def _finish_level(
     values when given; None leaves the unconstrained trace byte-identical.
     ``any_cat`` False (a frame with no categorical column, or the all-leaf
     terminal level) keeps the membership-mask term out of the partition.
+    ``col_offer`` None (a level that scans nothing) records no column offered.
     """
+    if col_offer is None:
+        col_offer = jnp.zeros((n_pad, bins_u8.shape[1]), jnp.float32)
     with jax.named_scope("ph_leaf"):
         leaf_now, leaf_val, child_base, cs, n_split, record = _leaf_decide(
             ok, gain, node_w, node_wy, node_wh, split_col, split_bin,
             is_cat_n, cat_mask, na_left, learn_rate, max_abs_leaf, n_pad,
             node_lo=node_lo, node_hi=node_hi,
-            reg_lambda=reg_lambda, reg_alpha=reg_alpha,
+            reg_lambda=reg_lambda, reg_alpha=reg_alpha, col_offer=col_offer,
         )
 
     varimp = varimp.at[split_col].add(jnp.where(ok, gain, 0.0).astype(varimp.dtype))
@@ -926,6 +950,40 @@ def _child_bounds(ok, child_base, mono_col, mid, node_lo, node_hi,
     new_hi = new_hi.at[li].set(l_hi, mode="drop")
     new_hi = new_hi.at[ri].set(r_hi, mode="drop")
     return new_lo, new_hi
+
+
+def _offered_columns(key, cols_enabled, col_sample_rate, n_pad: int,
+                     n_cols_real: int | None = None):
+    """The columns each node of a level may split on: ``(n_pad, C)`` float
+    0/1 — exactly ``k = max(1, round(col_sample_rate * C_real))`` distinct
+    columns a node (H2O's ``mtries`` / ``col_sample_rate`` per split), drawn
+    uniformly without replacement from the tree's enabled columns: the ``k``
+    smallest of one random word a column. The words are made distinct (the
+    column's index in their low bits), so a node is never offered ``k + 1``
+    on a tie; fewer than ``k`` enabled columns offers them all; rate 1.0
+    offers every enabled column. The one draw of every builder
+    (:func:`_level_core`, the monotone step, the streamed decide).
+
+    The draw runs at the REAL column count (``n_cols_real``) so
+    shape-bucketed column padding cannot perturb which columns a node
+    samples — bucketed builds stay bit-identical. ``col_sample_rate`` may be
+    a traced scalar.
+    """
+    C = cols_enabled.shape[0]
+    Cr = n_cols_real or C
+    k = jnp.clip(jnp.round(col_sample_rate * Cr), 1, Cr).astype(jnp.int32)
+    low = max(1, (Cr - 1).bit_length())
+    col = jnp.arange(Cr, dtype=jnp.uint32)
+    word = jax.random.bits(key, (n_pad, Cr), jnp.uint32)
+    word = (word >> (low + 1) << low) | col  # distinct; the top bit is free
+    enabled = cols_enabled[:Cr] > 0
+    word = jnp.where(enabled, word, word | jnp.uint32(1 << 31))  # disabled last
+    kth = jnp.take_along_axis(
+        jnp.sort(word, axis=1), jnp.broadcast_to(k - 1, (n_pad, 1)), axis=1)
+    keep = (word <= kth) & enabled
+    if Cr < C:
+        keep = jnp.pad(keep, ((0, 0), (0, C - Cr)))
+    return keep.astype(cols_enabled.dtype)
 
 
 def _level_core(
@@ -965,20 +1023,8 @@ def _level_core(
     zero, so every candidate split fails the min_rows check and they retire
     as zero-valued leaves that no row is assigned to.
     """
-    C = bins_u8.shape[1]
-    # per-(node,col) sampling mask (H2O col_sample_rate per split).
-    # Fallback when a node draws no columns: use all (rare; H2O instead
-    # redraws one uniformly — indistinguishable in expectation at our
-    # histogram granularity). The draw runs at the REAL column count
-    # (n_cols_real) so shape-bucketed column padding cannot perturb which
-    # columns a node samples — bucketed builds stay bit-identical.
-    Cr = n_cols_real or C
-    col_mask = jnp.broadcast_to(cols_enabled[None, :], (n_pad, C))
-    keep = jax.random.uniform(key, (n_pad, Cr)) < col_sample_rate
-    keep = jnp.where(keep.any(axis=1, keepdims=True), keep, True)
-    if Cr < C:
-        keep = jnp.pad(keep, ((0, 0), (0, C - Cr)))
-    col_mask = col_mask * keep
+    col_mask = _offered_columns(
+        key, cols_enabled, col_sample_rate, n_pad, n_cols_real)
     # ph_split: phase tag (utils/telemetry.summarize, tools/profile_fused.py)
     with jax.named_scope("ph_split"):
         if split_shard:
@@ -1014,6 +1060,7 @@ def _level_core(
         sp["col"], sp["split_bin"], sp["is_cat"], sp["cat_mask"], sp["na_left"],
         learn_rate, max_abs_leaf, n_pad,
         reg_lambda=rl, reg_alpha=ra, any_cat=bool(cat_cols),
+        col_offer=col_mask,
     )
 
     half = n_pad_next // 2
@@ -1233,6 +1280,8 @@ def _fused_levels(
                 "is_cat": zb, "cat_mask": jnp.zeros((n_sat, node_cap, n_bins), bool),
                 "na_left": zb, "leaf_now": jnp.ones((n_sat, node_cap), bool),
                 "leaf_val": zf, "child_base": zi, "gain": zf,
+                "col_offer": jnp.zeros(
+                    (n_sat, node_cap, bins_u8.shape[1]), bool),
             }
 
             def sat_cond(carry):
@@ -1359,6 +1408,18 @@ def use_fused_trees(max_depth: int) -> bool:
 # blocks, the 2-D mesh row axis) composes with no new code paths.
 
 
+def bootstrap_mask(row_key, tree_index, sample_rate: float, shape):
+    """The rows in tree ``tree_index``'s bag: a Bernoulli(``sample_rate``)
+    draw a row (``sample_rate`` without replacement), keyed by the builder's
+    row key and the tree's global index alone. The one function behind every
+    resident build's row sample — the chunk program calls it traced, DRF's
+    per-tree loop and ``DRFModel.inbag_rows`` call it directly — so a fitted
+    model can say which rows each of its trees saw."""
+    return jax.random.bernoulli(
+        jax.random.fold_in(jax.random.fold_in(row_key, tree_index), 1 << 29),
+        sample_rate, shape)
+
+
 def _goss_ab() -> tuple[float, float] | None:
     """Parse ``H2O3_TPU_TREE_GOSS='a,b'``; None (knob empty) = GOSS off."""
     from h2o3_tpu import config
@@ -1422,7 +1483,6 @@ def _level_step_mono_fn(
     [lo, hi] bounds; children of a constrained split get tightened bounds."""
     from h2o3_tpu.ops.histogram import histogram_in_jit
 
-    C = bins_u8.shape[1]
     hist = histogram_in_jit(
         bins_u8, nid, (w, wy, wh), n_pad, n_bins, col_sharded=split_shard
     )
@@ -1440,10 +1500,7 @@ def _level_step_mono_fn(
         mid = jnp.zeros(n_pad, jnp.float32)
         mono_col = jnp.zeros(n_pad, jnp.int32)
     else:
-        col_mask = jnp.broadcast_to(cols_enabled[None, :], (n_pad, C))
-        keep = jax.random.uniform(key, (n_pad, C)) < col_sample_rate
-        keep = jnp.where(keep.any(axis=1, keepdims=True), keep, True)
-        col_mask = col_mask * keep
+        col_mask = _offered_columns(key, cols_enabled, col_sample_rate, n_pad)
         if split_shard:
             sp = _split_scan_sharded(
                 hist, is_cat, col_mask, min_rows, min_split_improvement,
@@ -1465,12 +1522,13 @@ def _level_step_mono_fn(
         mid, mono_col = sp["mid"], sp["mono_col"]
 
     rl, ra = (None, None) if leaf_reg is None else leaf_reg
+    col_offer = None if force_leaf else col_mask
     nid, preds, varimp, n_split, record, cs = _finish_level(
         bins_u8, nid, preds, varimp, ok, gain, node_w, node_wy, node_wh,
         split_col, split_bin, is_cat_n, cat_mask, na_left,
         learn_rate, max_abs_leaf, n_pad, node_lo=node_lo, node_hi=node_hi,
         reg_lambda=rl, reg_alpha=ra,
-        any_cat=bool(cat_cols) and not force_leaf,
+        any_cat=bool(cat_cols) and not force_leaf, col_offer=col_offer,
     )
     # child bounds scatter: left child at child_base, right at child_base+1
     new_lo, new_hi = _child_bounds(
@@ -1691,11 +1749,7 @@ def build_trees_scanned(
                 m = i + offset
                 tkey = jax.random.fold_in(base_key, m)
                 if sample_rate < 1.0:
-                    mask = jax.random.bernoulli(
-                        jax.random.fold_in(jax.random.fold_in(row_key_, m), 1 << 29),
-                        sample_rate,
-                        w.shape,
-                    )
+                    mask = bootstrap_mask(row_key_, m, sample_rate, w.shape)
                     w_tree = w * mask.astype(w.dtype)
                 else:
                     w_tree = w
@@ -1808,7 +1862,7 @@ def scan_chunk_cap(
 # uint8 lanes (exact, any magnitude); bools ship as 1 byte each, so the
 # payload stays byte-sized for cat_mask — the dominant field.
 _PACK_I32 = ("split_col", "split_bin", "child_base")
-_PACK_BOOL = ("is_cat", "na_left", "leaf_now", "cat_mask")
+_PACK_BOOL = ("is_cat", "na_left", "leaf_now", "cat_mask", "col_offer")
 _PACK_F32 = ("node_w", "leaf_val", "gain")
 _PACK_FIELDS = _PACK_F32 + _PACK_I32 + _PACK_BOOL
 
@@ -1905,6 +1959,9 @@ class TreeLevel:
     child_base: jnp.ndarray
     gain: jnp.ndarray | None = None  # per-node split gain (varimp source)
     node_w: jnp.ndarray | None = None  # per-node weighted cover (TreeSHAP)
+    # (n_pad, C) bool: the columns each node was offered (mtries /
+    # col_sample_rate per split; all False on a level that scanned nothing)
+    col_offer: jnp.ndarray | None = None
 
 
 @dataclass
@@ -2226,18 +2283,16 @@ def _stream_decide_prog(n_pad: int, n_pad_next: int, n_bins: int,
                     jnp.zeros(n_pad, bool), learn_rate, max_abs_leaf,
                     n_pad, node_lo=node_lo, node_hi=node_hi,
                     reg_lambda=rl, reg_alpha=ra,
+                    col_offer=jnp.zeros((n_pad, n_cols), jnp.float32),
                 )
                 if mono:
                     return (varimp, n_split, rec,
                             jnp.full(n_pad_next, -jnp.inf, jnp.float32),
                             jnp.full(n_pad_next, jnp.inf, jnp.float32))
                 return varimp, n_split, rec
-            # per-(node,col) sampling mask — same draw as _level_core at
-            # the REAL column count (the streamed path never column-pads)
-            col_mask = jnp.broadcast_to(cols_enabled[None, :], (n_pad, n_cols))
-            keep = jax.random.uniform(key_, (n_pad, n_cols)) < col_sample_rate
-            keep = jnp.where(keep.any(axis=1, keepdims=True), keep, True)
-            col_mask = col_mask * keep
+            # the streamed path never column-pads: n_cols is the real count
+            col_mask = _offered_columns(
+                key_, cols_enabled, col_sample_rate, n_pad)
             sp = _split_scan(hist, is_cat, col_mask, min_rows, msi, cat_cols,
                              mono=mono_vec, node_lo=node_lo, node_hi=node_hi)
             ok = sp["ok"]
@@ -2249,7 +2304,7 @@ def _stream_decide_prog(n_pad: int, n_pad_next: int, n_bins: int,
                 sp["col"], sp["split_bin"], sp["is_cat"], sp["cat_mask"],
                 sp["na_left"], learn_rate, max_abs_leaf, n_pad,
                 node_lo=node_lo, node_hi=node_hi,
-                reg_lambda=rl, reg_alpha=ra,
+                reg_lambda=rl, reg_alpha=ra, col_offer=col_mask,
             )
             varimp = varimp.at[sp["col"]].add(
                 jnp.where(ok, gain, 0.0).astype(varimp.dtype))

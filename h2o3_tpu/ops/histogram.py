@@ -333,6 +333,12 @@ def histogram_in_jit(
 
     smat = jnp.stack(list(stats), axis=1)  # (n, S)
 
+    # node tiles asked for (tree_node_tiles_total): 64 node slots over every
+    # row each, tallied and replayed per dispatch like the bytes below
+    from h2o3_tpu.ops.hist_pallas import NODE_TILE
+
+    record_collective("node_tiles", -(-n_nodes // NODE_TILE))
+
     # HBM model of hist + split (see record_hbm): the dense tensor
     # is written once and its (possibly column-sharded) slice re-read by the
     # split scan; the Pallas local impl additionally pays its two unscramble

@@ -51,14 +51,25 @@ GBM_TREE = {
 }
 
 
-@pytest.mark.parametrize("algo,want", [("glm", GLM_TREE), ("gbm", GBM_TREE)])
+# a forest's builder has GBM's spans under its own name (ISSUE 32)
+DRF_TREE = {
+    (k.replace("gbm.", "drf.")): (
+        {x.replace("gbm.", "drf.") for x in v} if isinstance(v, set)
+        else v and v.replace("gbm.", "drf."))
+    for k, v in GBM_TREE.items()}
+
+
+@pytest.mark.parametrize("algo,want", [
+    ("glm", GLM_TREE), ("gbm", GBM_TREE), ("drf", DRF_TREE)])
 def test_train_leaves_one_tree_rooted_at_train(algo, want):
     from h2o3_tpu import estimators as E
 
-    est = (E.H2OGeneralizedLinearEstimator(family="binomial", lambda_=1e-4)
-           if algo == "glm" else
-           E.H2OGradientBoostingEstimator(ntrees=4, max_depth=3,
-                                          score_tree_interval=2, seed=1))
+    est = {"glm": lambda: E.H2OGeneralizedLinearEstimator(
+               family="binomial", lambda_=1e-4),
+           "gbm": lambda: E.H2OGradientBoostingEstimator(
+               ntrees=4, max_depth=3, score_tree_interval=2, seed=1),
+           "drf": lambda: E.H2ORandomForestEstimator(
+               ntrees=4, max_depth=5, score_tree_interval=2, seed=1)}[algo]()
     flightrec.reset()
     before = set(mx._TRACES)
     est.train(y="y", training_frame=_frame())
@@ -92,7 +103,7 @@ def test_train_leaves_one_tree_rooted_at_train(algo, want):
             if e["site"] == site and e["trace"] == tid]
     assert disp and all(
         by_id[d["parent"]]["name"] == ("glm.fit" if algo == "glm"
-                                       else "gbm.build_tree") for d in disp)
+                                       else f"{algo}.build_tree") for d in disp)
 
 
 @pytest.mark.parametrize("with_validation", [False, True])

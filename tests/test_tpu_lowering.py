@@ -118,11 +118,14 @@ def test_histogram_kernel_step_has_no_lane_tiling(n_nodes):
     assert body.count("tpu.matmul") == ct  # one contraction a column
 
 
-def _chunk_program(monkeypatch, max_depth, node_cap, rows=ROWS, sharding=None):
+def _chunk_program(monkeypatch, max_depth, node_cap, rows=ROWS, sharding=None,
+                   drf=False):
     """The jitted 5-tree ``build_trees_scanned`` chunk program at 28 columns
     and 255 bins with the chip's branches taken, and its operands as
     shapes: what a ``gbm_higgs`` call dispatches, captured where
-    ``_run_counted`` would run it."""
+    ``_run_counted`` would run it. ``drf``: a random forest's OWN program,
+    as ``drf.py`` asks for it — the 0.632 bootstrap, 5 of 28 columns a node,
+    ``min_rows`` 1, the response as the gradient."""
     import numpy as np
 
     from h2o3_tpu.models.tree.distributions import grad_hess
@@ -144,12 +147,14 @@ def _chunk_program(monkeypatch, max_depth, node_cap, rows=ROWS, sharding=None):
             S((rows, cols), jnp.uint8), S((rows,), jnp.float32),
             S((rows,), jnp.float32), S((rows,), jnp.float32),
             S((cols,), jnp.float32), jax.random.PRNGKey(42), 5,
-            grad_fn=lambda F, y, w: grad_hess("bernoulli", F, y, w, 0.0),
-            grad_key=("lowering", max_depth), sample_rate=1.0, n_bins=255,
+            grad_fn=((lambda F, y, w: (y, w)) if drf else
+                     (lambda F, y, w: grad_hess("bernoulli", F, y, w, 0.0))),
+            grad_key=("drf",) if drf else ("lowering", max_depth),
+            sample_rate=0.632 if drf else 1.0, n_bins=255,
             is_cat_cols=np.zeros(cols, bool), max_depth=max_depth,
-            min_rows=10.0, min_split_improvement=1e-5,
-            learn_rates=np.full(5, 0.1), max_abs_leaf=np.inf,
-            col_sample_rate=1.0, col_sample_rate_per_tree=1.0,
+            min_rows=1.0 if drf else 10.0, min_split_improvement=1e-5,
+            learn_rates=np.full(5, 1.0 if drf else 0.1), max_abs_leaf=np.inf,
+            col_sample_rate=5 / 28 if drf else 1.0, col_sample_rate_per_tree=1.0,
             node_cap=node_cap,
         )
     shapes = jax.tree_util.tree_map(
@@ -208,9 +213,10 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("max_depth", [6, 7, 8, 20])
+@pytest.mark.parametrize("max_depth,drf", [
+    (6, False), (7, False), (8, False), (20, False), (20, True)])
 def test_tree_chunk_program_compiles_for_a_described_v5e(
-        monkeypatch, v5e_chip, no_compile_cache, max_depth):
+        monkeypatch, v5e_chip, no_compile_cache, max_depth, drf):
     """What the chip's own compiler says of the chunk program, with no chip
     (a compile is not a run). Depth 6 — the benchmark's cells — and depth 7
     compile, and since ISSUE 31 so do depth 8 and a DRF's depth 20 at
@@ -219,7 +225,11 @@ def test_tree_chunk_program_compiles_for_a_described_v5e(
     tile) has its kernel's ``f32[192, 8192]`` output placed in VMEM by XLA
     inside the tree program, and with the old grid step's 4 MB lane-tiled
     code block and its copies the kernel overran its 16 MB of scoped VMEM by
-    676 KB (``RESOURCE_EXHAUSTED`` from ``lowered.compile()``)."""
+    676 KB (``RESOURCE_EXHAUSTED`` from ``lowered.compile()``). The last case
+    is ``drf_higgs``'s own program (ISSUE 32): the bootstrap, the exact draw
+    of 5 of 28 columns a node (a sort in every level, the saturated loop's
+    body too) and ``min_rows`` 1, where the others compile GBM's gradients
+    with no sampling."""
     from jax.sharding import Mesh, SingleDeviceSharding
 
     import numpy as np
@@ -231,7 +241,7 @@ def test_tree_chunk_program_compiles_for_a_described_v5e(
     try:
         fn, shapes = _chunk_program(
             monkeypatch, max_depth, 2048, rows=65_536,
-            sharding=SingleDeviceSharding(v5e_chip))
+            sharding=SingleDeviceSharding(v5e_chip), drf=drf)
         lowered = fn.lower(*shapes)
     finally:
         pm.set_mesh(old)
