@@ -157,6 +157,23 @@ _NODE_TILES = _metrics.counter(
     "node tiles of 64 node slots the tree programs asked the histogram op "
     "to build (one pass over the rows each)", always=True)
 
+# The grouped histogram (ISSUE 33): a level wider than one node tile reads its
+# rows in node order and contracts a row chunk only against the node tiles
+# whose rows it holds. Levels are tallied where ``histogram_in_jit`` is traced
+# with an order and replayed like the node tiles; the grid steps that
+# contracted a chunk are counted on the device (they depend on the data) and
+# come back with the executed saturated levels. steps / (the grouped levels'
+# node tiles x row chunks x column tiles) is the share of the dense pass still
+# run: 1 / n_nt where the order holds, 1 where a level fell back to dense.
+_GROUPED_LEVELS = _metrics.counter(
+    "tree_hist_grouped_levels_total",
+    "tree levels whose histogram ran grouped over rows in node order",
+    always=True)
+_CHUNK_VISITS = _metrics.counter(
+    "tree_hist_chunk_visits_total",
+    "kernel grid steps that contracted a row chunk in grouped histogram "
+    "levels, over all shards and column tiles", always=True)
+
 
 def count_partition_levels(n: int) -> None:
     """``n`` partition passes of a program dispatched outside
@@ -182,7 +199,7 @@ _PROG_KEY: dict[int, tuple] = {}
 _PROG_COLL: dict = {}
 
 
-def _run_counted(fn, args, mult: int = 1, sat_from=None):
+def _run_counted(fn, args, mult: int = 1, counts_from=None):
     """Dispatch ``fn(*args)`` with collective byte accounting.
 
     ``mult`` scales the traced tally per dispatch (a scanned chunk's body
@@ -190,11 +207,13 @@ def _run_counted(fn, args, mult: int = 1, sat_from=None):
     ``tally_group("sat")`` — the node_cap-saturated while_loop body, traced
     once but executed a data-dependent number of times — are instead
     scaled by the EXECUTED iteration count, extracted from the program's
-    output via ``sat_from(out)`` (the fused programs return it), so the
+    output via ``counts_from(out)`` (the fused programs return int32
+    ``[executed saturated levels, grouped histogram grid steps]``), so the
     counters report actual volume, not the old n_sat trace-time upper
-    bound. Reading that scalar syncs the dispatch — one int32 pull, and
-    only for programs that traced a saturated region at all (deep builds
-    whose per-level cost dwarfs it; GBM-typical shallow trees never pay)."""
+    bound. Reading the pair syncs the dispatch — one 8-byte pull, and
+    only for programs that traced a saturated region or a grouped level
+    (deep builds whose per-level cost dwarfs it; GBM-typical shallow trees
+    never pay)."""
     from h2o3_tpu.ops.collectives import collective_tally
     from h2o3_tpu.utils import flightrec as _fr
 
@@ -222,18 +241,14 @@ def _run_counted(fn, args, mult: int = 1, sat_from=None):
         for (ph, _lane, _grp), b in agg.items():
             by_phase[ph] = by_phase.get(ph, 0) + int(b)
         _fr.record("collectives", **by_phase)
-    sat_n = None
+    sat_n = 0
+    if counts_from is not None and any(
+            grp == "sat" or ph == "hist_grouped" for ph, _lane, grp in agg):
+        sat_n, steps = (int(c) for c in jax.device_get(counts_from(out)))
+        BUILD_STATS["sat_levels_executed"] += sat_n
+        _CHUNK_VISITS.inc(steps)
     for (ph, lane, grp), b in agg.items():
-        if grp == "sat":
-            if sat_n is None:
-                sat_n = (
-                    int(jax.device_get(sat_from(out)))
-                    if sat_from is not None else 0
-                )
-                BUILD_STATS["sat_levels_executed"] += sat_n
-            m = sat_n
-        else:
-            m = mult
+        m = sat_n if grp == "sat" else mult
         if not b or not m:
             continue
         if ph.startswith("hbm/"):
@@ -242,6 +257,8 @@ def _run_counted(fn, args, mult: int = 1, sat_from=None):
             _PART_LEVELS.inc(b * m, path=ph[5:])
         elif ph == "node_tiles":
             _NODE_TILES.inc(b * m)
+        elif ph == "hist_grouped":
+            _GROUPED_LEVELS.inc(b * m)
         else:
             _COLL_BYTES.inc(b * m, phase=ph)
             _COLL_BYTES.inc(b * m, phase=ph, lane=lane)
@@ -1195,8 +1212,14 @@ def _fused_levels(
     so subtraction, the split scans and the partition walk are untouched —
     the O(rows · C) accumulation is the only thing that shrinks. EFB rides
     the replicated lane only (callers force ``split_shard=False``).
+
+    Returns ``(nid, preds, varimp, records, counts)``, ``counts`` the int32
+    pair [executed saturated levels, grid steps of the grouped histogram
+    levels] that :func:`_run_counted` reads back.
     """
-    from h2o3_tpu.ops.histogram import histogram_in_jit
+    from h2o3_tpu.ops.histogram import (
+        histogram_in_jit, order_codes_in_jit, restore_rows_in_jit,
+        row_order_in_jit)
 
     efb_expand = None
     if efb is not None:
@@ -1219,31 +1242,54 @@ def _fused_levels(
     n_split = None
     sat_start, n_sat = _sat_region(max_depth, node_cap)
     bins_h = bins_b if efb_expand else bins_u8  # what the histograms read
+    # The rows in node order (ISSUE 33), made before the first level whose
+    # histogram is wider than one node tile. From there on the TREE lives in
+    # that order — codes, node ids and predictions are permuted once, every
+    # later level partitions and histograms them as they lie, and the ids
+    # and predictions go back to the frame's order at the tree's end: no
+    # level pays a pass to bring its ids into the order. Children are
+    # numbered in their parents' order (``_leaf_decide``'s child_base), so the
+    # descendants of a sorted node keep its segment of the rows and a
+    # contiguous node range: the rows stay in node-tile order at the next
+    # level exactly, and later but for the segments whose range straddles a
+    # tile boundary. The histogram reads the tiles' chunk ranges from the ids
+    # themselves; records, varimp and the metrics never see a row's place.
+    order = None
+    n_rows = bins_u8.shape[0]
+
+    def built_nodes(depth):
+        """Node slots the level's histogram builds: the lighter child of
+        each pair under subtraction, else the frontier."""
+        n_pad = min(1 << depth, node_cap)
+        return n_pad if depth == 0 or not subtract else n_pad // 2
+
+    def hist_of(nid_h, n_nodes):
+        """``(hist, steps)`` of ``histogram_in_jit``, ``steps`` 0 where the
+        level runs dense."""
+        h = histogram_in_jit(
+            bins_h, nid_h, (w, wy, wh), n_nodes, n_bins,
+            col_sharded=split_shard, order=order,
+        )
+        h, steps = (h, jnp.int32(0)) if order is None else h
+        return (efb_expand(h) if efb_expand else h), steps
 
     def level_hist(depth, nid, pair_info, parent_hist):
-        """One level's histogram — direct or sibling-sub. Under
-        ``split_shard`` the column axis comes back sharded (and padded to
-        the shard count); subtraction and the parent carry are columnwise
-        ops, so they stay block-local and never transpose in HBM."""
+        """One level's histogram — direct or sibling-sub — and the grid steps
+        its grouped pass took. Under ``split_shard`` the column axis comes
+        back sharded (and padded to the shard count); subtraction and the
+        parent carry are columnwise ops, so they stay block-local and never
+        transpose in HBM."""
         n_pad = min(1 << depth, node_cap)
         if depth == 0 or not subtract:
-            h = histogram_in_jit(
-                bins_h, nid, (w, wy, wh), n_pad, n_bins,
-                col_sharded=split_shard,
-            )
-            return efb_expand(h) if efb_expand else h
+            return hist_of(nid, n_pad)
         half = n_pad // 2
         row_pair = jnp.maximum(nid, 0) >> 1  # pair = nid//2 (child_base even)
         row_left = (nid & 1) == 0
         bl = pair_info["build_left"]
         build_row = (nid >= 0) & (row_left == bl[row_pair])
         nid_build = jnp.where(build_row, row_pair, -1)
-        built = histogram_in_jit(
-            bins_h, nid_build, (w, wy, wh), half, n_bins,
-            col_sharded=split_shard,
-        )  # (half, C, B, 3) — EFB accumulates bundled, expands to real C
-        if efb_expand:
-            built = efb_expand(built)
+        # (half, C, B, 3) — EFB accumulates bundled, expands to real C
+        built, steps = hist_of(nid_build, half)
         psel = jnp.where(
             pair_info["valid"][:, None, None, None],
             parent_hist[pair_info["parent_idx"]],
@@ -1253,14 +1299,24 @@ def _fused_levels(
         blb = bl[:, None, None, None]
         return jnp.stack(
             [jnp.where(blb, built, sib), jnp.where(blb, sib, built)], axis=1
-        ).reshape(n_pad, *built.shape[1:])
+        ).reshape(n_pad, *built.shape[1:]), steps
 
     depth = 0
     sat_iters = jnp.int32(0)  # executed saturated-region levels (0 if none)
+    hist_steps = jnp.int32(0)  # grid steps of the grouped histogram levels
     while depth <= max_depth:
         n_pad = min(1 << depth, node_cap)
         n_pad_next = min(2 * n_pad, node_cap)
         force_leaf = depth == max_depth
+        builds_hist = not (force_leaf and subtract and pair_info is not None)
+        if order is None and builds_hist:
+            ordered = row_order_in_jit(
+                bins_h, nid, (w, wy, wh), built_nodes(depth), n_bins,
+                carry=(nid, preds) + ((bins_u8,) if efb_expand else ()))
+            if ordered is not None:
+                order, (nid, preds, *codes) = ordered
+                bins_u8 = codes[0] if codes else order_codes_in_jit(
+                    order, bins_u8.shape[1])
 
         if depth == sat_start:
             # ---- saturated run: ONE compiled body, on-device early exit ----
@@ -1288,11 +1344,12 @@ def _fused_levels(
                 return (carry[0] < n_sat) & (carry[4] > 0)
 
             def sat_body(carry):
-                i, nid_c, preds_c, vi_c, _, phist, pinfo, bufs_c = carry[:8]
-                bgt_c = carry[8] if max_leaves else None
+                (i, nid_c, preds_c, vi_c, _, phist, pinfo, bufs_c,
+                 steps_c) = carry[:9]
+                bgt_c = carry[9] if max_leaves else None
                 d = sat_start + i
                 lkey = jax.random.fold_in(tkey, d)
-                hist = level_hist(sat_start, nid_c, pinfo, phist)
+                hist, steps = level_hist(sat_start, nid_c, pinfo, phist)
                 out = _level_core(
                     hist, bins_u8, nid_c, preds_c, vi_c, lkey, cols_enabled,
                     is_cat, min_rows, min_split_improvement, learn_rate,
@@ -1305,7 +1362,8 @@ def _fused_levels(
                 bufs_c = {k: bufs_c[k].at[i].set(rec[k]) for k in bufs_c}
                 # direct mode threads a fixed dummy parent carry instead
                 base = (i + 1, nid_c, preds_c, vi_c, nsp,
-                        hist if subtract else phist, pinfo, bufs_c)
+                        hist if subtract else phist, pinfo, bufs_c,
+                        steps_c + steps)
                 return base + ((out[-1],) if max_leaves else ())
 
             if not subtract:
@@ -1321,13 +1379,13 @@ def _fused_levels(
             # count returned below (_run_counted), so the byte counters
             # report actual volume, not the n_sat upper bound
             carry0 = (jnp.int32(0), nid, preds, varimp, n_split, parent_hist,
-                      pair_info, bufs)
+                      pair_info, bufs, hist_steps)
             if max_leaves:
                 carry0 = carry0 + (leaf_budget,)
             with tally_group("sat"):
                 out = jax.lax.while_loop(sat_cond, sat_body, carry0)
             (sat_iters, nid, preds, varimp, n_split, parent_hist,
-             pair_info, bufs) = out[:8]
+             pair_info, bufs, hist_steps) = out[:9]
             if max_leaves:
                 leaf_budget = out[-1]
             for j in range(n_sat):
@@ -1350,7 +1408,8 @@ def _fused_levels(
             recs.append(rec)
             break
 
-        hist = level_hist(depth, nid, pair_info, parent_hist)
+        hist, steps = level_hist(depth, nid, pair_info, parent_hist)
+        hist_steps = hist_steps + steps
 
         if force_leaf:
             tot = hist[:, 0, :, :].sum(axis=1)
@@ -1372,7 +1431,9 @@ def _fused_levels(
             parent_hist = hist
         recs.append(rec)
         depth += 1
-    return nid, preds, varimp, tuple(recs), sat_iters
+    if order is not None:
+        nid, preds = restore_rows_in_jit(order, (nid, preds), n_rows)
+    return nid, preds, varimp, tuple(recs), jnp.stack([sat_iters, hist_steps])
 
 
 def _subtract_enabled() -> bool:
@@ -1645,7 +1706,7 @@ def _tree_program(
                 is_cat = jnp.pad(is_cat, (0, Cp - C))
                 varimp = jnp.pad(varimp, (0, Cp - C))
                 cols_enabled = jnp.pad(cols_enabled, (0, Cp - C))
-            nid, preds_, varimp_, records, sat_iters = _fused_levels(
+            nid, preds_, varimp_, records, counts = _fused_levels(
                 bins_u8, preds, varimp, w, wy, wh, key_, cols_enabled, is_cat,
                 min_rows, min_split_improvement, learn_rate, max_abs_leaf,
                 col_sample_rate, leaf_reg,
@@ -1654,7 +1715,7 @@ def _tree_program(
                 split_shard=split_shard,
                 max_leaves=max_leaves, efb=efb, bins_b=bins_b,
             )
-            return nid, preds_, varimp_[:C], records, sat_iters
+            return nid, preds_, varimp_[:C], records, counts
 
         return jax.jit(whole_tree, donate_argnums=(1, 2))
 
@@ -1779,7 +1840,7 @@ def build_trees_scanned(
                 if Cp > C:
                     cols_enabled = jnp.pad(cols_enabled, (0, Cp - C))
 
-                _, F, vi, recs, sat_i = _fused_levels(
+                _, F, vi, recs, counts = _fused_levels(
                     bins_u8, F, vi, w_tree, wy, wh, tkey, cols_enabled,
                     is_cat, min_rows_, msi_, lr, max_abs_leaf_, col_rate_,
                     leaf_reg_,
@@ -1788,14 +1849,15 @@ def build_trees_scanned(
                     split_shard=split_shard,
                     max_leaves=max_leaves, efb=efb, bins_b=bins_b,
                 )
-                return (F, vi), (recs, sat_i)
+                return (F, vi), (recs, counts)
 
-            (preds, varimp), (stacked, sat_per_tree) = jax.lax.scan(
+            (preds, varimp), (stacked, counts_per_tree) = jax.lax.scan(
                 body, (preds, varimp), (jnp.arange(n_trees), lrs)
             )
             # total executed saturated-region levels across the chunk's
-            # trees — the dispatch-time weight for the sat byte tallies
-            return preds, varimp[:C], stacked, sat_per_tree.sum()
+            # trees — the dispatch-time weight for the sat byte tallies —
+            # and their grouped histogram steps
+            return preds, varimp[:C], stacked, counts_per_tree.sum(axis=0)
 
         # preds/varimp donated: chunk t+1 reuses chunk t's output buffers in
         # place — the running prediction never copies between dispatches
@@ -1837,7 +1899,7 @@ def build_trees_scanned(
             bins_b,
         ),
         mult=n_trees,
-        sat_from=lambda o: o[3],
+        counts_from=lambda o: o[3],
     )
     _FUSED_SECONDS.inc(_time.perf_counter() - _t0)
     return out[:3]
@@ -2173,7 +2235,7 @@ def build_tree(
                 jnp.float32(learn_rate), jnp.float32(max_abs_leaf),
                 jnp.float32(col_sample_rate), leaf_reg, bins_b,
             ),
-            sat_from=lambda o: o[4],
+            counts_from=lambda o: o[4],
         )
         _FUSED_SECONDS.inc(_time.perf_counter() - _t0)
         for rec in records:

@@ -49,6 +49,7 @@ from h2o3_tpu.parallel.mesh import (
     get_mesh,
     n_col_shards,
     pad_cols_to_shards,
+    row_axes,
     row_pspec,
     shard_map,
 )
@@ -153,6 +154,100 @@ def _select_local():
 
 def _local_is_pallas(local) -> bool:
     return local not in (_hist_scatter_local, _hist_matmul_local)
+
+
+def _wants_row_order(
+    n_rows: int, n_cols: int, n_nodes: int, n_bins: int, n_lanes: int,
+) -> bool:
+    """Whether a level that builds ``n_nodes`` nodes over shards of
+    ``n_rows`` rows runs grouped — read off shapes alone: the local impl is
+    the Pallas kernel and the level is wider than one node tile, where the
+    dense grid passes over every row once a tile (ISSUE 33). One tile, or
+    the CPU's scatter: nothing to group. Nor under the tile autotuner, whose
+    tiles may change with a level's width: one order could not serve them."""
+    from h2o3_tpu import config
+
+    if (not _local_is_pallas(_select_local())
+            or config.get("H2O3_TPU_PALLAS_TILES").strip() == "auto"):
+        return False
+    from h2o3_tpu.ops.hist_pallas import grouped_fits, plan_layout, tiles_for
+
+    tiles = tiles_for(n_cols, n_nodes, n_bins, n_lanes)
+    lay = plan_layout(n_cols, n_nodes, n_bins, n_lanes, tiles=tiles)
+    return lay.n_nt > 1 and grouped_fits(n_rows, lay, tiles[0])
+
+
+def row_order_in_jit(bins_u8, nid, stats, n_nodes: int, n_bins: int,
+                     carry: tuple = (), mesh=None):
+    """The rows of every shard in node order, for ``histogram_in_jit``'s
+    ``order`` at this and every later level of the tree (the codes and the
+    stat lanes must not change meanwhile): a
+    :class:`~h2o3_tpu.ops.hist_pallas.RowOrder` whose arrays are sharded
+    over the rows as the frame's are — the sort is shard-local, no row
+    crosses a device — and the row-sharded per-row arrays of ``carry``
+    ((n,) lanes, (n, C) uint8 codes) in that order (a shard's order is padded
+    to whole row tiles: those places hold zeros). None where a level of
+    ``n_nodes`` nodes would not run grouped (:func:`_wants_row_order`)."""
+    n, C = bins_u8.shape
+    mesh = mesh or get_mesh()
+    if not _wants_row_order(
+            n // int(mesh.devices.size), C, n_nodes, n_bins, len(stats)):
+        return None
+    from h2o3_tpu.ops.hist_pallas import sort_rows, tiles_for
+
+    tiles = tiles_for(C, n_nodes, n_bins, len(stats))
+    rspec = row_pspec(mesh)
+    cspec = tuple(row_pspec(mesh, x.ndim) for x in carry)
+    with jax.named_scope("ph_hist"):
+        return shard_map(
+            lambda b, n, s, c: sort_rows(
+                b, n, s, n_nodes, n_bins, tiles=tiles, carry=c),
+            mesh=mesh,
+            in_specs=(rspec, rspec, rspec, cspec),
+            out_specs=(_order_spec(mesh), cspec),
+            check_vma=False,
+        )(bins_u8, nid, jnp.stack(list(stats), axis=1), tuple(carry))
+
+
+def order_codes_in_jit(order, n_cols: int, mesh=None):
+    """The codes ``order`` was made from, ``(rows, n_cols)`` uint8, in it."""
+    from h2o3_tpu.ops.hist_pallas import order_codes
+
+    mesh = mesh or get_mesh()
+    return shard_map(
+        lambda o: order_codes(o, n_cols),
+        mesh=mesh,
+        in_specs=(_order_spec(mesh),),
+        out_specs=row_pspec(mesh, 2),
+        check_vma=False,
+    )(order)
+
+
+def restore_rows_in_jit(order, lanes_s: tuple, n: int, mesh=None) -> tuple:
+    """Per-row lanes that lie in ``order`` back in the frame's row order,
+    ``(n,)`` each, row-sharded."""
+    from h2o3_tpu.ops.hist_pallas import restore_rows
+
+    mesh = mesh or get_mesh()
+    n_local = n // int(mesh.devices.size)
+    rspec = tuple(row_pspec(mesh) for _ in lanes_s)
+    return shard_map(
+        lambda o, a: restore_rows(o, a, n_local),
+        mesh=mesh,
+        in_specs=(_order_spec(mesh), rspec),
+        out_specs=rspec,
+        check_vma=False,
+    )(order, tuple(lanes_s))
+
+
+def _order_spec(mesh):
+    """A ``RowOrder``'s arrays carry the rows on their LAST axis (``n_live``:
+    one count a shard)."""
+    from h2o3_tpu.ops.hist_pallas import RowOrder
+
+    return RowOrder(
+        perm=row_pspec(mesh), bins3=row_pspec(mesh, 3, 2),
+        stats_t=row_pspec(mesh, 2, 1), n_live=row_pspec(mesh))
 
 
 # ---------------------------------------------------------------------------
@@ -276,12 +371,22 @@ def _hist_matmul_local(bins_u8, nid, stats, n_nodes: int, n_bins: int):
 
 def histogram_in_jit(
     bins_u8, nid, stats, n_nodes: int, n_bins: int, mesh=None,
-    *, col_sharded: bool = False,
+    *, col_sharded: bool = False, order=None,
 ):
     """Cross-device histogram, traceable inside a jitted program.
 
     ``stats`` is a TUPLE of (n,) row-sharded arrays — the stat lanes.
     Returns (n_nodes, C, n_bins, S), replicated across the mesh.
+
+    ``order`` (:func:`row_order_in_jit`, made earlier in the same tree from
+    the same codes and lanes) runs the level grouped: each shard reads its
+    rows in node order and a row chunk is contracted only against the node
+    tiles whose rows it holds. ``nid`` then arrives IN the order
+    (:func:`row_order_in_jit`'s ``carry``) and ``bins_u8`` and ``stats``
+    give shapes only. The cells are those of the dense pass (real-valued
+    statistics: summed in another order). The return is then
+    ``(hist, steps)``, ``steps`` the int32 count of grid steps that
+    contracted a row chunk, over all shards.
 
     ``col_sharded=True`` is the split-pipeline mode: the cross-device
     reduction ends in ``lax.psum_scatter`` over contiguous COLUMN blocks
@@ -307,10 +412,7 @@ def histogram_in_jit(
 
     local_acc = _maybe_i16(local)
 
-    def body(b, n, s):
-        # retired/padding rows (nid < 0) carry zero stats into every impl
-        s = jnp.where((n >= 0)[:, None], s, 0.0)
-        h = local_acc(b, n, s, n_nodes, n_bins)
+    def reduce(h):
         # the cross-device reduction runs through the collective lane
         # (ops/collectives.py): stock psum/psum_scatter when the quant lane
         # is off — bit-for-bit the pre-lane program — or the block-
@@ -331,7 +433,19 @@ def histogram_in_jit(
         return collectives.psum_scatter(
             h, n_dev=n_dev, phase="hist_reduce", lane_axis=-1, mesh=mesh)
 
-    smat = jnp.stack(list(stats), axis=1)  # (n, S)
+    def body(b, n, s):
+        # retired/padding rows (nid < 0) carry zero stats into every impl
+        s = jnp.where((n >= 0)[:, None], s, 0.0)
+        return reduce(local_acc(b, n, s, n_nodes, n_bins))
+
+    def body_grouped(order, n):
+        from h2o3_tpu.ops.hist_pallas import hist_pallas_grouped, tiles_for
+
+        h, steps = hist_pallas_grouped(
+            order, n, n_nodes, n_bins, C,
+            interpret=jax.default_backend() == "cpu",
+            tiles=tiles_for(C, n_nodes, n_bins, S))
+        return reduce(h), jax.lax.psum(steps, row_axes(mesh))
 
     # node tiles asked for (tree_node_tiles_total): 64 node slots over every
     # row each, tallied and replayed per dispatch like the bytes below
@@ -361,17 +475,29 @@ def histogram_in_jit(
     # ph_hist: phase tag consumed by tools/profile_fused.py (HLO op_name
     # metadata carries the scope path into the profiler trace)
     rspec = row_pspec(mesh)
+    hspec = col_block_spec(0, mesh) if col_sharded else P()
     with jax.named_scope("ph_hist"):
-        h = shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(rspec, rspec, rspec),
-            out_specs=col_block_spec(0, mesh) if col_sharded else P(),
-            check_vma=False,
-        )(bins_u8, nid, smat)  # (C[p], n_nodes*n_bins, S)
-        return jnp.transpose(
+        if order is None:
+            h = shard_map(
+                body,
+                mesh=mesh,
+                in_specs=(rspec, rspec, rspec),
+                out_specs=hspec,
+                check_vma=False,
+            )(bins_u8, nid, jnp.stack(list(stats), axis=1))
+        else:
+            record_collective("hist_grouped", 1)
+            h, steps = shard_map(
+                body_grouped,
+                mesh=mesh,
+                in_specs=(_order_spec(mesh), rspec),
+                out_specs=(hspec, P()),
+                check_vma=False,
+            )(order, nid)
+        h = jnp.transpose(
             h.reshape(h.shape[0], n_nodes, n_bins, S), (1, 0, 2, 3)
         )  # (n_nodes, C[p], n_bins, S)
+    return h if order is None else (h, steps)
 
 
 _BUILD_HIST_PROG: dict = {}

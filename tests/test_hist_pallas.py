@@ -6,6 +6,7 @@ codes, ragged row counts, retired rows, and the 2-term bf16 split's
 accuracy bound."""
 
 import contextlib
+import functools
 import os
 
 import numpy as np
@@ -14,6 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from h2o3_tpu.ops import hist_pallas as hp
 from h2o3_tpu.ops.hist_pallas import NODE_TILE, ROW_TILE, hist_pallas_local
 from h2o3_tpu.ops.histogram import _hist_scatter_local
 
@@ -464,3 +466,177 @@ def test_kernel_key_follows_tiles_and_hist_override():
         with _env(H2O3_TPU_HIST="matmul"):
             st._level_step(1, 2, 16, False)
         assert len(st._STEP_CACHE) == n0 + 1
+
+
+# ---------------------------------------------------------------------------
+# The grouped pass (ISSUE 33): rows read in node order, a row chunk contracted
+# only against the node tiles whose rows it holds — against the dense call.
+
+SMALL = (128, 8, 8)  # row, column, node tile: many tiles at test sizes
+REAL = (hp.ROW_TILE, hp.COL_TILE, hp.NODE_TILE)
+GROUPED_SHAPES = [
+    # (n_rows, n_cols, n_nodes, n_bins, stat lanes, tiles)
+    pytest.param(1000, 28, 16, 256, 3, SMALL, id="2-tiles-28-cols"),
+    pytest.param(1000, 13, 40, 64, 4, SMALL, id="5-tiles-13-cols-4-lanes"),
+    pytest.param(2000, 28, 128, 32, 3, SMALL, id="16-tiles"),
+    pytest.param(1300, 28, 128, 256, 3, REAL, id="2-tiles-of-64-4-col-tiles"),
+    pytest.param(1300, 11, 130, 256, 4, REAL, id="ragged-3rd-tile-2-col-tiles"),
+]
+
+
+def _grouped_case(n, c, n_nodes, n_bins, ns, seed, integer):
+    rng = np.random.default_rng(seed)
+    bins = rng.integers(0, n_bins, size=(n, c)).astype(np.uint8)
+    nid = rng.integers(0, n_nodes, size=n).astype(np.int32)
+    nid[rng.random(n) < 0.2] = -1  # retired, or the sibling that is not built
+    stats = (rng.integers(0, 3, (n, ns)) if integer
+             else rng.normal(size=(n, ns))).astype(np.float32)
+    return jnp.asarray(bins), jnp.asarray(nid), jnp.asarray(stats)
+
+
+@functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "tiles"))
+def _grouped(bins, sort_key, nid, stats, n_nodes, n_bins, tiles):
+    """The histogram of ``nid`` over rows sorted by ``sort_key``."""
+    order, (nid_s,) = hp.sort_rows(
+        bins, sort_key, stats, n_nodes, n_bins, tiles=tiles, carry=(nid,))
+    return hp.hist_pallas_grouped(
+        order, nid_s, n_nodes, n_bins, bins.shape[1], interpret=True,
+        tiles=tiles)
+
+
+def _grid(n, c, n_nodes, n_bins, ns, tiles):
+    lay = hp.plan_layout(c, n_nodes, n_bins, ns, tiles=tiles)
+    return lay, -(-n // tiles[0])
+
+
+@pytest.mark.parametrize("integer", [True, False], ids=["0-1-2-sums", "real"])
+@pytest.mark.parametrize("rows", ["node-order", "random-order"])
+@pytest.mark.parametrize("n,c,n_nodes,n_bins,ns,tiles", GROUPED_SHAPES)
+def test_grouped_matches_dense(n, c, n_nodes, n_bins, ns, tiles, rows, integer):
+    """Right whatever the order: with the rows sorted by node, and with the
+    rows sorted by a key that has nothing to do with the nodes, the grouped
+    pass returns the dense call's cells — bit for bit where the statistics
+    are small integers (a forest's 0/1 sums), to 1e-6 of the largest cell
+    where they are real (float32 sums in another order)."""
+    bins, nid, stats = _grouped_case(n, c, n_nodes, n_bins, ns, n + c, integer)
+    key = nid if rows == "node-order" else jnp.asarray(
+        np.random.default_rng(1).integers(0, 1 << 20, n).astype(np.int32))
+    got, steps = _grouped(bins, key, nid, stats, n_nodes, n_bins, tiles)
+    want = hist_pallas_local(
+        bins, nid, stats, n_nodes, n_bins, interpret=True, tiles=tiles)
+    assert got.shape == want.shape == (c, n_nodes * n_bins, ns)
+    if integer:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=0, atol=1e-6 * scale)
+    lay, n_r = _grid(n, c, n_nodes, n_bins, ns, tiles)
+    if rows == "node-order":
+        assert int(steps) <= (n_r + lay.n_nt - 1) * lay.n_ct
+    else:
+        assert int(steps) <= lay.n_nt * n_r * lay.n_ct
+
+
+def test_grouped_tile_without_rows_is_zero():
+    """A node tile that owns no row still gets one visit, which zeroes its
+    block: it comes back all zero, not as whatever VMEM held — a middle tile,
+    the last tile, and every tile at once."""
+    n, c, n_nodes, n_bins = 1500, 5, 40, 16
+    bins, nid, stats = _grouped_case(n, c, n_nodes, n_bins, 3, 9, True)
+    for empty in ([2], [4], [0, 1, 2, 3, 4]):
+        nid_e = jnp.where(jnp.isin(nid // SMALL[2], jnp.asarray(empty)), -1, nid)
+        got, _ = _grouped(bins, nid_e, nid_e, stats, n_nodes, n_bins, SMALL)
+        want = hist_pallas_local(
+            bins, nid_e, stats, n_nodes, n_bins, interpret=True, tiles=SMALL)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        cells = np.asarray(got).reshape(c, n_nodes, n_bins, 3)
+        for t in empty:
+            assert not cells[:, t * SMALL[2]:(t + 1) * SMALL[2]].any()
+
+
+@pytest.mark.parametrize("dead", ["retired", "zero-statistics"])
+@pytest.mark.parametrize("n", [1999, 2048])
+def test_grouped_skips_chunks_of_rows_that_add_nothing(n, dead):
+    """Rows no cell can see sort last — retired at the sort, or with every
+    statistic zero (out of a forest's bag) — and the chunks that hold nothing
+    else are never visited (``n`` that is no multiple of the row tile pads
+    with such rows); the cells are the dense call's."""
+    c, n_nodes, n_bins = 7, 32, 32
+    bins, nid, stats = _grouped_case(n, c, n_nodes, n_bins, 3, n, True)
+    keep = jnp.arange(n) % 4 == 0  # three in four add nothing
+    if dead == "retired":
+        nid = jnp.where(keep, nid, -1)
+        stats = stats + 1.0  # no row without statistics
+    else:
+        stats = jnp.where(keep[:, None], stats + 1.0, 0.0)
+    got, steps = _grouped(bins, nid, nid, stats, n_nodes, n_bins, SMALL)
+    want = hist_pallas_local(
+        bins, nid, stats, n_nodes, n_bins, interpret=True, tiles=SMALL)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    lay, n_r = _grid(n, c, n_nodes, n_bins, 3, SMALL)
+    live_chunks = -(-int((keep & (nid >= 0)).sum()) // SMALL[0])
+    assert live_chunks < n_r // 2
+    assert int(steps) <= (live_chunks + lay.n_nt - 1) * lay.n_ct
+
+
+def test_grouped_takes_the_dense_kernel_past_its_visit_list():
+    """Rows in no order at all at 16 node tiles: every tile's run of chunks is
+    the whole frame, 16 x n_r visits against a list of 2 n_r + 15 — the level
+    takes the dense kernel over the same operands and says so in its steps."""
+    n, c, n_nodes, n_bins = 2048, 5, 128, 16
+    bins, nid, stats = _grouped_case(n, c, n_nodes, n_bins, 3, 3, True)
+    key = jnp.asarray(np.random.default_rng(2).integers(0, 1 << 20, n), jnp.int32)
+    got, steps = _grouped(bins, key, nid, stats, n_nodes, n_bins, SMALL)
+    lay, n_r = _grid(n, c, n_nodes, n_bins, 3, SMALL)
+    assert lay.n_nt * n_r > hp._visit_list_len(n_r, lay.n_nt)
+    assert int(steps) == lay.n_nt * n_r * lay.n_ct
+    want = hist_pallas_local(
+        bins, nid, stats, n_nodes, n_bins, interpret=True, tiles=SMALL)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_grouped_fits_reads_the_visit_list_off_the_shard():
+    """Whether a level can run grouped is a matter of shapes: the visit list
+    (one int32 a visit, in SMEM) holds ``drf_higgs``'s 6,029,312 rows and
+    the published 11M with room, and refuses a shard past 50M rows."""
+    lay = hp.plan_layout(28, 1024, 256, 3, tiles=REAL)
+    assert lay.n_nt == 16
+    assert hp.grouped_fits(6_029_312, lay, hp.ROW_TILE)
+    assert hp.grouped_fits(11_010_048, lay, hp.ROW_TILE)
+    assert not hp.grouped_fits(60_000_000, lay, hp.ROW_TILE)
+
+
+@pytest.mark.parametrize("n,c", [(1000, 28), (1024, 5)])
+def test_rows_go_into_the_order_and_come_back(n, c):
+    """What the tree program permutes once a tree, by sorting: the order is
+    the stable sort by node with the rows that add nothing last; a float
+    lane, an int lane and uint8 codes carried into it are the gathered rows
+    (zeros in the places that pad a shard to whole row tiles), the order's
+    own codes read back from the kernels' layout are the same, and lanes
+    restored are the lanes they were."""
+    bins, nid, stats = _grouped_case(n, c, 16, 256, 3, n, True)
+    lane = jnp.arange(n, dtype=jnp.float32) * 0.5 - 3.0
+    order, (lane_s, nid_s, bins_s) = hp.sort_rows(
+        bins, nid, stats, 16, 256, tiles=SMALL, carry=(lane, nid, bins))
+    perm = np.asarray(order.perm)
+    npad = perm.shape[0]
+    assert npad % SMALL[0] == 0 and sorted(perm) == list(range(npad))
+    live = (np.asarray(nid) >= 0) & (np.asarray(stats) != 0).any(axis=1)
+    assert int(order.n_live[0]) == live.sum()
+    key = np.where(live, np.asarray(nid), 1 << 30)
+    np.testing.assert_array_equal(perm[:n], np.argsort(key, kind="stable"))
+
+    def padded(x):
+        return np.concatenate(
+            [np.asarray(x), np.zeros((npad - n,) + x.shape[1:], x.dtype)])
+
+    for got, want in ((lane_s, lane), (nid_s, nid), (bins_s, bins),
+                      (hp.order_codes(order, c), bins)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), padded(want)[perm])
+    np.testing.assert_array_equal(
+        np.asarray(order.stats_t), padded(stats)[perm].T)
+    back = hp.restore_rows(order, (lane_s, nid_s), n)
+    np.testing.assert_array_equal(np.asarray(back[0]), np.asarray(lane))
+    np.testing.assert_array_equal(np.asarray(back[1]), np.asarray(nid))

@@ -15,6 +15,7 @@ The cache-placement and smoke-rehearsal tests at the end start fresh
 interpreters (jax reads JAX_COMPILATION_CACHE_DIR at import).
 """
 
+import collections
 import functools
 import json
 import os
@@ -135,7 +136,7 @@ def _chunk_program(monkeypatch, max_depth, node_cap, rows=ROWS, sharding=None,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     captured = {}
 
-    def capture(fn, args, mult=1, sat_from=None):
+    def capture(fn, args, mult=1, counts_from=None):
         captured.update(fn=fn, args=args)
         raise LookupError("captured")
 
@@ -169,18 +170,39 @@ def test_tree_chunk_program_lowers_for_tpu(monkeypatch, max_depth, node_cap):
     """The whole ``build_trees_scanned`` chunk program (the dispatch behind
     every ``gbm_higgs`` tree, five trees a chunk) lowers for the TPU with
     the chip's branches taken, and the only Pallas kernel in its text is
-    ``hist_pallas_dense``. Depth 6 is the benchmark's shape; depth 20 with
-    ``node_cap`` 2048 is a DRF's — the saturated ``lax.while_loop`` (ROADMAP
-    B-R3: first trained on a chip in ISSUE 31): one loop for the levels, one
-    for the scan over trees."""
+    ``hist_pallas_dense`` up to depth 7. Depth 6 is the benchmark's shape;
+    depth 20 with ``node_cap`` 2048 is a DRF's — the saturated
+    ``lax.while_loop`` (ROADMAP B-R3: first trained on a chip in ISSUE 31):
+    one loop for the levels, one for the scan over trees."""
     import re
 
     fn, shapes = _chunk_program(monkeypatch, max_depth, node_cap)
     module = _export_tpu(fn, *shapes)
     kernels = set(re.findall(r'kernel_name = "([^"]+)"', module))
-    assert kernels == {"hist_pallas_dense"}, kernels
-    want_loops = 1 if st._sat_region(max_depth, node_cap)[1] == 0 else 2
+    # one loop for the scan over trees, one for the saturated levels; a deep
+    # tree's rows go into node order and back by sorting lane after lane
+    # (``hist_pallas._sorted_by``): two loops more
+    want_loops = 1 if st._sat_region(max_depth, node_cap)[1] == 0 else 4
     assert module.count("stablehlo.while") == want_loops
+    monkeypatch.setattr(hg, "_wants_row_order", lambda *a: False)
+    st._STEP_CACHE.clear()
+    fn, shapes = _chunk_program(monkeypatch, max_depth, node_cap)
+    unordered = _export_tpu(fn, *shapes)
+    if max_depth == 6:
+        # at most 16 built nodes a level, one node tile: the GBM cells'
+        # program is ISSUE 33's bypass — operation for operation what it
+        # lowers to with the row order withheld (the texts differ in their
+        # source locations alone): no sort of the rows, no second kernel
+        assert kernels == {"hist_pallas_dense"}, kernels
+        ops = lambda text: collections.Counter(
+            re.findall(r"stablehlo\.\w+|kernel_name = \S+", text))
+        assert ops(module) == ops(unordered)
+    else:
+        # levels 8-19 build 128-1024 nodes: the rows sorted once a tree, the
+        # grouped kernel, and the dense one behind each level's ``cond``
+        assert kernels == {"hist_pallas_dense", "hist_pallas_grouped"}, kernels
+        assert (module.count("stablehlo.sort")
+                == unordered.count("stablehlo.sort") + 2)
 
 
 @pytest.fixture(scope="module")
@@ -268,6 +290,35 @@ def test_histogram_kernel_compiles_alone_for_a_described_v5e(
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
             for a in _hist_args(lanes)]
     assert "tpu_custom_call" in jax.jit(f).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("rows", [4096, 6_029_312])
+def test_grouped_kernel_compiles_alone_for_a_described_v5e(
+        v5e_chip, no_compile_cache, rows):
+    """ISSUE 33's grouped ``pallas_call`` at a saturated forest level — 1,024
+    built nodes: 16 tiles of 64, 28 columns in four steps of 8, 256 bins —
+    with its scalar-prefetch operands, the visit list among them: at
+    ``drf_higgs``'s 6,029,312 rows that is 23,567 int32 of the chip's 1 MiB
+    of SMEM. The level's ``cond`` carries the dense kernel beside it."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(v5e_chip)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    lay = hp.plan_layout(28, 1024, BINS, LANES, tiles=TILES)
+    assert (lay.n_nt, lay.nt, lay.n_ct, lay.ct) == (16, 64, 4, 8)
+    assert hp.grouped_fits(rows, lay, hp.ROW_TILE)
+    order = hp.RowOrder(
+        perm=S((rows,), jnp.int32), bins3=S((lay.n_ct, lay.ct, rows), jnp.int32),
+        stats_t=S((LANES, rows), jnp.float32), n_live=S((1,), jnp.int32))
+    f = functools.partial(
+        hp.hist_pallas_grouped, n_nodes=1024, n_bins=BINS, n_cols=28,
+        interpret=False, tiles=TILES)
+    text = jax.jit(f).lower(order, S((rows,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert set(re.findall(r"hist_pallas_\w+", text)) >= {
+        "hist_pallas_grouped", "hist_pallas_dense"}
 
 
 def test_tile_sweep_skips_only_candidates_that_do_not_fit(monkeypatch):

@@ -574,13 +574,13 @@ def test_fused_whole_tree_deep_matches_per_level(monkeypatch, k, depth,
                 )
             return preds, vi
         prog = st._tree_program(depth, 32, node_cap, ())
-        _, preds, vi, _, sat_iters = prog(
+        _, preds, vi, _, counts = prog(
             bins, preds, vi, w, w * t, h, key,
             jnp.ones(c, jnp.float32), jnp.zeros(c, bool),
             jnp.float32(10.0), jnp.float32(1e-5), jnp.float32(0.1),
             jnp.float32(np.inf), jnp.float32(1.0), None,
         )
-        assert int(sat_iters) >= 1
+        assert int(counts[0]) >= 1  # executed saturated levels
         return preds, vi
 
     bins_np = rng.integers(1, 32, (n, c)).astype(np.uint8)

@@ -110,3 +110,168 @@ def test_nbins_bucket_collapses_nearby_shapes(monkeypatch):
     stats = st.reset_build_stats()
     assert stats["tree_programs_compiled"] == 0
     assert stats["tree_program_cache_hits"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# The grouped histogram inside the whole-tree program (ISSUE 33): from the
+# first level wider than one node tile the histogram reads its rows in node
+# order. The kernel runs in the interpreter with a node tile of 8, so that a
+# tree of a few thousand rows has levels of 2 to 16 tiles.
+
+_TILES = (128, 8, 8)  # H2O3_TPU_PALLAS_TILES: row, column, node
+_GROUPED_COUNTERS = (
+    "tree_hist_grouped_levels_total", "tree_hist_chunk_visits_total",
+    "tree_node_tiles_total", "tree_sat_levels_total")
+
+
+def _one_tree(monkeypatch, depth, node_cap, *, forest, order=True, devices=1,
+              n=4096, c=5):
+    """One whole-tree program over ``n`` rows through ``_run_counted`` with
+    the Pallas kernel interpreted: a forest's tree (0/1 response as the
+    gradient, a 0.632 bag, ``min_rows`` 1, 3 of 5 columns a node) or a GBM's
+    (real-valued gradients, ``min_rows`` 10). ``order=False`` withholds the
+    row order by its predicate — there is no knob. Returns (nid, preds,
+    varimp, records, counts) as numpy and the counters' movement."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from h2o3_tpu.ops import histogram as hg
+    from h2o3_tpu.parallel import mesh as pm
+    from h2o3_tpu.utils import metrics
+
+    monkeypatch.setenv("H2O3_TPU_HIST", "pallas")
+    monkeypatch.setenv("H2O3_TPU_PALLAS_TILES", ",".join(map(str, _TILES)))
+    if not order:
+        monkeypatch.setattr(hg, "_wants_row_order", lambda *a: False)
+    rng = np.random.default_rng(5)
+    bins = rng.integers(1, 32, (n, c)).astype(np.uint8)
+    if forest:
+        w = (rng.random(n) < 0.632).astype(np.float32)
+        t = (rng.random(n) < 0.5).astype(np.float32)
+        h = w
+    else:
+        w = np.ones(n, np.float32)
+        t = rng.normal(size=n).astype(np.float32)
+        h = rng.random(n).astype(np.float32)
+    old = pm._mesh
+    pm.set_mesh(Mesh(np.array(jax.devices("cpu")[:devices]), (pm.ROWS_AXIS,)))
+    st._STEP_CACHE.clear()
+    st._PROG_COLL.clear()
+    try:
+        prog = st._tree_program(depth, 32, node_cap, ())
+        before = {k: metrics.counter_value(k) for k in _GROUPED_COUNTERS}
+        out = st._run_counted(prog, (
+            pm.shard_rows(jnp.asarray(bins)),
+            pm.shard_rows(jnp.zeros(n, jnp.float32)), jnp.zeros(c, jnp.float32),
+            pm.shard_rows(jnp.asarray(w)), pm.shard_rows(jnp.asarray(w * t)),
+            pm.shard_rows(jnp.asarray(h)), jax.random.PRNGKey(3),
+            jnp.ones(c, jnp.float32), jnp.zeros(c, bool),
+            jnp.float32(1.0 if forest else 10.0), jnp.float32(1e-5),
+            jnp.float32(1.0 if forest else 0.1), jnp.float32(np.inf),
+            jnp.float32(0.6 if forest else 1.0), None,
+        ), counts_from=lambda o: o[4])
+        out = jax.tree_util.tree_map(np.asarray, out)
+        moved = {k: metrics.counter_value(k) - v for k, v in before.items()}
+    finally:
+        pm.set_mesh(old)
+        st._STEP_CACHE.clear()
+        st._PROG_COLL.clear()
+        monkeypatch.undo()
+    return out, moved
+
+
+def _grouped_levels(depth, node_cap, sat_levels, n, devices=1):
+    """From the level structure alone: how many of a tree's histogram levels
+    are wider than one node tile, and the most grid steps a level may take
+    whose rows are in node-tile order / at all before it goes dense."""
+    sat_start, _ = st._sat_region(depth, node_cap)
+    levels = list(range(depth if sat_start is None else sat_start))
+    levels += [sat_start] * sat_levels
+    n_r = -(-(n // devices) // _TILES[0])
+    tiles = [-(-max(min(1 << d, node_cap) // 2, 1) // _TILES[2]) for d in levels]
+    wide = [t for t in tiles if t > 1]
+    return (len(wide), devices * sum(n_r + t - 1 for t in wide),
+            devices * sum(2 * n_r + t - 1 for t in wide))
+
+
+def _same_records(a, b):
+    """Node ids, predictions, varimp and every record array, bit for bit."""
+    import jax
+
+    la, lb = (jax.tree_util.tree_leaves(x[:4]) for x in (a, b))
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_grouped_histogram_builds_the_same_forest_tree(monkeypatch):
+    """A depth-10 forest tree (``node_cap`` 256: levels 8 and 9 are the
+    saturated ``while_loop``) with its histograms grouped from level 5 on,
+    against the same build with the order withheld: every record array, the
+    node ids and the predictions are bit-identical (0/1 sums are exact in
+    any order). The counters say what ran: one grouped level for every level
+    wider than a node tile, the loop's by the levels it executed; far fewer
+    grid steps than the dense pass and never more than the visit list."""
+    depth, cap, n = 10, 256, 4096
+    on, moved = _one_tree(monkeypatch, depth, cap, forest=True)
+    off, moved_off = _one_tree(monkeypatch, depth, cap, forest=True, order=False)
+    _same_records(on, off)
+    sat = int(on[4][0])
+    assert sat >= 1 and moved["tree_sat_levels_total"] == sat
+    levels, _, most = _grouped_levels(depth, cap, sat, n)
+    assert levels == 3 + sat
+    assert moved["tree_hist_grouped_levels_total"] == levels
+    assert moved["tree_hist_chunk_visits_total"] == int(on[4][1]) <= most
+    n_r = n // _TILES[0]
+    dense = n_r * sum(t for t in (2, 4, 8) + (16,) * sat)
+    assert moved["tree_hist_chunk_visits_total"] < dense / 4
+    assert moved_off["tree_hist_grouped_levels_total"] == 0
+    assert moved_off["tree_hist_chunk_visits_total"] == 0
+    assert moved_off["tree_node_tiles_total"] == moved["tree_node_tiles_total"]
+
+
+def test_sorted_level_and_the_next_visit_a_chunk_once(monkeypatch):
+    """The invariant the order rests on, where it holds exactly: children are
+    numbered in their parents' order, so at the level of the sort and at the
+    next one the rows lie in node-tile order and a level takes at most
+    ``n_r + n_nt − 1`` chunk visits. (Two levels on, the grandchildren of one
+    sorted node interleave inside its segment and may straddle a tile: the
+    visit list has room for that, the first test pins its bound.) Depth 7:
+    levels 5 and 6 build 16 and 32 nodes, two and four tiles of 8."""
+    out, moved = _one_tree(monkeypatch, 7, 256, forest=True)
+    levels, in_order, _ = _grouped_levels(7, 256, 0, 4096)
+    assert levels == moved["tree_hist_grouped_levels_total"] == 2
+    assert 0 < moved["tree_hist_chunk_visits_total"] <= in_order
+
+
+def test_grouped_histogram_is_shard_local(monkeypatch):
+    """The same forest tree on the 8-device mesh: every shard sorts its own
+    rows (no row crosses a device, the reduce is untouched) and the tree is
+    the one-device tree, bit for bit."""
+    depth, cap, n = 10, 256, 4096
+    one, _ = _one_tree(monkeypatch, depth, cap, forest=True)
+    eight, moved = _one_tree(monkeypatch, depth, cap, forest=True, devices=8)
+    _same_records(one, eight)
+    levels, _, most = _grouped_levels(depth, cap, int(eight[4][0]), n, devices=8)
+    assert moved["tree_hist_grouped_levels_total"] == levels
+    assert 0 < moved["tree_hist_chunk_visits_total"] <= most
+
+
+def test_grouped_histogram_holds_a_deep_gbm_tree(monkeypatch):
+    """Real-valued statistics: a depth-9 GBM tree with the order on sums a
+    cell's float32 terms in another order than with the order withheld. The
+    tree is held to the parity tolerances of sibling subtraction
+    (``test_hist_subtraction_matches_direct``): the same splits, the
+    predictions within 1e-5."""
+    on, moved = _one_tree(monkeypatch, 9, 2048, forest=False)
+    off, _ = _one_tree(monkeypatch, 9, 2048, forest=False, order=False)
+    assert moved["tree_hist_grouped_levels_total"] == 4  # levels 5..8
+    for rec_on, rec_off in zip(on[3], off[3]):
+        np.testing.assert_array_equal(rec_on["leaf_now"], rec_off["leaf_now"])
+        split = ~rec_on["leaf_now"]
+        np.testing.assert_array_equal(
+            rec_on["split_col"][split], rec_off["split_col"][split])
+        np.testing.assert_array_equal(
+            rec_on["split_bin"][split], rec_off["split_bin"][split])
+    np.testing.assert_allclose(on[1], off[1], rtol=0, atol=1e-5)
