@@ -64,8 +64,19 @@ def multinomial_grad_hess(F, Y1h, w, K: int):
 
 def init_score(dist: str, y: np.ndarray, w: np.ndarray, aux: float = 0.0) -> float:
     """f0 — the init value (h2o's initial prediction per distribution)."""
-    sw = w.sum()
-    mean = float((w * y).sum() / max(sw, _EPS))
+    if dist == "laplace":
+        return float(_weighted_quantile(y, w, 0.5))
+    if dist == "quantile":
+        return float(_weighted_quantile(y, w, aux))
+    return init_score_from_sums(dist, w.sum(), (w * y).sum())
+
+
+def init_score_from_sums(dist: str, sw, swy) -> float:
+    """f0 from Σw and Σw·y, for every distribution whose init value is a
+    function of the weighted mean (all but laplace and quantile, which take
+    an order statistic: :func:`init_score`). Float32 sums give the bits
+    ``init_score`` gives on float32 lanes with the same sums."""
+    mean = float(swy / max(sw, _EPS))
     if dist == "gaussian" or dist == "huber":
         return mean
     if dist == "bernoulli":
@@ -73,10 +84,6 @@ def init_score(dist: str, y: np.ndarray, w: np.ndarray, aux: float = 0.0) -> flo
         return float(np.log(p / (1 - p)))
     if dist in ("poisson", "gamma", "tweedie"):
         return float(np.log(max(mean, _EPS)))
-    if dist == "laplace":
-        return float(_weighted_quantile(y, w, 0.5))
-    if dist == "quantile":
-        return float(_weighted_quantile(y, w, aux))
     raise ValueError(dist)
 
 

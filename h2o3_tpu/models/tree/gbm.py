@@ -11,6 +11,7 @@ steps from the same histogram stats, shrunk by ``learn_rate``.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any
 
 import jax
@@ -32,6 +33,7 @@ from h2o3_tpu.models.tree.binning import MAX_BINS, BinSpec, bin_frame, fit_bins,
 from h2o3_tpu.models.tree.distributions import (
     grad_hess,
     init_score,
+    init_score_from_sums,
     multinomial_grad_hess,
     resolve_distribution,
     response_transform,
@@ -40,6 +42,13 @@ from h2o3_tpu.models.tree.shared_tree import Tree, build_tree
 from h2o3_tpu.utils import faults
 from h2o3_tpu.utils import metrics as _mx
 from h2o3_tpu.utils.log import Log
+
+_RESPONSE_LANES = _mx.counter(
+    "tree_response_lanes_total",
+    "GBM builds by where their response and weight lanes were made: "
+    "path=device (a program over the frame's columns; only the initial "
+    "score's sums come down), path=host (the lanes pulled to the host: the "
+    "laplace/quantile initial score, the streamed build)", always=True)
 
 
 @dataclass
@@ -616,10 +625,43 @@ class GBM(ModelBuilder):
                         "HBM window (H2O3_TPU_HBM_WINDOW_MB) or drop the "
                         "frame below the streaming threshold"
                     )
+                _RESPONSE_LANES.inc(path="host")
                 return self._build_streamed(
                     job, train, valid, p, spec, dist, aux, yv, prior, stream,
                     classification, mono_vec=mono_vec,
                 )
+
+        # response / weights on the device, from the frame's resident columns
+        # (span gbm.response_lanes): one program makes the lanes and the sums
+        # behind the initial score, and only those sums come down — but for
+        # laplace and quantile, whose initial score is an order statistic of
+        # the labels and pulls the lanes. Made before bin_frame, so that the
+        # pull waits for this program alone and a binning pass runs while the
+        # host traces the tree program. xgboost-surface scale_pos_weight
+        # (XGBoostParams only) goes into the TRAINING row weights alone:
+        # xgboost scales grad/hess (≡ row weights in our Newton leaves) but
+        # evaluates metrics unweighted, so the metric weights must not carry it
+        with _mx.span("gbm.response_lanes"):
+            spw = float(getattr(p, "scale_pos_weight", 1.0))
+            if spw != 1.0 and dist != "bernoulli":
+                raise ValueError("scale_pos_weight requires a binary response")
+            y, w_metric, w_train, sums = _response_lanes(
+                yv.data,
+                train.vec(p.weights_column).data if p.weights_column else None,
+                train.nrow, spw=spw, n_classes=K if dist == "multinomial" else 0,
+            )
+            w = w_metric if w_train is None else w_train
+            lanes_path = "device"
+            if prior is not None:
+                f0 = prior.output["init_f"]
+            elif dist in ("laplace", "quantile"):
+                lanes_path = "host"
+                f0 = init_score(dist, np.asarray(y)[: train.nrow],
+                                np.asarray(w_metric)[: train.nrow], aux)
+            else:
+                f0 = _initial_score(dist, np.asarray(sums))
+        _RESPONSE_LANES.inc(path=lanes_path)
+
         bins = bin_frame(spec, train)
         n_bins = spec.max_bins
         npad = train.npad
@@ -642,44 +684,12 @@ class GBM(ModelBuilder):
                 if efb is not None:
                     bins_b = bundle_bins(efb, bins)
 
-        # response / weights on device: the label pulled to the host, the
-        # weight and response lanes built there and uploaded (span
-        # gbm.response_lanes; starts with a pull, ends in enqueued uploads)
-        with _mx.span("gbm.response_lanes"):
-            y_np = yv.to_numpy().astype(np.float64)
-            w_np = np.zeros(npad, np.float32)
-            w_np[: train.nrow] = 1.0
-            if p.weights_column:
-                w_np[: train.nrow] *= np.nan_to_num(
-                    train.vec(p.weights_column).to_numpy()
-                ).astype(np.float32)
-            w_np[: train.nrow] *= ~np.isnan(y_np) if not classification else (y_np >= 0)
-            ybuf = np.zeros(npad, np.float32)
-            ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
-            # xgboost-surface scale_pos_weight (XGBoostParams only): fold the
-            # positive-class up-weighting into the TRAINING row weights only —
-            # xgboost scales grad/hess (≡ row weights in our Newton leaves) but
-            # evaluates metrics unweighted, so the metric weights (wn) must not
-            # carry it
-            spw = float(getattr(p, "scale_pos_weight", 1.0))
-            w_train_np = w_np
-            if spw != 1.0:
-                if dist != "bernoulli":
-                    raise ValueError("scale_pos_weight requires a binary response")
-                w_train_np = w_np.copy()
-                w_train_np[: train.nrow] *= np.where(
-                    ybuf[: train.nrow] == 1.0, spw, 1.0
-                ).astype(np.float32)
-            w = jnp.asarray(w_train_np)
-            y = jnp.asarray(ybuf)
-
         offset = jnp.zeros(npad, jnp.float32)
         if p.offset_column:
             offset = jnp.nan_to_num(train.vec(p.offset_column).data)
 
         rngkey = jax.random.PRNGKey(abs(p.seed) if p.seed and p.seed > 0 else 1234)
 
-        wn, yn = w_np, ybuf  # host copies already exist — never pull from device
         trees: list[list[Tree]] = []
         varimp_dev = jnp.zeros(len(self._x), jnp.float32)
         history: list[dict] = []
@@ -718,10 +728,6 @@ class GBM(ModelBuilder):
                 offset_v = jnp.nan_to_num(valid.vec(p.offset_column).data)
 
         if dist == "multinomial":
-            prior_p = np.array(
-                [max((wn * (yn == k)).sum() / max(wn.sum(), 1e-30), 1e-9) for k in range(K)]
-            )
-            f0 = np.log(prior_p).astype(np.float32)
             F = jnp.tile(jnp.asarray(f0)[None, :], (npad, 1)) + offset[:, None]
             Y1h = (y[:, None] == jnp.arange(K)[None, :]).astype(jnp.float32)
             Fv = (
@@ -730,7 +736,6 @@ class GBM(ModelBuilder):
                 else None
             )
         else:
-            f0 = init_score(dist, yn[: train.nrow], wn[: train.nrow], aux)
             F = jnp.full(npad, f0, jnp.float32) + offset
             Fv = (
                 [jnp.full(bins_v.shape[0], f0, jnp.float32) + offset_v]
@@ -750,9 +755,9 @@ class GBM(ModelBuilder):
 
         start_trees = 0
         if prior is not None:
-            # continue exactly where the prior model stopped: its init score,
-            # its trees replayed into F (identical bin spec), its varimp
-            f0 = prior.output["init_f"]
+            # continue exactly where the prior model stopped: its init score
+            # (f0 above), its trees replayed into F (identical bin spec), its
+            # varimp
             raw = prior._replay_all_dev(train)
             if dist == "multinomial":
                 F = jnp.asarray(np.asarray(f0))[None, :] + offset[:, None] + raw
@@ -828,7 +833,7 @@ class GBM(ModelBuilder):
                     Fv[0] = replay_batch(bins_v, stacked, Fv[0])
                 m_done += chunk
 
-                mval = _train_metric(dist, F, yn, wn, train.nrow, metric_name, K)
+                mval = _train_metric(dist, F, y, w_metric, None, metric_name, K)
                 entry = {"ntrees": m_done, f"training_{metric_name}": mval}
                 stop_val = mval
                 if Fv is not None:
@@ -844,7 +849,7 @@ class GBM(ModelBuilder):
                     lambda key: self._partial_model(
                         key, p, spec, trees, K, dist, f0, varimp_dev,
                         tuple(yv.domain) if classification else None,
-                        F, yn, wn, train.nrow, history,
+                        F, y, w_metric, None, history,
                     ),
                 )
                 faults.die_check(self.algo)  # chaos: worker death at boundary
@@ -943,7 +948,7 @@ class GBM(ModelBuilder):
                     )
 
             if (m + 1) % max(1, p.score_tree_interval) == 0 or m == p.ntrees - 1:
-                mval = _train_metric(dist, F, yn, wn, train.nrow, metric_name, K)
+                mval = _train_metric(dist, F, y, w_metric, None, metric_name, K)
                 entry = {"ntrees": m + 1, f"training_{metric_name}": mval}
                 stop_val = mval
                 if Fv is not None:
@@ -960,7 +965,7 @@ class GBM(ModelBuilder):
                     lambda key: self._partial_model(
                         key, p, spec, trees, K, dist, f0, varimp_dev,
                         tuple(yv.domain) if classification else None,
-                        F, yn, wn, train.nrow, history,
+                        F, y, w_metric, None, history,
                     ),
                 )
                 faults.die_check(self.algo)  # chaos: worker death at boundary
@@ -989,7 +994,7 @@ class GBM(ModelBuilder):
         # the trees, so no model.predict_raw child); ends in the stats' pull
         with _mx.span("model.score_metrics", algo=self.algo):
             model.training_metrics = _metrics_from_F(
-                dist, F, yn, wn, train.nrow, domain=dom
+                dist, F, y, w_metric, None, domain=dom
             )
             if valid is not None:
                 Fv_s = jnp.stack(Fv, axis=1) if dist == "multinomial" else Fv[0]
@@ -1002,26 +1007,81 @@ class GBM(ModelBuilder):
         return model
 
 
+@partial(jax.jit, static_argnames=("spw", "n_classes"))
+@jax.named_scope("ph_std")
+def _response_lanes(ydata, weights, nrow, spw: float = 1.0, n_classes: int = 0):
+    """The resident build's row lanes from the frame's device columns:
+    ``y`` (0 where the label is missing: a categorical code < 0, a NaN),
+    ``w_metric`` (row valid, ``iota < nrow``, times the frame's weights with
+    NaN as 0, times label present), ``w_train`` (``w_metric`` times ``spw``
+    on the positive rows; None where ``spw`` is 1) and the sums behind the
+    initial score: Σw and Σw·y, or with ``n_classes`` Σw·[y == k] for each
+    class and then Σw. The missing-label rules are GLM's
+    (``glm._response_lanes``, which the tests hold these lanes to), written
+    out here: importing the GLM module (scipy.linalg, through ops/gram.py)
+    inside this program's first trace took 1.2 s of a GBM process's first
+    build on a TPU v5e host."""
+    if jnp.issubdtype(ydata.dtype, jnp.floating):
+        yna = jnp.isnan(ydata)
+        y = jnp.nan_to_num(ydata.astype(jnp.float32), nan=0.0)
+    else:
+        yna = ydata < 0
+        y = jnp.where(yna, 0, ydata).astype(jnp.float32)
+    w = (jnp.arange(ydata.shape[0]) < nrow).astype(jnp.float32)
+    if weights is not None:
+        w = w * jnp.nan_to_num(weights)
+    w = w * (1.0 - yna.astype(jnp.float32))
+    sw = w.sum()
+    w_train = None
+    if spw != 1.0:
+        w_train = w * jnp.where(y == 1.0, jnp.float32(spw), jnp.float32(1.0))
+    if n_classes:
+        per_class = (w[:, None] * (y[:, None] == jnp.arange(n_classes))).sum(0)
+        sums = jnp.append(per_class, sw)
+    else:
+        sums = jnp.stack([sw, (w * y).sum()])
+    return y, w, w_train, sums
+
+
+def _initial_score(dist, sums):
+    """f0 from :func:`_response_lanes`' float32 sums, pulled to the host: the
+    multinomial log class priors, else ``init_score_from_sums``."""
+    if dist == "multinomial":
+        *per_class, sw = sums
+        prior_p = np.array([max(s / max(sw, 1e-30), 1e-9) for s in per_class])
+        return np.log(prior_p).astype(np.float32)
+    return init_score_from_sums(dist, *sums)
+
+
 def _metrics_from_F(dist, F, yn, wn, nrow, domain=None) -> MM.ModelMetrics:
     """Full ModelMetrics from the RUNNING scores — the training loop already
-    holds F, so the recorded trees are not replayed to re-derive it. On accelerators the transformed scores stay on
-    device (metrics.py reduces sufficient statistics there)."""
-    conv = (
-        (lambda x: x)
-        if jax.default_backend() != "cpu" or jax.process_count() > 1
-        else np.asarray
-    )
+    holds F, so the recorded trees are not replayed to re-derive it. On
+    accelerators the transformed scores stay on device (metrics.py reduces
+    sufficient statistics there). The resident build hands in its padded
+    device lanes whole (``nrow`` None; padded rows weigh 0); host lanes of
+    ``nrow`` rows (the streamed build, a validation frame) cut the scores to
+    them. On the CPU backend everything is pulled (an allgather across
+    processes: the build runs on every rank) and reduced in numpy."""
+    from h2o3_tpu.parallel.mesh import pull_to_host
+
+    on_host = jax.default_backend() == "cpu"
+
+    def conv(x):
+        x = pull_to_host(x) if on_host else x
+        return x if nrow is None else x[:nrow]
+
     if dist == "multinomial":
-        P = conv(jax.nn.softmax(F, axis=1))[:nrow]
+        yk = conv(yn)
+        if isinstance(yk, np.ndarray):  # the host path indexes by class id
+            yk = yk.astype(np.int64)
         return MM.multinomial_metrics(
-            yn[:nrow].astype(np.int64), P, wn[:nrow], domain=domain or ()
-        )
+            yk, conv(jax.nn.softmax(F, axis=1)), conv(wn), domain=domain or ())
     if dist == "bernoulli":
-        p1 = conv(response_transform("bernoulli", F))[:nrow]
-        return MM.binomial_metrics(yn[:nrow], p1, wn[:nrow], domain=domain or ("0", "1"))
-    mu = conv(response_transform(dist, F))[:nrow]
+        p1 = conv(response_transform("bernoulli", F))
+        return MM.binomial_metrics(conv(yn), p1, conv(wn), domain=domain or ("0", "1"))
+    mu = conv(response_transform(dist, F))
     mdist = dist if dist in ("poisson", "gamma", "laplace") else "gaussian"
-    return MM.regression_metrics(yn[:nrow], mu, wn[:nrow], mdist)
+    return MM.regression_metrics(conv(yn), mu, conv(wn), mdist)
 
 
 def _train_metric(dist, F, yn, wn, nrow, metric_name, K) -> float:

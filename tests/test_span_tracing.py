@@ -370,6 +370,11 @@ def _lowered(which):
     if which == "response_lanes":
         return glm._response_lanes.lower(
             jax.ShapeDtypeStruct(y.shape, jnp.int8), w, None, None)
+    if which == "gbm_response_lanes":
+        from h2o3_tpu.models.tree import gbm
+
+        return gbm._response_lanes.lower(
+            jax.ShapeDtypeStruct(y.shape, jnp.int8), None, 200, spw=3.0, n_classes=0)
     if which == "design":
         from h2o3_tpu.models import datainfo
         from h2o3_tpu.parallel.mesh import mesh_key
@@ -400,6 +405,7 @@ def _lowered(which):
     ("binom_stats", {"ph_metric"}),
     ("linear_mu", {"ph_score"}),
     ("response_lanes", {"ph_std"}),
+    ("gbm_response_lanes", {"ph_std"}),
     ("design", {"ph_std"}),
     ("finish_level", {"ph_leaf", "ph_part", "ph_pred"}),
 ])
