@@ -43,29 +43,6 @@ _G_GENERATION = metrics.gauge(
     "interleave with a wedged predecessor")
 _C_TRANSITIONS = metrics.counter(
     "cloud_health_transitions_total", "health state changes, by target state")
-_C_CACHE_HITS = metrics.counter(
-    "compile_cache_hits_total",
-    "persistent XLA compilation-cache hits (jax monitoring event "
-    "'/jax/compilation_cache/cache_hits') — a warm scoring replica or a "
-    "same-shape-bucket rebuild should count only hits here and compile "
-    "zero new programs")
-
-_CACHE_LISTENER_INSTALLED = False
-
-
-def _on_jax_event(event: str, **kw) -> None:
-    if event == "/jax/compilation_cache/cache_hits":
-        _C_CACHE_HITS.inc()
-
-
-def _install_cache_hit_listener() -> None:
-    """Bridge jax's compilation-cache monitoring events into the registry
-    so operators can watch cross-process cache effectiveness (replica
-    cold-start, AutoML same-bucket rebuilds) from /3/Metrics."""
-    global _CACHE_LISTENER_INSTALLED
-    if not _CACHE_LISTENER_INSTALLED:
-        jax.monitoring.register_event_listener(_on_jax_event)
-        _CACHE_LISTENER_INSTALLED = True
 
 
 def _declared_platform() -> str:
@@ -123,7 +100,6 @@ def init(
     from h2o3_tpu import config
 
     Log.set_level(log_level or config.get("H2O3_TPU_LOG_LEVEL"))
-    _install_cache_hit_listener()
     _enable_compile_cache()
     if coordinator is not None and not jax.distributed.is_initialized():
         # Must run before any backend use (jax.devices() etc.).

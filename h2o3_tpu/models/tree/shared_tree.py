@@ -78,10 +78,6 @@ _BUILD_COUNTERS = {
         "node_cap-saturated tree levels actually executed by the fused "
         "builds' while_loop (post-early-exit)", always=True),
 }
-_FUSED_SECONDS = _metrics.counter(
-    "tree_fused_build_seconds_total",
-    "wall seconds spent inside fused whole-tree/chunk build dispatch calls",
-    always=True)
 
 # Collective observability for the split pipeline (labeled by phase:
 # hist_reduce = the histogram psum / psum_scatter, winner_gather = the
@@ -1873,12 +1869,6 @@ def build_trees_scanned(
     )
     BUILD_STATS["dispatches"] += 1
     BUILD_STATS["trees_built"] += n_trees
-    # host-side dispatch wall time (includes the trace/compile on a cache
-    # miss; the device work itself completes asynchronously) — the
-    # "fused-build seconds" lane of the registry
-    import time as _time
-
-    _t0 = _time.perf_counter()
     # the scan body traces once but runs once per tree: mult=n_trees; the
     # saturated-region tallies instead scale by the chunk's total EXECUTED
     # sat levels, returned as the program's last output
@@ -1901,7 +1891,6 @@ def build_trees_scanned(
         mult=n_trees,
         counts_from=lambda o: o[3],
     )
-    _FUSED_SECONDS.inc(_time.perf_counter() - _t0)
     return out[:3]
 
 
@@ -2223,9 +2212,6 @@ def build_tree(
         )
         BUILD_STATS["dispatches"] += 1
         BUILD_STATS["trees_built"] += 1
-        import time as _time
-
-        _t0 = _time.perf_counter()
         _, preds, varimp, records, _sat = _run_counted(
             prog,
             (
@@ -2237,7 +2223,6 @@ def build_tree(
             ),
             counts_from=lambda o: o[4],
         )
-        _FUSED_SECONDS.inc(_time.perf_counter() - _t0)
         for rec in records:
             tree.levels.append(TreeLevel(**rec))
         return tree, preds, varimp
@@ -2491,12 +2476,9 @@ def build_trees_streamed(
     if monotone is not None and np.any(np.asarray(monotone) != 0):
         mono_dev = jnp.asarray(np.asarray(monotone, np.int32))
     trees: list[Tree] = []
-    import time as _time
-
     for m in range(n_trees):
         g = m + tree_offset
         tkey = jax.random.fold_in(base_key, g)
-        _t0 = _time.perf_counter()
         if col_sample_rate_per_tree < 1.0:
             keep = (
                 jax.random.uniform(jax.random.fold_in(tkey, 1 << 30), (C,))
@@ -2568,6 +2550,5 @@ def build_trees_streamed(
             if force_leaf or int(n_split) == 0:
                 break
         BUILD_STATS["trees_built"] += 1
-        _FUSED_SECONDS.inc(_time.perf_counter() - _t0)
         trees.append(tree)
     return trees, varimp

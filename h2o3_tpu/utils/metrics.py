@@ -414,6 +414,11 @@ _SPAN_VAR: contextvars.ContextVar[int | None] = contextvars.ContextVar(
 _TRACE_KIND_VAR: contextvars.ContextVar[str | None] = contextvars.ContextVar(
     "h2o3_trace_kind", default=None
 )
+# (innermost open span's name, its root span's name): what a reader that
+# knows no span id is told of where it runs (telemetry's compile listener)
+_OPEN_VAR: contextvars.ContextVar[tuple[str, str] | None] = contextvars.ContextVar(
+    "h2o3_open_span", default=None
+)
 
 _IDS = itertools.count(1)
 
@@ -456,9 +461,11 @@ def trace(trace_id: str, kind: str = "job"):
     # contextvars — without this the job's root span would parent under
     # the request's rest.request span, a node in a DIFFERENT trace)
     stoken = _SPAN_VAR.set(None)
+    otoken = _OPEN_VAR.set(None)
     try:
         yield
     finally:
+        _OPEN_VAR.reset(otoken)
         _SPAN_VAR.reset(stoken)
         _TRACE_VAR.reset(token)
         _TRACE_KIND_VAR.reset(ktoken)
@@ -472,6 +479,13 @@ def current_span() -> int | None:
     """Active span id (None outside any span) — the parent the flight
     recorder links its dispatch events under."""
     return _SPAN_VAR.get()
+
+
+def open_span_names() -> tuple[str, str] | None:
+    """``(innermost, root)``: the names of the innermost span open in this
+    context and of the span with no parent it nests under (None outside any
+    span). A span the ``H2O3_TPU_METRICS`` gate leaves unopened has no name."""
+    return _OPEN_VAR.get()
 
 
 def next_span_id() -> int:
@@ -526,21 +540,25 @@ class OpenSpan:
     """The one span enter/exit core, shared by :func:`span` and the flight
     recorder's dispatch spans: constructing it OPENS the span (an id from
     the shared sequence, parent and trace from the contextvars, the active
-    span pushed, a profiler annotation entered that carries the ids and the
-    caller's ``labels`` as its stats, the clocks stamped) and
+    span and its name pushed (:func:`open_span_names`), a profiler
+    annotation entered that carries the ids and the caller's ``labels`` as
+    its stats, the clocks stamped) and
     :meth:`close` ends it and returns its seconds. What the caller then
     records (trace tree and histogram, or ring event and job ledger) is the
     caller's; how a span is timed, nested and put into a profiler capture
     is here and nowhere else. Not gated: :func:`span` checks the gate before
     it opens one, the dispatch spans run in every process."""
 
-    __slots__ = ("id", "parent", "trace", "ts", "_t0", "_tok", "_ann")
+    __slots__ = ("id", "parent", "trace", "ts", "_t0", "_tok", "_otok", "_ann")
 
     def __init__(self, name: str, labels: dict | None = None):
         self.id = next(_IDS)
         self.parent = _SPAN_VAR.get()
         self.trace = _TRACE_VAR.get()
         self._tok = _SPAN_VAR.set(self.id)
+        outer = _OPEN_VAR.get()
+        self._otok = _OPEN_VAR.set(
+            (name, name if self.parent is None or outer is None else outer[1]))
         stats = {"span_id": self.id, "parent": self.parent or 0,
                  "trace": self.trace or ""}
         # the span's labels ride behind its own stats, so a capture says
@@ -554,6 +572,7 @@ class OpenSpan:
     def close(self) -> float:
         dur = time.perf_counter() - self._t0
         self._ann.__exit__(None, None, None)
+        _OPEN_VAR.reset(self._otok)
         _SPAN_VAR.reset(self._tok)
         return dur
 

@@ -4,7 +4,10 @@ and the ``/3/Profiler`` stack sampler [UNVERIFIED upstream paths, SURVEY.md
 
 On TPU, XLA compile time IS the dominant hidden cost (AutoML builds many
 small programs), so the timeline's first-class events are compilations:
-``install()`` hooks jax's compile monitoring events into a ring buffer.
+``install()`` hooks jax's compile pipeline (trace, lower, compile or cache
+load) into the registry, by stage and by the root program span open on the
+calling thread, and into a ring buffer, by the innermost span (where a
+first ``train()`` or a first scoring request spends its set-up).
 ``profiler`` wraps ``jax.profiler.trace`` (xplane dumps viewable in
 TensorBoard/XProf) — the JProfile/stack-sampling analog for a compiled
 runtime — and ``summarize`` reduces such a capture to three tables: device
@@ -24,6 +27,8 @@ import re
 import threading
 import time
 
+from h2o3_tpu.utils import metrics as _metrics
+
 _EVENTS: collections.deque = collections.deque(maxlen=4096)
 _LOCK = threading.Lock()
 _INSTALLED = False
@@ -39,28 +44,100 @@ def events(n: int = 200) -> list[dict]:
         return list(_EVENTS)[-n:]
 
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the three stages of jax's compile pipeline, each one ``log_elapsed_time``
+# event (jax/_src/dispatch.py); "compile" times an XLA compile and a load
+# from the persistent cache alike
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+
+_C_SECONDS = _metrics.counter(
+    "compile_seconds_total",
+    "seconds of jax's compile pipeline, by stage (trace | lower | compile, "
+    "a compile being an XLA compile or a persistent-cache load) and by the "
+    "root program span open on the calling thread (root=- outside any); "
+    "a stage nested in another is counted as that one's")
+_C_EVENTS = _metrics.counter(
+    "compile_events_total",
+    "programs through each stage of jax's compile pipeline, by stage and "
+    "root span (stage=lower counts the programs lowered)")
+_C_CACHE_HITS = _metrics.counter(
+    "compile_cache_hits_total",
+    "persistent XLA compilation-cache hits (jax monitoring event "
+    "'/jax/compilation_cache/cache_hits') — a warm scoring replica or a "
+    "same-shape-bucket rebuild should count only hits here and compile "
+    "zero new programs")
+
+_TLS = threading.local()
 
 
-def _on_duration(event: str, duration: float, **kw) -> None:
-    if event == _COMPILE_EVENT:
-        record("compile", f"XLA compilation of {kw.get('fun_name', '?')} "
-                          f"in {duration:.3f} s")
+def _on_enter(event: str, value: float, **kw) -> None:
+    """jax marks the start of each stage with a scalar event: this thread's
+    open stages, innermost last, each as the count of stages nested in it."""
+    if event in _STAGES:
+        _TLS.__dict__.setdefault("open", []).append(0)
+
+
+def _on_stage(event: str, start: float, end: float, **kw) -> None:
+    """A stage has ended. Only an outermost one counts its seconds: what jax
+    does inside it is its own (jnp's jitted helpers trace inside the outer
+    function's trace; threefry's lowering rule traces its bit operations,
+    over a thousand times for a tree program; an eager call while a function
+    is traced lowers and compiles inside that trace), so a thread's seconds
+    are the union of its stages, each counted once, and the ring holds one
+    event for each outermost stage with the count of those nested in it."""
+    stage = _STAGES.get(event)
+    if stage is None:
+        return
+    opened = _TLS.__dict__.get("open")
+    nested = opened.pop() if opened else 0
+    span, root = _metrics.open_span_names() or ("-", "-")
+    cache = _TLS.__dict__.pop("cache", "-") if stage == "compile" else "-"
+    _C_EVENTS.inc(stage=stage, root=root)
+    if opened:  # inside another stage: that one's seconds
+        opened[-1] += nested + 1
+        return
+    secs, fun = end - start, kw.get("fun_name", "?")
+    _C_SECONDS.inc(secs, stage=stage, root=root)
+    with _LOCK:
+        _EVENTS.append({
+            "ts": end, "kind": "compile", "stage": stage, "span": span,
+            "root": root, "fun": fun, "seconds": secs, "nested": nested,
+            "cache": cache, "msg": f"{stage} {fun} in {secs:.3f} s under {span}"})
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT:
+        _C_CACHE_HITS.inc()
+        _TLS.cache = "hit"
+    elif event == _CACHE_MISS:
+        _TLS.cache = "miss"
 
 
 def install() -> None:
-    """Capture XLA compile events into the timeline (idempotent) — one per
-    program compiled OR loaded from the persistent cache (jax times both
-    under the one event; a load is just much quicker), through jax's
-    monitoring hooks. No logger is touched: scraping jax's compile log
-    meant enabling it, one stderr line of argument shapes per traced
-    function."""
+    """The process's one set of ``jax.monitoring`` listeners (idempotent):
+    each stage of the compile pipeline into ``compile_seconds_total`` and
+    ``compile_events_total`` by ``stage`` and ``root`` span, and into the
+    ring as a ``compile`` event with the stage, the innermost open span,
+    jax's ``fun_name``, the seconds and, for a compile, whether the
+    persistent cache had it (``cache=hit|miss|-``); a stage nested in
+    another is that one's (``nested`` counts them: :func:`_on_stage`). Cache
+    hits go into ``compile_cache_hits_total``. The listeners run only when
+    jax traces, lowers or compiles: a warm call hears nothing. No logger is
+    touched: scraping jax's compile log meant enabling it, one stderr line
+    of argument shapes per traced function."""
     global _INSTALLED
     if _INSTALLED:
         return
     import jax
 
-    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_scalar_listener(_on_enter)
+    jax.monitoring.register_event_time_span_listener(_on_stage)
+    jax.monitoring.register_event_listener(_on_event)
     _INSTALLED = True
     record("telemetry", "compile-event capture installed")
 
@@ -429,27 +506,19 @@ def timeline(n: int = 200) -> dict:
     # appends (RuntimeError: deque mutated during iteration)
     with _LOCK:
         snap = list(_EVENTS)
-    compile_count = sum(1 for e in snap if e["kind"] == "compile")
-    evs = snap[-n:]
-    span_count = 0
-    try:
-        from h2o3_tpu.utils import metrics
-
-        spans = metrics.recent_spans(n)
-        span_count = len(spans)
-        evs = evs + [
-            {"ts": s["ts"], "kind": "span",
-             "msg": s["name"], "dur_ms": round(s["dur_s"] * 1e3, 3),
-             **({"job": s["trace"]} if s["trace"] else {})}
-            for s in spans
-        ]
-        evs = sorted(evs, key=lambda e: e["ts"])[-n:]
-    except Exception:  # metrics layer disabled/broken must not sink /3/Timeline
-        pass
+    compile_count = sum(1 for e in snap if e["kind"] == "compile"
+                        and e.get("stage", "compile") == "compile")
+    spans = _metrics.recent_spans(n)
+    evs = snap[-n:] + [
+        {"ts": s["ts"], "kind": "span",
+         "msg": s["name"], "dur_ms": round(s["dur_s"] * 1e3, 3),
+         **({"job": s["trace"]} if s["trace"] else {})}
+        for s in spans
+    ]
     return {
-        "events": evs,
+        "events": sorted(evs, key=lambda e: e["ts"])[-n:],
         "compile_count": compile_count,
-        "span_count": span_count,
+        "span_count": len(spans),
     }
 
 
