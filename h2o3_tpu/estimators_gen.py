@@ -863,8 +863,12 @@ class H2ODeepLearningEstimator(_EstimatorBase):
     rho: float (default 0.99)
     epsilon: float (default 1e-08)
     rate: float (default 0.005)
+    rate_annealing: float (default 1e-06)
     rate_decay: float (default 1.0)
     momentum_start: float (default 0.0)
+    momentum_ramp: float (default 1000000.0)
+    momentum_stable: float (default 0.0)
+    nesterov_accelerated_gradient: bool (default True)
     mini_batch_size: int (default 32)
     standardize: bool (default True)
     loss: str (default 'Automatic')
@@ -903,8 +907,12 @@ class H2ODeepLearningEstimator(_EstimatorBase):
         rho=0.99,
         epsilon=1e-08,
         rate=0.005,
+        rate_annealing=1e-06,
         rate_decay=1.0,
         momentum_start=0.0,
+        momentum_ramp=1000000.0,
+        momentum_stable=0.0,
+        nesterov_accelerated_gradient=True,
         mini_batch_size=32,
         standardize=True,
         loss='Automatic',
@@ -938,8 +946,12 @@ class H2ODeepLearningEstimator(_EstimatorBase):
             rho=rho,
             epsilon=epsilon,
             rate=rate,
+            rate_annealing=rate_annealing,
             rate_decay=rate_decay,
             momentum_start=momentum_start,
+            momentum_ramp=momentum_ramp,
+            momentum_stable=momentum_stable,
+            nesterov_accelerated_gradient=nesterov_accelerated_gradient,
             mini_batch_size=mini_batch_size,
             standardize=standardize,
             loss=loss,
@@ -973,8 +985,12 @@ class H2ODeepLearningEstimator(_EstimatorBase):
             'rho': 0.99,
             'epsilon': 1e-08,
             'rate': 0.005,
+            'rate_annealing': 1e-06,
             'rate_decay': 1.0,
             'momentum_start': 0.0,
+            'momentum_ramp': 1000000.0,
+            'momentum_stable': 0.0,
+            'nesterov_accelerated_gradient': True,
             'mini_batch_size': 32,
             'standardize': True,
             'loss': 'Automatic',

@@ -7,18 +7,22 @@ within a node plus periodic cross-node model averaging. The north star
 (BASELINE.json) explicitly licenses replacing that with synchronous
 data-parallel SGD: here each epoch is ONE compiled ``lax.scan`` over
 minibatches of the row-sharded design matrix — flax MLP forward/backward on
-the MXU, ADADELTA (h2o's adaptive_rate default) or momentum SGD from optax.
-Parameter parity: hidden/activation (+dropout variants), input_dropout,
-l1/l2, adaptive-rate rho/epsilon, rate/rate_decay, standardize, early
-stopping. Deviation noted: ``mini_batch_size`` defaults to 32 (h2o's
-online default of 1 serializes the MXU for no accuracy gain).
+the MXU in float32 (HIGHEST), ADADELTA (h2o's adaptive_rate default) from
+optax or H2O's manual-rate SGD (``_h2o_sgd``: rate annealing, per-layer rate
+decay, a momentum ramp, Nesterov). Parameter parity: hidden/activation
+(+dropout variants), input_dropout, l1/l2, adaptive-rate rho/epsilon,
+rate/rate_annealing/rate_decay, momentum_start/ramp/stable,
+nesterov_accelerated_gradient, standardize, early stopping. Scoring is a
+blocked forward in one program (``_pred_program``). Deviation noted:
+``mini_batch_size`` defaults to 32 (h2o's online default of 1 serializes the
+MXU for no accuracy gain).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +45,10 @@ from h2o3_tpu.utils import metrics as _mx
 from h2o3_tpu.utils.log import Log
 
 _DL_EPOCHS = _mx.counter("dl_epochs_total", "DeepLearning epochs executed")
+_DL_STEPS = _mx.counter(
+    "dl_minibatch_steps_total",
+    "DeepLearning minibatch updates executed (counted at dispatch: "
+    "minibatches an epoch x the dispatch's epochs)")
 _DL_EPOCH_SECONDS = _mx.histogram(
     "dl_epoch_seconds", "per-epoch wall time of the sync-SGD driver")
 # host dispatches issued by the epoch driver (the epoch-chunk acceptance
@@ -80,6 +88,11 @@ _DL_SHARD_FALLBACKS = _mx.counter(
 # epoch-chunk program cache: (shape bucket, net/optimizer descriptor,
 # lanes, mesh, backend) -> compiled chunk
 _DL_PROGRAMS: dict = {}
+# minibatch steps each probe of DeepLearningModel.sgd_probe replays: the
+# training's first steps and the last ones of its last epoch
+PROBE_STEPS = 256
+# rows a block of the device predictor's forward (_pred_program)
+PRED_BLOCK = 1 << 16
 
 
 def _dl_epoch_chunk(p) -> int:
@@ -194,8 +207,12 @@ class DeepLearningParams(CommonParams):
     rho: float = 0.99
     epsilon: float = 1e-8
     rate: float = 0.005
+    rate_annealing: float = 1e-6
     rate_decay: float = 1.0
     momentum_start: float = 0.0
+    momentum_ramp: float = 1e6
+    momentum_stable: float = 0.0
+    nesterov_accelerated_gradient: bool = True
     mini_batch_size: int = 32
     standardize: bool = True
     loss: str = "Automatic"
@@ -223,12 +240,16 @@ class _MLP(nn.Module):
         }[self.activation.lower()]
         if self.input_dropout > 0:
             x = nn.Dropout(self.input_dropout, deterministic=not train)(x)
+        # float32 matmuls, as H2O computes: HIGHEST holds the forward and,
+        # through the transpose rules, the backward products to it (the
+        # TPU's default is one bfloat16 pass)
+        hi = jax.lax.Precision.HIGHEST
         for i, h in enumerate(self.hidden):
-            x = nn.Dense(h)(x)
+            x = nn.Dense(h, precision=hi)(x)
             x = act(x)
             if self.dropout[i] > 0:
                 x = nn.Dropout(self.dropout[i], deterministic=not train)(x)
-        return nn.Dense(self.n_out)(x)
+        return nn.Dense(self.n_out, precision=hi)(x)
 
 
 
@@ -268,7 +289,7 @@ def _dl_chunk_program(desc, mlp, tx, kind: str, batch: int, npad: int,
     from jax.sharding import PartitionSpec as Spec
 
     key = ("dl_chunk", desc, batch, npad, n_chunk, bool(shard_on),
-           int(ctl_shards), mesh_key(), jax.default_backend())
+           int(ctl_shards), PROBE_STEPS, mesh_key(), jax.default_backend())
     fn = _DL_PROGRAMS.get(key)
     if fn is not None:
         _DL_HITS.inc()
@@ -377,41 +398,63 @@ def _dl_chunk_program(desc, mlp, tx, kind: str, batch: int, npad: int,
             )(prm_flat, ost, xb, yb, wb, bk, l1, l2)
 
     def chunk(params, opt_state, X, y, w, perms, key, nbatch, l1, l2,
-              slot_mask):
+              slot_mask, first):
         D = X.shape[1]
 
+        def pick(cond, new, old):
+            return jax.tree.map(lambda a, b: jnp.where(cond, a, b), new, old)
+
         def epoch_body(e, c):
-            prm, ost, key, losses = c
+            prm, ost, key, losses, early, late = c
             perm = perms[e]
-            Xp, yp, wp = X[perm], y[perm], w[perm] * slot_mask
+            with jax.named_scope("ph_dl_perm"):
+                Xp, yp, wp = X[perm], y[perm], w[perm] * slot_mask
             key, dkey = jax.random.split(key)
 
             def step(i, sc):
                 prm, ost, k, loss_sum = sc
-                k, bk = jax.random.split(k)
-                start = i * batch
-                xb = jax.lax.dynamic_slice(Xp, (start, 0), (batch, D))
-                yb = jax.lax.dynamic_slice(yp, (start,), (batch,))
-                wb = jax.lax.dynamic_slice(wp, (start,), (batch,))
-                if shard_on:
-                    loss, prm, ost = shard_step(
-                        prm, ost, xb, yb, wb, bk, l1, l2)
-                else:
-                    loss, g = jax.value_and_grad(loss_fn)(
-                        prm, xb, yb, wb, bk, l1, l2)
-                    upd, ost = tx.update(g, ost, prm)
-                    prm = optax.apply_updates(prm, upd)
+                with jax.named_scope("ph_dl_step"):
+                    k, bk = jax.random.split(k)
+                    start = i * batch
+                    xb = jax.lax.dynamic_slice(Xp, (start, 0), (batch, D))
+                    yb = jax.lax.dynamic_slice(yp, (start,), (batch,))
+                    wb = jax.lax.dynamic_slice(wp, (start,), (batch,))
+                    if shard_on:
+                        loss, prm, ost = shard_step(
+                            prm, ost, xb, yb, wb, bk, l1, l2)
+                    else:
+                        loss, g = jax.value_and_grad(loss_fn)(
+                            prm, xb, yb, wb, bk, l1, l2)
+                        upd, ost = tx.update(g, ost, prm)
+                        prm = optax.apply_updates(prm, upd)
                 return (prm, ost, k, loss_sum + loss)
 
-            prm, ost, _, loss_sum = jax.lax.fori_loop(
-                0, nbatch, step, (prm, ost, dkey, jnp.float32(0.0)))
-            losses = losses.at[e].set(loss_sum / nbatch)
-            return (prm, ost, key, losses)
+            # the probes' records (DeepLearningModel.sgd_probe): the loop is
+            # cut, with no work added to a step, after the training's first
+            # PROBE_STEPS steps and before the chunk's last epoch's last ones
+            head = first & (e == 0)
+            a = jnp.where(head, jnp.minimum(PROBE_STEPS, nbatch), 0)
+            cuts = jnp.stack([0, a, jnp.maximum(nbatch - PROBE_STEPS, a), nbatch])
 
-        params, opt_state, key, losses = jax.lax.fori_loop(
+            def segment(j, sg):
+                sc, early, late = sg
+                sc = jax.lax.fori_loop(cuts[j], cuts[j + 1], step, sc)
+                early = pick(head & (j == 0), sc[0], early)
+                late = pick((e == n_chunk - 1) & (j == 1), sc[:2], late)
+                return sc, early, late
+
+            sc, early, late = jax.lax.fori_loop(
+                0, 3, segment,
+                ((prm, ost, dkey, jnp.float32(0.0)), early, late))
+            prm, ost, _, loss_sum = sc
+            losses = losses.at[e].set(loss_sum / nbatch)
+            return (prm, ost, key, losses, early, late)
+
+        params, opt_state, key, losses, early, late = jax.lax.fori_loop(
             0, n_chunk, epoch_body,
-            (params, opt_state, key, jnp.zeros(n_chunk, jnp.float32)))
-        return params, opt_state, key, losses
+            (params, opt_state, key, jnp.zeros(n_chunk, jnp.float32),
+             params, (params, opt_state)))
+        return params, opt_state, key, losses, early, late
 
     fn = jax.jit(chunk, donate_argnums=(0, 1))
     _DL_PROGRAMS[key] = fn
@@ -429,7 +472,8 @@ def _run_sync_sgd(job, p, mlp, kind, tx, params, opt_state, X, y, w,
     opt_state, epochs_done, history)`` fires at every chunk boundary — with
     checkpoints/faults/early-stopping active the chunk clamps to one epoch,
     so that IS every epoch boundary. Returns (params, opt_state, history,
-    epochs_done)."""
+    epochs_done, probe): ``probe`` is what ``DeepLearningModel.sgd_probe``
+    reads (None where the training streamed)."""
     import time as _time
 
     from h2o3_tpu.frame.chunkstore import ChunkStore
@@ -445,7 +489,7 @@ def _run_sync_sgd(job, p, mlp, kind, tx, params, opt_state, X, y, w,
     nbatch = max(1, nrow // batch)
     # padded permutation slots alias row 0 — a SLOT mask zeroes their weight
     # so a final partial batch cannot over-count real rows (nrow < batch)
-    slot_mask = jnp.asarray((np.arange(npad) < nrow).astype(np.float32))
+    slot_mask = (jnp.arange(npad) < nrow).astype(jnp.float32)
     l1, l2 = jnp.float32(p.l1), jnp.float32(p.l2)
 
     chunk_k = _dl_epoch_chunk(p)
@@ -462,12 +506,12 @@ def _run_sync_sgd(job, p, mlp, kind, tx, params, opt_state, X, y, w,
     # head), and every optimizer hyper is baked into tx's update closure
     desc = (tuple(int(h) for h in mlp.hidden), mlp.activation.lower(),
             tuple(mlp.dropout), float(mlp.input_dropout), int(mlp.n_out),
-            kind, X.shape[1],
-            bool(p.adaptive_rate), float(p.rho), float(p.epsilon),
-            float(p.rate), float(p.rate_decay), float(p.momentum_start or 0))
+            kind, X.shape[1], *_sgd_desc(p))
 
     unravel = None
     n_real = fpad = 0
+    # the probes' starting point: the weights before the first step
+    initial = jax.tree.map(jnp.copy, params) if start_epochs == 0 else None
     if shard_on:
         from jax.flatten_util import ravel_pytree
 
@@ -511,6 +555,7 @@ def _run_sync_sgd(job, p, mlp, kind, tx, params, opt_state, X, y, w,
 
     e = start_epochs
     stopped = False
+    early = late = None
     while e < n_epochs and not stopped:
         k_i = min(chunk_k, n_epochs - e)
         _ep_t0 = _time.perf_counter()
@@ -521,15 +566,26 @@ def _run_sync_sgd(job, p, mlp, kind, tx, params, opt_state, X, y, w,
             desc, mlp, tx, kind, batch, npad, k_i, shard_on,
             unravel=unravel, n_real=n_real, fpad=fpad, ctl_shards=ctl,
         )
+        perms_dev = jnp.asarray(perms)
+        first = e == 0
         _DL_DISPATCHES.inc()
+        _DL_STEPS.inc(nbatch * k_i)
         from h2o3_tpu.utils import flightrec as _fr
 
         with _fr.dispatch("dl_chunk", epochs=int(k_i), rows=int(npad)):
-            params, opt_state, key, losses = prog(
-                params, opt_state, X, y, w, jnp.asarray(perms), key,
-                jnp.int32(nbatch), l1, l2, slot_mask,
+            params, opt_state, key, losses, snap, last = prog(
+                params, opt_state, X, y, w, perms_dev, key,
+                jnp.int32(nbatch), l1, l2, slot_mask, jnp.bool_(first),
             )
             losses = np.asarray(losses, np.float64)  # syncs the chunk's work
+        if first:
+            a = min(PROBE_STEPS, nbatch)
+            early = {"steps": a, "rows": perms[0, :a * batch].copy(),
+                     "after": snap}
+        b = max(nbatch - PROBE_STEPS, a if first and k_i == 1 else 0)
+        late = {"steps": nbatch - b,
+                "rows": perms[-1, b * batch:nbatch * batch].copy(),
+                "before": last[0], "state": last[1]}
         _dt = _time.perf_counter() - _ep_t0
         for j in range(k_i):
             epochs_done = e + j + 1
@@ -557,7 +613,16 @@ def _run_sync_sgd(job, p, mlp, kind, tx, params, opt_state, X, y, w,
     if shard_on:
         params = unravel(params[:n_real])
         opt_state = _state_from_flat(opt_state, unravel, n_real)
-    return params, opt_state, history, epochs_done
+        if late is not None:
+            late["before"] = unravel(late["before"][:n_real])
+            late["state"] = _state_from_flat(late["state"], unravel, n_real)
+        if early is not None:
+            early["after"] = unravel(early["after"][:n_real])
+    probe = None
+    if initial is not None and early is not None:
+        probe = {"batch": batch, "initial": initial, "early": early,
+                 "late": late}
+    return params, opt_state, history, epochs_done, probe
 
 
 def _run_sync_sgd_streamed(job, p, mlp, kind, tx, params, opt_state, store,
@@ -591,9 +656,7 @@ def _run_sync_sgd_streamed(job, p, mlp, kind, tx, params, opt_state, store,
     D = store.lane("X").shape[1]
     desc = (tuple(int(h) for h in mlp.hidden), mlp.activation.lower(),
             tuple(mlp.dropout), float(mlp.input_dropout), int(mlp.n_out),
-            kind, D,
-            bool(p.adaptive_rate), float(p.rho), float(p.epsilon),
-            float(p.rate), float(p.rate_decay), float(p.momentum_start or 0))
+            kind, D, *_sgd_desc(p))
 
     unravel = None
     n_real = fpad = 0
@@ -653,14 +716,15 @@ def _run_sync_sgd_streamed(job, p, mlp, kind, tx, params, opt_state, store,
                 unravel=unravel, n_real=n_real, fpad=fpad, ctl_shards=ctl,
             )
             _DL_DISPATCHES.inc()
+            _DL_STEPS.inc(nbatch)
             from h2o3_tpu.utils import flightrec as _fr
 
             with _fr.dispatch("dl_chunk", block=int(bi),
                               rows=int(blk_rows)):
-                params, opt_state, _k, losses = prog(
+                params, opt_state, _k, losses, _, _ = prog(
                     params, opt_state, blk["X"], blk["y"], blk["w"],
                     jnp.asarray(perm), jax.random.fold_in(ekey, bi),
-                    jnp.int32(nbatch), l1, l2, slot,
+                    jnp.int32(nbatch), l1, l2, slot, jnp.bool_(False),
                 )
                 loss_sum += float(np.asarray(losses)[0]) * nbatch
             nb_sum += nbatch
@@ -690,16 +754,107 @@ def _run_sync_sgd_streamed(job, p, mlp, kind, tx, params, opt_state, store,
     if shard_on:
         params = unravel(params[:n_real])
         opt_state = _state_from_flat(opt_state, unravel, n_real)
-    return params, opt_state, history, epochs_done
+    return params, opt_state, history, epochs_done, None
 
 
-def _make_optimizer(p):
+class H2OSGDState(NamedTuple):
+    """Manual-rate SGD's state: the minibatch steps taken (int32; the
+    samples seen are ``count * batch``, so a checkpoint continues the
+    schedule where it stopped) and the momentum buffer, parameter-shaped.
+    The scalar ``count`` also keeps the state off the sharded-gradient lane
+    (``_flat_state_ok``: ``dl_shard_fallbacks_total{reason=opt_state}``)."""
+
+    count: jax.Array
+    momentum: object
+
+
+def _sgd_desc(p) -> tuple:
+    """The optimizer's hyperparameters, for the chunk programs' cache key
+    (``tx``'s update closure bakes every one in)."""
+    return (bool(p.adaptive_rate), float(p.rho), float(p.epsilon),
+            float(p.rate), float(p.rate_annealing), float(p.rate_decay),
+            float(p.momentum_start or 0), float(p.momentum_ramp),
+            float(p.momentum_stable or 0),
+            bool(p.nesterov_accelerated_gradient))
+
+
+def _sgd_schedule(p, batch: int, count):
+    """(rate, momentum) at the step taken after ``count`` steps: H2O's
+    ``rate / (1 + rate_annealing * n)`` and its momentum, ``momentum_start``
+    rising (or falling) linearly to ``momentum_stable`` over
+    ``momentum_ramp`` samples and held there, for ``n = count * batch``
+    samples seen."""
+    n = count.astype(jnp.float32) * jnp.float32(batch)
+    rate = p.rate / (1.0 + p.rate_annealing * n)
+    start, stable = float(p.momentum_start or 0.0), float(p.momentum_stable or 0.0)
+    mom = jnp.float32(start)
+    if p.momentum_ramp > 0:
+        mom = jnp.where(n >= p.momentum_ramp, jnp.float32(stable),
+                        start + (stable - start) * (n / p.momentum_ramp))
+    return rate, mom
+
+
+def _dense_index(path) -> int:
+    """The ``i`` of the ``Dense_<i>`` module a parameter leaf belongs to."""
+    for k in path:
+        name = str(getattr(k, "key", getattr(k, "name", "")))
+        if name.startswith("Dense_"):
+            return int(name.split("_")[1])
+    raise ValueError(f"parameter {path} is in no Dense layer")
+
+
+def _h2o_sgd(p, batch: int) -> optax.GradientTransformation:
+    """H2O's manual-rate SGD (``adaptive_rate=False``). For the step after
+    ``n`` samples, with ``g`` the gradient of the batch's mean loss plus the
+    l1/l2 penalties, and ``l`` the index of a parameter's layer (0 for the
+    weights into the first hidden layer)::
+
+        rate_n = rate / (1 + rate_annealing * n)
+        mu_n   = momentum_start + (momentum_stable - momentum_start)
+                 * min(n / momentum_ramp, 1)          (momentum_ramp > 0)
+        d      = -rate_n * rate_decay**l * (1 - mu_n) * g
+        v      = mu_n * v + d
+        w      = w + v                   (classical momentum)
+        w      = w + mu_n * v + d        (nesterov_accelerated_gradient)
+
+    ``rate_decay`` is the factor between layers (the N-th layer learns at
+    ``rate * rate_decay**(N-1)``), the rate is scaled by ``1 - mu_n``, and
+    Nesterov's step is the look-ahead form ``w += (1 + mu) v - mu v_old``.
+    These follow H2O's documented parameters; upstream's ``Neurons.java``
+    was not at hand to check them against [UNVERIFIED: the ``1 - mu``
+    scaling, the Nesterov form, the penalties on biases (here every
+    parameter is penalised, as the ADADELTA path does)]."""
+    nesterov = bool(p.nesterov_accelerated_gradient)
+    decay = float(p.rate_decay)
+
+    def init(params):
+        return H2OSGDState(jnp.zeros((), jnp.int32),
+                           jax.tree.map(jnp.zeros_like, params))
+
+    def update(grads, state, params=None):
+        del params
+        rate, mom = _sgd_schedule(p, batch, state.count)
+        step = rate * (1.0 - mom)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(grads)
+        vel = treedef.flatten_up_to(state.momentum)
+        upd, new_v = [], []
+        for (path, g), v in zip(leaves, vel):
+            d = -(step * decay ** _dense_index(path)) * g
+            v = mom * v + d
+            upd.append(mom * v + d if nesterov else v)
+            new_v.append(v)
+        return (treedef.unflatten(upd),
+                H2OSGDState(state.count + 1, treedef.unflatten(new_v)))
+
+    return optax.GradientTransformation(init, update)
+
+
+def _make_optimizer(p, batch: int):
+    """ADADELTA (H2O's default ``adaptive_rate``) or H2O's manual-rate SGD
+    over minibatches of ``batch`` rows."""
     if p.adaptive_rate:
         return optax.adadelta(learning_rate=1.0, rho=p.rho, eps=p.epsilon)
-    return optax.sgd(
-        optax.exponential_decay(p.rate, 1000, p.rate_decay),
-        momentum=p.momentum_start or None,
-    )
+    return _h2o_sgd(p, batch)
 
 
 def _dl_pad_cols(d: int) -> int:
@@ -750,21 +905,134 @@ def _make_mlp(p, n_out: int) -> _MLP:
                 input_dropout=p.input_dropout_ratio)
 
 
+def _pred_program(mlp: _MLP, softmax: bool, block: int):
+    """The device predictor's forward, in row blocks inside one program: a
+    ``fori_loop`` over ``block``-row slices of the design (the last block
+    starts ``block`` rows before the end, so a ragged tail recomputes rows
+    it shares with the block before, to the same values), the softmax head
+    on each block, and the outputs written as ``(n_out, rows)``: an
+    ``(n, 2)`` float32 result would be tiled to 128 lanes a row. No
+    activation larger than ``(block, width)`` is ever alive."""
+    key = ("dl_pred", tuple(int(h) for h in mlp.hidden), mlp.activation.lower(),
+           int(mlp.n_out), bool(softmax), int(block), jax.default_backend())
+    fn = _DL_PROGRAMS.get(key)
+    if fn is not None:
+        return fn
+
+    @jax.jit
+    @jax.named_scope("ph_dl_pred")
+    def fn(prm, X):
+        n = X.shape[0]
+        B = min(block, n)
+
+        def body(i, out):
+            start = jnp.minimum(i * B, n - B)
+            o = mlp.apply(prm, jax.lax.dynamic_slice_in_dim(X, start, B, 0),
+                          train=False)
+            if softmax:
+                o = jax.nn.softmax(o, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(out, o.T, start, 1)
+
+        return jax.lax.fori_loop(0, -(-n // B), body,
+                                 jnp.zeros((mlp.n_out, n), jnp.float32))
+
+    _DL_PROGRAMS[key] = fn
+    return fn
+
+
+def _layers(prm, d_real: int | None = None) -> list:
+    """A parameter tree as ``[(W, b), ...]`` in layer order, numpy float32;
+    ``d_real`` cuts the first kernel's zero bucket-pad rows."""
+    inner = prm["params"] if "params" in prm else prm
+    names = sorted(inner, key=lambda k: int(k.split("_")[-1]))
+    out = [(np.asarray(inner[k]["kernel"]), np.asarray(inner[k]["bias"]))
+           for k in names]
+    if d_real is not None:
+        out[0] = (out[0][0][:d_real], out[0][1])
+    return out
+
+
 class DeepLearningModel(Model):
     algo = "deeplearning"
 
-    def _predict_raw(self, frame: Frame) -> np.ndarray:
-        di: DataInfo = self.output["datainfo"]
-        X, _ = di.transform(frame)
+    def _mlp(self) -> _MLP:
+        """The network, rebuilt from the parameters (its output width is the
+        last layer's bias)."""
+        n_out = int(_layers(self.output["params"])[-1][1].shape[0])
+        return _make_mlp(self.params, n_out)
+
+    def _design(self, frame: Frame):
+        X, _ = self.output["datainfo"].transform(frame)
         pad = int(self.output.get("input_pad") or 0)
         if pad:  # bucketed input width: scoring pads with the same zeros
             X = jnp.pad(X, ((0, 0), (0, pad)))
-        logits = self.output["apply_fn"](self.output["params"], X)
-        if self.output.get("autoencoder"):
-            return np.asarray(logits)[: frame.nrow]  # (n, expanded) recon
+        return X
+
+    def _forward(self, X):
+        """``(n_out, rows)`` float32 on the device: the blocked forward of
+        ``_pred_program``, class probabilities for a classifier."""
+        softmax = self.is_classifier and not self.output.get("autoencoder")
+        return _pred_program(self._mlp(), softmax, PRED_BLOCK)(
+            self.output["params"], X)
+
+    def _predict_raw(self, frame: Frame) -> np.ndarray:
+        out = np.asarray(self._forward(self._design(frame)))[:, : frame.nrow]
+        if self.output.get("autoencoder") or self.is_classifier:
+            return np.ascontiguousarray(out.T)  # (n, classes) / (n, expanded)
+        return out[0]
+
+    def _device_predictor(self):
+        # the autoencoder's metrics are its reconstruction error
+        return None if self.output.get("autoencoder") else self._predict_raw_dev
+
+    def _predict_raw_dev(self, frame: Frame):
+        """``_predict_raw`` with no pull to the host: P(class 1) for a
+        binomial model, ``(n, K)`` probabilities for multinomial, the
+        prediction for regression."""
+        return self._raw_from_design(self._design(frame), frame.nrow)
+
+    def _raw_from_design(self, X, nrow: int):
+        """The device predictions from a design matrix of this model's
+        ``DataInfo`` (the builder hands over the one it trained on, so the
+        training metrics transform nothing)."""
+        out = self._forward(X)
         if self.is_classifier:
-            return np.asarray(jax.nn.softmax(logits, axis=1))[: frame.nrow]
-        return np.asarray(logits[:, 0])[: frame.nrow]
+            return out[1, :nrow] if out.shape[0] == 2 else out[:, :nrow].T
+        return out[0, :nrow]
+
+    def sgd_probe(self) -> dict | None:
+        """The records a plain reference replays the training's updates from
+        (None after a checkpoint continuation, a streamed training or a
+        load: they are not persisted), in numpy, each parameter set as
+        ``[(W, b), ...]`` by layer:
+
+        - ``batch``: rows a minibatch;
+        - ``initial``: the weights before the first step;
+        - ``early``: ``steps`` (the first ``PROBE_STEPS``), ``rows`` (the
+          training's row indices for them, in the order they were taken) and
+          ``after`` (the weights the program held after them);
+        - ``late``: the last ``steps`` of the training's last epoch:
+          ``rows``, the weights ``before`` them, and where the optimizer is
+          manual-rate SGD its ``count`` (steps taken) and ``momentum``
+          then; ``after`` is the trained model's weights."""
+        pr = self.output.get("probe")
+        if pr is None:
+            return None
+        d = self.output["datainfo"].ncols_expanded
+        late = pr["late"]
+        rec = {"batch": int(pr["batch"]),
+               "initial": _layers(pr["initial"], d),
+               "early": {"steps": int(pr["early"]["steps"]),
+                         "rows": np.asarray(pr["early"]["rows"]),
+                         "after": _layers(pr["early"]["after"], d)},
+               "late": {"steps": int(late["steps"]),
+                        "rows": np.asarray(late["rows"]),
+                        "before": _layers(late["before"], d),
+                        "after": _layers(self.output["params"], d)}}
+        if isinstance(late["state"], H2OSGDState):
+            rec["late"]["count"] = int(late["state"].count)
+            rec["late"]["momentum"] = _layers(late["state"].momentum, d)
+        return rec
 
     def predict(self, frame: Frame) -> Frame:
         if not self.output.get("autoencoder"):
@@ -836,11 +1104,12 @@ class DeepLearning(ModelBuilder):
     algo = "deeplearning"
     PARAMS_CLS = DeepLearningParams
 
-    def _plan_streamed(self, train: Frame, di, p, d_pad: int, ybuf, okresp):
+    def _plan_streamed(self, train: Frame, di, p, d_pad: int, yv,
+                       classification: bool):
         """ChunkStore of block design lanes for out-of-core epochs, or
         None for the resident path (autoencoder is excluded — its
         reconstruction target is the whole design; docs/MIGRATION.md
-        fallback matrix)."""
+        fallback matrix). The streamed lanes are built on the host."""
         from h2o3_tpu.frame import chunkstore as cs
 
         if p.autoencoder:
@@ -866,6 +1135,13 @@ class DeepLearning(ModelBuilder):
             Xlane[lo:hi, : di.ncols_expanded] = np.asarray(jax.device_get(Xb))
             vmask[lo:hi] = np.asarray(jax.device_get(vb))
         cs.release_frame_features(train, need)
+        y_np = yv.to_numpy().astype(np.float64)
+        ybuf = np.zeros(npad, np.float32)
+        ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
+        okresp = np.ones(npad, np.float32)
+        okresp[: train.nrow] = (
+            (y_np >= 0) if classification else ~np.isnan(y_np)
+        ).astype(np.float32)
         w_np = vmask
         if p.weights_column:
             w_np = w_np * np.nan_to_num(
@@ -918,13 +1194,14 @@ class DeepLearning(ModelBuilder):
         H2OAutoEncoderEstimator): reconstruct the standardized design
         matrix; no response. Same sync-SGD driver as the supervised path."""
         p: DeepLearningParams = self.params
-        di = DataInfo.fit(train, self._x, standardize=p.standardize,
-                          hash_buckets=p.hash_buckets)
-        X, wmask = di.transform(train)
-        w = wmask
-        if p.weights_column:
-            w = w * jnp.nan_to_num(train.vec(p.weights_column).data)
-        w = jnp.asarray(np.asarray(w))
+        with _mx.span("dl.datainfo"):
+            di = DataInfo.fit(train, self._x, standardize=p.standardize,
+                              hash_buckets=p.hash_buckets)
+            X, wmask = di.transform(train)
+            w = wmask
+            if p.weights_column:
+                w = w * jnp.nan_to_num(train.vec(p.weights_column).data)
+            w = jnp.asarray(np.asarray(w))
 
         D = di.ncols_expanded
         mlp = _make_mlp(p, n_out=D)
@@ -952,7 +1229,7 @@ class DeepLearning(ModelBuilder):
                 )
             params = prior.output["params"]
 
-        tx = _make_optimizer(p)
+        tx = _make_optimizer(p, min(int(p.mini_batch_size), train.npad))
         opt_state = tx.init(params)
         if prior is not None and prior.output.get("opt_state") is not None:
             opt_state = prior.output["opt_state"]
@@ -970,11 +1247,12 @@ class DeepLearning(ModelBuilder):
         # autoencoder inputs are NOT shape-bucketed: the reconstruction
         # target is the input itself, so padded columns would enter the
         # per-row MSE mean (docs/MIGRATION.md fallback matrix)
-        params, opt_state, history, epochs_done = _run_sync_sgd(
-            job, p, mlp, "recon", tx, params, opt_state,
-            X, jnp.zeros(train.npad, jnp.float32), w,
-            train.nrow, train.npad, key, start_epochs, on_epoch=on_epoch,
-        )
+        with _mx.span("dl.epochs"):
+            params, opt_state, history, epochs_done, _ = _run_sync_sgd(
+                job, p, mlp, "recon", tx, params, opt_state,
+                X, jnp.zeros(train.npad, jnp.float32), w,
+                train.nrow, train.npad, key, start_epochs, on_epoch=on_epoch,
+            )
 
         apply_fn = jax.jit(lambda prm, xx: mlp.apply(prm, xx, train=False))
         out = {
@@ -1008,38 +1286,39 @@ class DeepLearning(ModelBuilder):
         K = yv.cardinality if classification else 1
         n_out = max(K, 1) if classification else 1
 
-        di = DataInfo.fit(train, self._x, standardize=p.standardize,
-                          hash_buckets=p.hash_buckets)
-        # shape-bucket ladder on the input width (zero columns, proven
-        # bit-inert via the zero-padded first kernel — _dl_pad_cols)
-        D = di.ncols_expanded
-        d_pad = _dl_pad_cols(D)
-        y_np = yv.to_numpy().astype(np.float64)
-        ybuf = np.zeros(train.npad, np.float32)
-        ybuf[: train.nrow] = np.nan_to_num(y_np, nan=0.0)
-        okresp = np.ones(train.npad, np.float32)
-        okresp[: train.nrow] = (
-            (y_np >= 0) if classification else ~np.isnan(y_np)
-        ).astype(np.float32)
+        # DataInfo.fit, the design matrix and the response and weight
+        # lanes; ends in a wait for the design program, so that its device
+        # time is not the epochs'
+        with _mx.span("dl.datainfo"):
+            di = DataInfo.fit(train, self._x, standardize=p.standardize,
+                              hash_buckets=p.hash_buckets)
+            # shape-bucket ladder on the input width (zero columns, proven
+            # bit-inert via the zero-padded first kernel — _dl_pad_cols)
+            D = di.ncols_expanded
+            d_pad = _dl_pad_cols(D)
 
-        # out-of-core streaming (ISSUE 11, frame/chunkstore.py): a design
-        # matrix past the HBM window trains as row-block epochs — DL
-        # already minibatches, so each block runs the existing chunk
-        # program; shuffling is within-block (documented deviation).
-        stream = self._plan_streamed(train, di, p, d_pad, ybuf, okresp)
-        if stream is not None:
-            X = stream
-            w = jnp.asarray(stream.lane("w"))
-            y = jnp.asarray(ybuf)
-        else:
-            X, wmask = di.transform(train)
-            if d_pad > D:
-                X = jnp.pad(X, ((0, 0), (0, d_pad - D)))
-            w = wmask
-            if p.weights_column:
-                w = w * jnp.nan_to_num(train.vec(p.weights_column).data)
-            w = jnp.asarray(np.asarray(w) * okresp)
-            y = jnp.asarray(ybuf)
+            # out-of-core streaming (frame/chunkstore.py): a design
+            # matrix past the HBM window trains as row-block epochs — DL
+            # already minibatches, so each block runs the existing chunk
+            # program; shuffling is within-block (documented deviation).
+            stream = self._plan_streamed(train, di, p, d_pad, yv,
+                                         classification)
+            if stream is not None:
+                X = stream
+                w = jnp.asarray(stream.lane("w"))
+                y = jnp.asarray(stream.lane("y"))
+            else:
+                from h2o3_tpu.models.glm import _response_lanes
+
+                # resident: nothing n-row-sized is made on the host
+                X, wmask = di.transform(train)
+                if d_pad > D:
+                    X = jnp.pad(X, ((0, 0), (0, d_pad - D)))
+                y, w, _, _ = _response_lanes(
+                    yv.data, wmask,
+                    train.vec(p.weights_column).data
+                    if p.weights_column else None, None)
+                X.block_until_ready()
 
         mlp = _make_mlp(p, n_out=n_out)
         seed = abs(p.seed) if p.seed and p.seed > 0 else 99
@@ -1068,7 +1347,9 @@ class DeepLearning(ModelBuilder):
                 )
             params = _repad_input_kernel(prior.output["params"], D, d_pad)
 
-        tx = _make_optimizer(p)
+        tx = _make_optimizer(p, min(int(p.mini_batch_size),
+                                    stream.block_rows if stream is not None
+                                    else train.npad))
         opt_state = tx.init(params)
         if prior is not None and prior.output.get("opt_state") is not None:
             # carry the optimizer accumulators (adadelta rho-averages /
@@ -1101,11 +1382,14 @@ class DeepLearning(ModelBuilder):
             faults.die_check(self.algo)  # chaos: worker death at boundary
             faults.abort_check(self.algo, done)
 
-        params, opt_state, history, epochs_done = _run_sync_sgd(
-            job, p, mlp, "ce" if classification else "mse", tx, params,
-            opt_state, X, y, w,
-            train.nrow, train.npad, key, start_epochs, on_epoch=on_epoch,
-        )
+        # the epochs: host permutations, then each chunk dispatched and
+        # synced in its `dispatch:dl_chunk` child
+        with _mx.span("dl.epochs"):
+            params, opt_state, history, epochs_done, probe = _run_sync_sgd(
+                job, p, mlp, "ce" if classification else "mse", tx, params,
+                opt_state, X, y, w,
+                train.nrow, train.npad, key, start_epochs, on_epoch=on_epoch,
+            )
         apply_fn = jax.jit(lambda prm, xx: mlp.apply(prm, xx, train=False))
 
         out = {
@@ -1126,7 +1410,13 @@ class DeepLearning(ModelBuilder):
             model.training_metrics = self._streamed_metrics(model, stream, train)
             stream.close()
         else:
-            model.training_metrics = model._score_metrics(train)
+            # from the design matrix the epochs ran on, in row blocks: `train`
+            # is not transformed a second time
+            model.training_metrics = model._score_metrics(
+                train, raw=lambda: model._raw_from_design(X, train.nrow))
         if valid is not None:
             model.validation_metrics = model._score_metrics(valid)
+        # on the host once the device's work is done: no HBM held for them
+        # (and not persisted: persist._STRIP)
+        model.output["probe"] = jax.device_get(probe)
         return model

@@ -523,7 +523,7 @@ def _pull_tree_output(out: dict) -> dict:
 
 _STRIP: dict[str, tuple[str, ...]] = {
     "glm": ("family_obj",),
-    "deeplearning": ("apply_fn",),
+    "deeplearning": ("apply_fn", "probe"),
 }
 
 _REBUILDERS: dict[str, Callable[[Model], None]] = {}

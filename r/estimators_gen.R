@@ -447,8 +447,12 @@ h2o.deeplearning <- function(
     rho = 0.99,
     epsilon = 1e-08,
     rate = 0.005,
+    rate_annealing = 1e-06,
     rate_decay = 1.0,
     momentum_start = 0.0,
+    momentum_ramp = 1000000.0,
+    momentum_stable = 0.0,
+    nesterov_accelerated_gradient = TRUE,
     mini_batch_size = 32,
     standardize = TRUE,
     loss = "Automatic",
@@ -481,8 +485,12 @@ h2o.deeplearning <- function(
   if (!missing(rho)) p$rho <- rho
   if (!missing(epsilon)) p$epsilon <- epsilon
   if (!missing(rate)) p$rate <- rate
+  if (!missing(rate_annealing)) p$rate_annealing <- rate_annealing
   if (!missing(rate_decay)) p$rate_decay <- rate_decay
   if (!missing(momentum_start)) p$momentum_start <- momentum_start
+  if (!missing(momentum_ramp)) p$momentum_ramp <- momentum_ramp
+  if (!missing(momentum_stable)) p$momentum_stable <- momentum_stable
+  if (!missing(nesterov_accelerated_gradient)) p$nesterov_accelerated_gradient <- nesterov_accelerated_gradient
   if (!missing(mini_batch_size)) p$mini_batch_size <- mini_batch_size
   if (!missing(standardize)) p$standardize <- standardize
   if (!missing(loss)) p$loss <- loss
