@@ -9,8 +9,10 @@ data-parallel SGD: here each epoch is ONE compiled ``lax.scan`` over
 minibatches of the row-sharded design matrix — flax MLP forward/backward on
 the MXU in float32 (HIGHEST), ADADELTA (h2o's adaptive_rate default) from
 optax or H2O's manual-rate SGD (``_h2o_sgd``: rate annealing, per-layer rate
-decay, a momentum ramp, Nesterov). Parameter parity: hidden/activation
-(+dropout variants), input_dropout, l1/l2, adaptive-rate rho/epsilon,
+decay, a momentum ramp, Nesterov); on one TPU chip a classifier's
+manual-rate step with no dropout is one Pallas kernel
+(``ops/dl_step_pallas.py``, :func:`_fused_schedule`). Parameter parity:
+hidden/activation (+dropout variants), input_dropout, l1/l2, adaptive-rate rho/epsilon,
 rate/rate_annealing/rate_decay, momentum_start/ramp/stable,
 nesterov_accelerated_gradient, standardize, early stopping. Scoring is a
 blocked forward in one program (``_pred_program``). Deviation noted:
@@ -49,6 +51,11 @@ _DL_STEPS = _mx.counter(
     "dl_minibatch_steps_total",
     "DeepLearning minibatch updates executed (counted at dispatch: "
     "minibatches an epoch x the dispatch's epochs)")
+_DL_FUSED_STEPS = _mx.counter(
+    "dl_fused_steps_total",
+    "DeepLearning minibatch updates dispatched through the one-kernel step "
+    "(ops/dl_step_pallas.py), counted at dispatch as dl_minibatch_steps_total "
+    "is")
 _DL_EPOCH_SECONDS = _mx.histogram(
     "dl_epoch_seconds", "per-epoch wall time of the sync-SGD driver")
 # host dispatches issued by the epoch driver (the epoch-chunk acceptance
@@ -254,9 +261,51 @@ class _MLP(nn.Module):
 
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _fused_schedule(p, mlp, kind: str, shard_on: bool, ctl_shards: int,
+                    n_in: int, batch: int):
+    """H2O's SGD schedule for the one-kernel step (``ops/dl_step_pallas``),
+    or None where the step stays XLA's: the kernel takes the softmax
+    cross-entropy of a dense tanh or rectifier network with no dropout,
+    trained by manual-rate SGD (``_h2o_sgd``) on one replicated lane, on one
+    TPU chip (a Mosaic kernel is not partitioned over a mesh), whose
+    parameters, momentum and activations fit its VMEM budget."""
+    from h2o3_tpu.ops import dl_step_pallas as dsp
+    from h2o3_tpu.parallel.mesh import n_shards
+
+    widths = [int(n_in), *(int(h) for h in mlp.hidden), int(mlp.n_out)]
+    if (kind != "ce" or p.adaptive_rate or shard_on or ctl_shards
+            or mlp.activation.lower() not in ("tanh", "rectifier")
+            or float(mlp.input_dropout) > 0
+            or any(float(d) > 0 for d in mlp.dropout)
+            or not _on_tpu() or n_shards() != 1
+            or not dsp.fits(widths, batch)):
+        return None
+    return dsp.Schedule(
+        float(p.rate), float(p.rate_annealing), float(p.rate_decay),
+        float(p.momentum_start or 0.0), float(p.momentum_ramp),
+        float(p.momentum_stable or 0.0),
+        bool(p.nesterov_accelerated_gradient))
+
+
+def _fused_slots(prm) -> list:
+    """The leaf positions of each Dense layer's ``(kernel, bias)`` in
+    ``jax.tree.leaves(prm)`` order, by layer."""
+    slots = {}
+    for j, (path, _) in enumerate(jax.tree_util.tree_flatten_with_path(prm)[0]):
+        name = str(getattr(path[-1], "key", getattr(path[-1], "name", "")))
+        slots[(_dense_index(path), name)] = j
+    return [(slots[(i, "kernel")], slots[(i, "bias")])
+            for i in range(len(slots) // 2)]
+
+
 def _dl_chunk_program(desc, mlp, tx, kind: str, batch: int, npad: int,
                       n_chunk: int, shard_on: bool, unravel=None,
-                      n_real: int = 0, fpad: int = 0, ctl_shards: int = 0):
+                      n_real: int = 0, fpad: int = 0, ctl_shards: int = 0,
+                      sgd=None):
     """Build (or fetch) the compiled K-epochs-per-dispatch training chunk.
 
     One program runs ``n_chunk`` whole epochs: an outer fori over the
@@ -279,6 +328,13 @@ def _dl_chunk_program(desc, mlp, tx, kind: str, batch: int, npad: int,
     per-chunk folds reproduces the identical masks: that is the
     ``ctl_shards`` lane (``H2O3_TPU_DL_GRAD_SHARD=ctl``), the
     trajectory-parity control for the sharded dropout run.
+
+    With ``sgd`` (H2O's schedule, from :func:`_fused_schedule`) a step is
+    one launch of ``ops/dl_step_pallas``'s kernel: forward, loss, backward
+    and update in VMEM, the parameters and momentum carried in the kernel's
+    layout through each segment of the step loop (compiled, or interpreted
+    off the TPU). It shares no step logic with the XLA step, which serves
+    dropout, ADADELTA, the autoencoder and the sharded lanes.
     """
     import jax.tree_util as jtu
 
@@ -289,7 +345,7 @@ def _dl_chunk_program(desc, mlp, tx, kind: str, batch: int, npad: int,
     from jax.sharding import PartitionSpec as Spec
 
     key = ("dl_chunk", desc, batch, npad, n_chunk, bool(shard_on),
-           int(ctl_shards), PROBE_STEPS, mesh_key(), jax.default_backend())
+           int(ctl_shards), sgd, PROBE_STEPS, mesh_key(), jax.default_backend())
     fn = _DL_PROGRAMS.get(key)
     if fn is not None:
         _DL_HITS.inc()
@@ -397,6 +453,39 @@ def _dl_chunk_program(desc, mlp, tx, kind: str, batch: int, npad: int,
                 check_vma=False,
             )(prm_flat, ost, xb, yb, wb, bk, l1, l2)
 
+    def fused_steps(lo, hi, sc, Xp, yp, wp, l1, l2):
+        """Steps ``lo..hi`` of the epoch, one kernel launch each, the
+        parameters and momentum in the kernel's layout between them."""
+        from h2o3_tpu.ops import dl_step_pallas as dsp
+
+        prm, ost, k, loss_sum = sc
+        leaves, treedef = jax.tree.flatten(prm)
+        mom = treedef.flatten_up_to(ost.momentum)
+        slots = _fused_slots(prm)
+        carry = dsp.pack(ost.count, loss_sum,
+                         *([src[s[c]] for s in slots]
+                           for src in (leaves, mom) for c in (0, 1)))
+        pen = jnp.stack([l1, l2]).reshape(1, 2)
+        act = mlp.activation.lower()
+        D = Xp.shape[1]
+
+        def fstep(i, c):
+            with jax.named_scope("ph_dl_step"):
+                start = i * batch
+                xt = jax.lax.dynamic_slice(Xp, (start, 0), (batch, D)).T
+                yb = jax.lax.dynamic_slice(yp, (start,), (batch,))
+                wb = jax.lax.dynamic_slice(wp, (start,), (batch,))
+                return dsp.step(c, xt, yb, wb, pen, sched=sgd, act=act,
+                                interpret=jax.default_backend() != "tpu")
+
+        count, loss_sum, ws, bs, vs, vbs = dsp.unpack(
+            jax.lax.fori_loop(lo, hi, fstep, carry))
+        new, new_m = list(leaves), list(mom)
+        for (kw, kb), w_, b_, v_, vb_ in zip(slots, ws, bs, vs, vbs):
+            new[kw], new[kb], new_m[kw], new_m[kb] = w_, b_, v_, vb_
+        return (treedef.unflatten(new),
+                H2OSGDState(count, treedef.unflatten(new_m)), k, loss_sum)
+
     def chunk(params, opt_state, X, y, w, perms, key, nbatch, l1, l2,
               slot_mask, first):
         D = X.shape[1]
@@ -429,6 +518,11 @@ def _dl_chunk_program(desc, mlp, tx, kind: str, batch: int, npad: int,
                         prm = optax.apply_updates(prm, upd)
                 return (prm, ost, k, loss_sum + loss)
 
+            def steps(lo, hi, sc):
+                if sgd is None:
+                    return jax.lax.fori_loop(lo, hi, step, sc)
+                return fused_steps(lo, hi, sc, Xp, yp, wp, l1, l2)
+
             # the probes' records (DeepLearningModel.sgd_probe): the loop is
             # cut, with no work added to a step, after the training's first
             # PROBE_STEPS steps and before the chunk's last epoch's last ones
@@ -438,7 +532,7 @@ def _dl_chunk_program(desc, mlp, tx, kind: str, batch: int, npad: int,
 
             def segment(j, sg):
                 sc, early, late = sg
-                sc = jax.lax.fori_loop(cuts[j], cuts[j + 1], step, sc)
+                sc = steps(cuts[j], cuts[j + 1], sc)
                 early = pick(head & (j == 0), sc[0], early)
                 late = pick((e == n_chunk - 1) & (j == 1), sc[:2], late)
                 return sc, early, late
@@ -500,6 +594,7 @@ def _run_sync_sgd(job, p, mlp, kind, tx, params, opt_state, X, y, w,
     ctl = _dl_dropout_ctl(p, dropout, p.input_dropout_ratio)
     if ctl and batch % ctl:
         ctl = 0  # the sharded lane it controls for would be ineligible too
+    sgd = _fused_schedule(p, mlp, kind, shard_on, ctl, X.shape[1], batch)
     n_sh = n_shards()
     # the FULL network + optimizer identity: n_out matters even at equal
     # hidden/width (a cached program's closed-over mlp bakes the output
@@ -565,11 +660,14 @@ def _run_sync_sgd(job, p, mlp, kind, tx, params, opt_state, X, y, w,
         prog = _dl_chunk_program(
             desc, mlp, tx, kind, batch, npad, k_i, shard_on,
             unravel=unravel, n_real=n_real, fpad=fpad, ctl_shards=ctl,
+            sgd=sgd,
         )
         perms_dev = jnp.asarray(perms)
         first = e == 0
         _DL_DISPATCHES.inc()
         _DL_STEPS.inc(nbatch * k_i)
+        if sgd is not None:
+            _DL_FUSED_STEPS.inc(nbatch * k_i)
         from h2o3_tpu.utils import flightrec as _fr
 
         with _fr.dispatch("dl_chunk", epochs=int(k_i), rows=int(npad)):
@@ -654,6 +752,7 @@ def _run_sync_sgd_streamed(job, p, mlp, kind, tx, params, opt_state, store,
         ctl = 0
     n_sh = n_shards()
     D = store.lane("X").shape[1]
+    sgd = _fused_schedule(p, mlp, kind, shard_on, ctl, D, batch)
     desc = (tuple(int(h) for h in mlp.hidden), mlp.activation.lower(),
             tuple(mlp.dropout), float(mlp.input_dropout), int(mlp.n_out),
             kind, D, *_sgd_desc(p))
@@ -714,9 +813,12 @@ def _run_sync_sgd_streamed(job, p, mlp, kind, tx, params, opt_state, store,
             prog = _dl_chunk_program(
                 desc, mlp, tx, kind, batch, blk_rows, 1, shard_on,
                 unravel=unravel, n_real=n_real, fpad=fpad, ctl_shards=ctl,
+                sgd=sgd,
             )
             _DL_DISPATCHES.inc()
             _DL_STEPS.inc(nbatch)
+            if sgd is not None:
+                _DL_FUSED_STEPS.inc(nbatch)
             from h2o3_tpu.utils import flightrec as _fr
 
             with _fr.dispatch("dl_chunk", block=int(bi),

@@ -321,6 +321,93 @@ def test_grouped_kernel_compiles_alone_for_a_described_v5e(
         "hist_pallas_grouped", "hist_pallas_dense"}
 
 
+def _hlo_computation(text: str, name: str) -> list:
+    """The instruction lines of one computation of a compiled module."""
+    out, inside = [], False
+    for line in text.splitlines():
+        if not inside:
+            inside = line.startswith(name + " ")
+        elif line == "}":
+            break
+        else:
+            out.append(line.strip())
+    return out
+
+
+def test_dl_step_loop_is_one_kernel_on_a_described_v5e(
+        monkeypatch, v5e_chip, no_compile_cache):
+    """``dl_higgs``'s epoch program — 28 -> 5 x 300 tanh -> 2, batches of
+    100, H2O's manual-rate SGD — compiled for a v5e with the chip's branches
+    taken: the step loop's body is the minibatch's slices (its rows
+    transposed, ``f32[28,100]``, a free relayout of the epoch's copy; its
+    labels and weights, ``f32[100]``) and one ``tpu_custom_call``, the
+    ``dl_step_pallas`` kernel. The XLA step's body held 33 fusions. The
+    program's temporaries stay at the XLA step's, 1.54 GB at the cell's
+    rows: a row-major ``(100, 28)`` operand had XLA lay the epoch's copy
+    out at 128 lanes a row, 7.09 GB (the compiler makes that choice only at
+    large row counts: at 65,536 rows both read 2.7 MB)."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, SingleDeviceSharding
+
+    from h2o3_tpu.models import deeplearning as dlm
+    from h2o3_tpu.parallel import mesh as pm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, batch, cols = 11_010_048, 100, 28  # the cell's padded rows
+    p = dlm.DeepLearningParams(
+        hidden=[300] * 5, activation="Tanh", adaptive_rate=False, rate=0.05,
+        rate_annealing=2.02e-9, momentum_start=0.9, momentum_ramp=2.1e9,
+        momentum_stable=0.99, nesterov_accelerated_gradient=False, l2=1e-5,
+        mini_batch_size=batch)
+    mlp = dlm._make_mlp(p, 2)
+    tx = dlm._make_optimizer(p, batch)
+    params = jax.eval_shape(
+        lambda: mlp.init(jax.random.PRNGKey(0), jnp.zeros((1, cols))))
+    one = SingleDeviceSharding(v5e_chip)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    as_shapes = functools.partial(
+        jax.tree.map, lambda a: S(a.shape, a.dtype))
+    f32 = jnp.float32
+    old = pm._mesh
+    pm.set_mesh(Mesh(np.array([v5e_chip]), (pm.ROWS_AXIS,)))
+    try:
+        sgd = dlm._fused_schedule(p, mlp, "ce", False, 0, cols, batch)
+        assert sgd is not None
+        prog = dlm._dl_chunk_program(
+            ("lowering", "dl_higgs"), mlp, tx, "ce", batch, rows, 1, False,
+            sgd=sgd)
+        lowered = prog.lower(
+            as_shapes(params), as_shapes(jax.eval_shape(tx.init, params)),
+            S((rows, cols), f32), S((rows,), f32), S((rows,), f32),
+            S((1, rows), jnp.int32), S((2,), jnp.uint32), S((), jnp.int32),
+            S((), f32), S((), f32), S((rows,), f32), S((), jnp.bool_))
+    finally:
+        pm.set_mesh(old)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    bodies = [_hlo_computation(text, b)
+              for b in re.findall(r"body=(%[\w.\-]+)", text)]
+    steps = [b for b in bodies if any("tpu_custom_call" in ln for ln in b)]
+    assert len(steps) == 1, len(steps)
+    ops = [m.group(2) for m in (
+        re.match(r"(?:ROOT )?%\S+ = (.*?) ([a-z\-]+)\(", ln) for ln in steps[0])
+        if m]
+    assert ops.count("custom-call") == 1
+    assert "dl_step_pallas" in " ".join(steps[0])
+    # what else launches in the body: the three slices, and no layout copy
+    assert ops.count("fusion") + ops.count("dynamic-slice") <= 3, ops
+    assert "copy" not in ops, ops
+    # an operation with the minibatch's rows: how dl_step_roofline_pct's
+    # reader finds the step loop in a trace
+    assert any(re.search(rf"= [a-z]+\d*\[{batch}[,\]]", ln) for ln in steps[0])
+    # the epoch's copy at 32 sublanes a column block (1.41 GB), not at 128
+    # lanes a row (5.64 GB)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * rows * 64, temp
+
+
 def test_tile_sweep_skips_only_candidates_that_do_not_fit(monkeypatch):
     """On the chip 4 of the 12 sweep candidates exceed scoped VMEM at the
     headline geometry (PR 21): that RESOURCE_EXHAUSTED is a measured outcome
