@@ -8,6 +8,12 @@ values imputed or skipped. Here the view is materialized as one row-sharded
 fuses the expansion into downstream matmuls. Train-time statistics (means,
 sigmas, domains) are captured so the identical transform applies to
 validation/test frames (the ``adaptTestForTrain`` contract).
+
+:meth:`DataInfo.transform_factored` is the second view, for the GLM's IRLS:
+each plain categorical column stays its codes (``hex.DataInfo.Row``'s
+``binIds``) and only the numerics and the intercept are dense — a
+:class:`FactoredDesign`, a few bytes a row where the indicator blocks take
+four bytes a level.
 """
 
 from __future__ import annotations
@@ -20,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from h2o3_tpu.frame.frame import CAT, Frame, Vec
-from h2o3_tpu.parallel.mesh import mesh_key, row_sharding
+from h2o3_tpu.parallel.mesh import get_mesh, mesh_key, row_pspec, row_sharding
 from h2o3_tpu.utils import flightrec as _fr
 
 MEAN_IMPUTATION = "mean_imputation"
@@ -47,6 +53,52 @@ class ColumnSpec:
     # TRAINING domains of both sources, kept so scoring frames remap each
     # source before forming combined code a*|domain_b| + b
     pair_domains: tuple[tuple[str, ...], tuple[str, ...]] | None = None
+
+
+@jax.tree_util.register_pytree_node_class
+class FactoredDesign:
+    """The design matrix with its categorical blocks as codes (what
+    :meth:`DataInfo.transform_factored` returns): an ``(npad, p)`` matrix
+    that is never materialised.
+
+    - ``codes`` ``(C, npad)`` int32, one row a plain categorical column: the
+      DESIGN column index of the row's level (the block's offset plus the
+      level, shifted for the dropped reference level), or -1 where the
+      indicator row is all zeros (NA, the reference level, an invalid row);
+    - ``dense`` ``(q, npad)`` float32: the numerics (imputed, standardised)
+      and the intercept, zero on invalid rows, at design columns
+      ``dense_pos``;
+    - ``p``: the design's width (padded columns past ``ncols_expanded`` are
+      zero columns).
+
+    Both arrays carry the rows on their LAST axis: an ``(npad, 6)`` operand
+    would be tiled to 128 lanes a row in the TPU's HBM. A pytree: the arrays
+    are its leaves, ``dense_pos`` and ``p`` its static structure."""
+
+    def __init__(self, codes, dense, dense_pos: tuple, p: int):
+        self.codes = codes
+        self.dense = dense
+        self.dense_pos = tuple(dense_pos)
+        self.p = int(p)
+
+    def tree_flatten(self):
+        return (self.codes, self.dense), (self.dense_pos, self.p)
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves, *aux)
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.codes.shape[1], self.p)
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.codes.nbytes + self.dense.nbytes)
+
+    def widened(self, p: int) -> "FactoredDesign":
+        """The same design with zero columns up to width ``p``."""
+        return FactoredDesign(self.codes, self.dense, self.dense_pos, p)
 
 
 @dataclass
@@ -264,6 +316,44 @@ class DataInfo:
         with _fr.dispatch("design", rows=frame.npad, cols=self.ncols_expanded):
             return _design(plan, np.int32(frame.nrow), stats, operands)
 
+    def factorable(self) -> bool:
+        """Whether :meth:`transform_factored` applies: a plain categorical
+        column and no interaction or hashed one (those keep the dense
+        design, docs/MIGRATION.md)."""
+        plain = [c.kind == "cat" and c.pair is None for c in self.columns]
+        return any(plain) and all(
+            ok or (c.kind == "num" and c.pair is None)
+            for ok, c in zip(plain, self.columns))
+
+    def transform_factored(self, frame: Frame):
+        """The design of :meth:`transform` as a :class:`FactoredDesign`, plus
+        the same row validity mask. One dispatch (``dispatch:design``), the
+        traced program :func:`_design_factored`; the numerics are computed
+        op for op as :func:`_design` computes them."""
+        assert self.factorable(), "interaction or hashed columns: use transform"
+        cols, operands, dense_pos = [], [], []
+        stats = np.zeros((max(1, len(self.columns)), 2), np.float32)
+        for i, c in enumerate(self.columns):
+            stats[i] = c.mean, c.sigma
+            if c.kind == "cat":
+                cols.append(("cat", c.offset))
+                operands.append(_codes_operands(frame.vec(c.name), c.domain))
+            else:
+                cols.append(("num",))
+                operands.append(frame.vec(c.name).data)
+                dense_pos.append(c.offset)
+        if self.add_intercept:
+            dense_pos.append(self.ncols_expanded - 1)
+        plan = (
+            tuple(cols), self.standardize, self.missing_handling == SKIP,
+            self.use_all_factor_levels, self.add_intercept, frame.npad,
+            mesh_key(),
+        )
+        with _fr.dispatch("design", rows=frame.npad, cols=self.ncols_expanded):
+            codes, dense, valid = _design_factored(
+                plan, np.int32(frame.nrow), stats, operands)
+        return FactoredDesign(codes, dense, dense_pos, self.ncols_expanded), valid
+
     def _hash_lut_for(self, v: Vec, c: ColumnSpec):
         """Device LUT (level code -> bucket) of a hashed column, cached per
         column (most-recent domain) so steady-state scoring never re-pays the
@@ -295,14 +385,8 @@ def _design(plan, nrow, stats, operands):
     for i, (cp, ops) in enumerate(zip(col_plans, operands)):
         mean, sigma = stats[i, 0], stats[i, 1]
         if cp[0] == "num":
-            data = ops[0]
-            isna = jnp.isnan(data)
+            x, isna = _num_column(ops[0], mean, sigma, standardize, skip)
             present &= ~isna
-            x = jnp.where(isna, mean, data)
-            if standardize:
-                x = (x - mean) / sigma
-            elif skip:
-                x = jnp.where(isna, 0.0, x)
             cols.append(x[:, None])
         elif cp[0] == "num_num":
             xa, xb = ops
@@ -341,6 +425,53 @@ def _design(plan, nrow, stats, operands):
     # zero out invalid rows so they contribute nothing to reductions
     X = X * valid[:, None]
     return X, jax.lax.with_sharding_constraint(valid, row_sharding())
+
+
+def _num_column(data, mean, sigma, standardize: bool, skip: bool):
+    """A numeric column of the design and its NA mask: NA imputed with the
+    TRAINING mean, then standardised (or, unstandardised under SKIP, NA as
+    0: the row is invalid anyway)."""
+    isna = jnp.isnan(data)
+    x = jnp.where(isna, mean, data)
+    if standardize:
+        x = (x - mean) / sigma
+    elif skip:
+        x = jnp.where(isna, 0.0, x)
+    return x, isna
+
+
+@partial(jax.jit, static_argnums=0)
+@jax.named_scope("ph_std")
+def _design_factored(plan, nrow, stats, operands):
+    """The traced body of :meth:`DataInfo.transform_factored`: ``(codes,
+    dense, valid)``. ``plan`` holds each column's kind and, for a
+    categorical, its block's offset; the rest is :func:`_design`'s."""
+    col_plans, standardize, skip, use_all, add_intercept, npad, _mesh = plan
+    valid = (jnp.arange(npad) < nrow).astype(jnp.float32)
+    present = jnp.ones(npad, bool)
+    base = 0 if use_all else 1  # use_all_factor_levels=False drops level 0
+    codes, dense = [], []
+    for i, (cp, ops) in enumerate(zip(col_plans, operands)):
+        if cp[0] == "cat":
+            c = _codes(ops).astype(jnp.int32)
+            present &= c >= 0
+            codes.append(jnp.where(c >= base, c - base + cp[1], -1))
+        else:
+            x, isna = _num_column(ops, stats[i, 0], stats[i, 1], standardize, skip)
+            present &= ~isna
+            dense.append(x)
+    if skip:
+        valid = valid * present.astype(jnp.float32)
+    if add_intercept:
+        dense.append(jnp.ones(npad, jnp.float32))
+    lanes = jax.sharding.NamedSharding(
+        get_mesh(), row_pspec(ndim=2, axis=1))
+    codes = jnp.where(valid > 0, jnp.stack(codes), -1)
+    dense = (jnp.stack(dense) if dense else jnp.zeros((0, npad), jnp.float32))
+    dense = dense * valid[None, :]
+    return (jax.lax.with_sharding_constraint(codes, lanes),
+            jax.lax.with_sharding_constraint(dense, lanes),
+            jax.lax.with_sharding_constraint(valid, row_sharding()))
 
 
 def _hash_lut(domain: tuple[str, ...], col_name: str, n_buckets: int):

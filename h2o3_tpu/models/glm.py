@@ -35,16 +35,20 @@ import numpy as np
 from h2o3_tpu.cluster.job import Job
 from h2o3_tpu.cluster.registry import DKV
 from h2o3_tpu.frame.frame import Frame
-from h2o3_tpu.models.datainfo import MEAN_IMPUTATION, SKIP, DataInfo
+from h2o3_tpu.models.datainfo import (
+    MEAN_IMPUTATION, SKIP, DataInfo, FactoredDesign,
+)
 from h2o3_tpu.models.glm_families import get_family
 from h2o3_tpu.models.model_base import CommonParams, Model, ModelBuilder
 from h2o3_tpu.ops.gram import (
     admm_elastic_net,
     admm_elastic_net_device,
+    cat_gram_local,
     cho_solve_jitter_device,
     gram_collective_bytes,
     solve_cholesky,
     weighted_gram,
+    weighted_gram_factored,
 )
 from h2o3_tpu.utils import faults
 from h2o3_tpu.utils import metrics as _mx
@@ -98,6 +102,19 @@ _GLM_FALLBACKS = _mx.counter(
     "glm_fuse_fallbacks_total",
     "GLM fits (or lambda steps) that fell back from the fused while_loop "
     "lane while the fuse knob was on, by structural reason", always=True)
+
+# the factored design (categoricals as codes, models/datainfo.py): the bytes
+# of the design operands each GLM transform made (dense: 4 a design column a
+# row; factored: 4 a categorical column plus 4 a dense column), and the
+# Gram passes the categorical kernel (ops/gram.py::cat_gram_local) ran
+_DESIGN_BYTES = _mx.counter(
+    "glm_design_bytes_total",
+    "device bytes of the design operands each GLM transform made",
+    always=True)
+_CAT_GRAM_CALLS = _mx.counter(
+    "glm_cat_gram_calls_total",
+    "Gram passes over a factored design (the cat_gram_pallas kernel)",
+    always=True)
 
 # fused IRLS chunk program cache: (shape bucket, family, solver branch,
 # mesh, backend) -> compiled chunk. The shape-bucket ladder (rows ride the
@@ -182,13 +199,67 @@ class GLMParams(CommonParams):
 # device programs (cached per family via partial+jit)
 
 
+def _single_irls(family: str, p: "GLMParams") -> bool:
+    """The single-vector IRLS lane with no p-values: the fits that may
+    stream their design, and whose design may be factored."""
+    return (family not in ("multinomial", "ordinal")
+            and p.solver.upper().replace("-", "_") not in ("L_BFGS", "LBFGS")
+            and not p.compute_p_values)
+
+
+def _factored(family: str, p: "GLMParams", di: DataInfo) -> bool:
+    """Whether a fit (or a model's scoring) takes the factored design: the
+    single-vector IRLS lane on a DataInfo with a plain categorical column.
+    Multinomial, ordinal, L-BFGS and p-values keep the dense one
+    (docs/MIGRATION.md), as do interaction and hashed columns."""
+    return _single_irls(family, p) and di.factorable()
+
+
+def _glm_design(di: DataInfo, frame: Frame, factored: bool):
+    """``(X, valid)``: the design, factored or dense, counted in
+    ``glm_design_bytes_total``."""
+    X, valid = (di.transform_factored(frame) if factored
+                else di.transform(frame))
+    _DESIGN_BYTES.inc(X.nbytes)
+    return X, valid
+
+
+def _eta(X, beta):
+    """``X @ beta``: the einsum of a dense design; for a factored one a
+    gather of ``beta`` for each categorical column (code -1 takes the 0
+    appended past the end) plus the dense columns' product."""
+    if not isinstance(X, FactoredDesign):
+        return jnp.einsum("np,p->n", X, beta, precision=_HI)
+    with jax.named_scope("ph_eta_cat"):
+        ext = jnp.concatenate([beta, jnp.zeros((1,), beta.dtype)])
+        eta = None
+        for c in range(X.codes.shape[0]):
+            code = X.codes[c]
+            g = jnp.take(ext, jnp.where(code >= 0, code, beta.shape[0]))
+            eta = g if eta is None else eta + g
+        if X.dense_pos:
+            eta = eta + jnp.einsum("qn,q->n", X.dense,
+                                   beta[np.asarray(X.dense_pos)],
+                                   precision=_HI)
+        return eta
+
+
+def _gram(X, W, z):
+    """(G, b, sw) of :func:`weighted_gram`, from codes for a factored
+    design."""
+    if isinstance(X, FactoredDesign):
+        return weighted_gram_factored(X.codes, X.dense, W, z, p=X.p,
+                                      dense_pos=X.dense_pos)
+    return weighted_gram(X, W, z)
+
+
 def _irls_weights(fam, X, y, w, offset, beta):
     """The GLMIterationTask row math for the current beta: IRLS working
     weights W, working response z, and the deviance — shared op-for-op by
     the per-iteration pass and the fused while_loop body so the two lanes
     compute identical iterations."""
     with jax.named_scope("ph_gram"):  # the Gram pass's row math
-        eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
+        eta = _eta(X, beta) + offset
         mu = fam.link.inv(eta)
         d = fam.link.dinv(eta)
         d = jnp.where(d == 0, 1e-10, jnp.sign(d) * jnp.maximum(jnp.abs(d), 1e-10))
@@ -205,12 +276,12 @@ def _irls_pass(X, y, w, offset, beta, family_key, fam_args):
     """One GLMIterationTask: Gram/XtWz for the current beta + deviance."""
     fam = get_family(family_key, *fam_args)
     W, z, dev = _irls_weights(fam, X, y, w, offset, beta)
-    G, b, sw = weighted_gram(X, W, z)
+    G, b, sw = _gram(X, W, z)
     return G, b, dev
 
 
 def _fused_chunk_program(npad, p_pad, family_key, fam_args, l1_on,
-                         non_negative):
+                         non_negative, layout=None):
     """Build (or fetch) the compiled K-iterations-per-dispatch IRLS chunk.
 
     One ``lax.while_loop`` runs up to ``kmax`` IRLS iterations entirely on
@@ -221,11 +292,12 @@ def _fused_chunk_program(npad, p_pad, family_key, fam_args, l1_on,
     convergence (``stop``) or a non-finite solve (``bad`` — the host f64
     lstsq fallback lane takes over). All regularization/convergence scalars
     are DYNAMIC arguments so one program serves the whole lambda path;
-    ``beta`` is donated (the carry pipelines across chunk dispatches)."""
+    ``beta`` is donated (the carry pipelines across chunk dispatches).
+    ``layout`` is a factored design's dense positions (None: dense)."""
     from h2o3_tpu.parallel.mesh import get_mesh, mesh_key
 
-    key = ("glm_irls_chunk", npad, p_pad, family_key, fam_args, bool(l1_on),
-           bool(non_negative), mesh_key(), jax.default_backend())
+    key = ("glm_irls_chunk", npad, p_pad, layout, family_key, fam_args,
+           bool(l1_on), bool(non_negative), mesh_key(), jax.default_backend())
     fn = _GLM_PROGRAMS.get(key)
     if fn is not None:
         _GLM_HITS.inc()
@@ -234,7 +306,7 @@ def _fused_chunk_program(npad, p_pad, family_key, fam_args, l1_on,
 
     from jax.sharding import PartitionSpec as Spec
 
-    from h2o3_tpu.parallel.mesh import col_axis_name, row_pspec
+    from h2o3_tpu.parallel.mesh import col_axis_name, row_axes, row_pspec
 
     fam = get_family(family_key, *fam_args)
     mesh = get_mesh()
@@ -252,11 +324,28 @@ def _fused_chunk_program(npad, p_pad, family_key, fam_args, l1_on,
         packed psum, and a single all_gather reassembles G for the solve —
         three collective rendezvous per iteration instead of five
         (collective count, not just volume, is what the CPU proxy pays
-        for)."""
+        for). A factored design's local Gram is the categorical kernel's."""
+        factored = isinstance(X, FactoredDesign)
+
         def local(Xl, yl, wl, ol, beta):
             from h2o3_tpu.ops import collectives
 
             W, z, dev = _irls_weights(fam, Xl, yl, wl, ol, beta)
+            if factored:
+                G_l, b_l, _ = cat_gram_local(
+                    Xl.codes, Xl.dense, W, z, p=Xl.p, dense_pos=Xl.dense_pos)
+                # one exact reduce over every row axis at once (as
+                # weighted_gram_factored's): G row blocks scattered over
+                # all the devices and gathered back, so a fit gives the
+                # same numbers on a 1-D and a 2-D mesh of the same devices
+                axes = row_axes(mesh)
+                rows = -(-p_pad // n_sh) * n_sh
+                G_blk = jax.lax.psum_scatter(
+                    jnp.pad(G_l, ((0, rows - p_pad), (0, 0))), axes,
+                    scatter_dimension=0, tiled=True)
+                vec = jax.lax.psum(jnp.concatenate([b_l, dev[None]]), axes)
+                G = jax.lax.all_gather(G_blk, axes, axis=0, tiled=True)
+                return G[:p_pad], vec[:p_pad], vec[p_pad]
             with jax.named_scope("ph_gram"):
                 Xw = Xl * W[:, None]
                 G_l = jnp.einsum("np,nq->pq", Xw, Xl, precision=_HI)
@@ -276,9 +365,14 @@ def _fused_chunk_program(npad, p_pad, family_key, fam_args, l1_on,
         from h2o3_tpu.parallel.mesh import shard_map
 
         rspec = row_pspec(mesh)
+        if factored:
+            lanes = row_pspec(mesh, ndim=2, axis=1)
+            xspec = FactoredDesign(lanes, lanes, X.dense_pos, X.p)
+        else:
+            xspec = row_pspec(mesh, ndim=2)
         return shard_map(
             local, mesh,
-            in_specs=(row_pspec(mesh, ndim=2), rspec, rspec, rspec, Spec()),
+            in_specs=(xspec, rspec, rspec, rspec, Spec()),
             out_specs=(Spec(), Spec(), Spec()),
             check_vma=False,
         )(X, y, w, offset, beta)
@@ -295,7 +389,7 @@ def _fused_chunk_program(npad, p_pad, family_key, fam_args, l1_on,
                 G, b, dev = gram_dev_sharded(X, y, w, offset, beta)
             else:
                 W, z, dev = _irls_weights(fam, X, y, w, offset, beta)
-                G, b, _sw = weighted_gram(X, W, z)
+                G, b, _sw = _gram(X, W, z)
             with jax.named_scope("ph_solve"):
                 if l1_on:
                     beta_new, ok = admm_elastic_net_device(
@@ -492,7 +586,7 @@ def _glm_dev_grad(X, y, w, offset, beta, family_key, fam_args):
 def _deviance_pass(X, y, w, offset, beta, family_key, fam_args):
     fam = get_family(family_key, *fam_args)
     with jax.named_scope("ph_dev"):
-        eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
+        eta = _eta(X, beta) + offset
         mu = fam.link.inv(eta)
         return fam.deviance(y, mu, w)
 
@@ -528,7 +622,7 @@ def _fam_args(p) -> tuple:
 def _linear_mu(X, beta, offset, family_key, fam_args):
     """Linear predictor and inverse link of the single-vector families."""
     fam = get_family(family_key, *fam_args)
-    eta = jnp.einsum("np,p->n", X, beta, precision=_HI) + offset
+    eta = _eta(X, beta) + offset
     return fam.link.inv(eta)
 
 
@@ -651,7 +745,8 @@ class GLMModel(Model):
         host: (n, 2) for binomial, ``mu`` for the regression families,
         (n, K) for multinomial."""
         di: DataInfo = self.output["datainfo"]
-        X, _ = di.transform(frame)
+        X, _ = _glm_design(di, frame, _factored(
+            self.output["family"], self.params, di))
         raw = self._raw_from_design(
             X, _offset_col(self.params, frame), frame.nrow)
         if self.is_classifier and raw.ndim == 1:
@@ -765,10 +860,9 @@ class GLM(ModelBuilder):
             # row blocks). Fallback matrix (docs/MIGRATION.md): multinomial /
             # ordinal / L-BFGS / compute_p_values stay resident.
             stream = None
-            if (family not in ("multinomial", "ordinal")
-                    and p.solver.upper().replace("-", "_") not in ("L_BFGS", "LBFGS")
-                    and not p.compute_p_values):
-                stream = self._plan_streamed(train, di, p, yv)
+            factored = _factored(family, p, di)
+            if _single_irls(family, p):
+                stream = self._plan_streamed(train, di, p, yv, factored)
             if stream is not None:
                 X = stream
                 w = stream.lane("w")
@@ -777,7 +871,7 @@ class GLM(ModelBuilder):
                 nobs_dev = w.sum()
             else:
                 # resident: nothing n-row-sized is made on the host
-                X, valid_mask = di.transform(train)
+                X, valid_mask = _glm_design(di, train, factored)
                 y, w, offset, nobs_dev = _response_lanes(
                     yv.data, valid_mask,
                     train.vec(p.weights_column).data
@@ -863,17 +957,27 @@ class GLM(ModelBuilder):
             model.validation_metrics = model._score_metrics(valid)
         return model
 
-    def _plan_streamed(self, train: Frame, di, p: GLMParams, yv):
+    def _plan_streamed(self, train: Frame, di, p: GLMParams, yv,
+                       factored: bool = False):
         """ChunkStore with the block-transformed design lanes, or None for
-        the resident path. The block transform reuses ``di.transform`` on
-        host-block sub-frames — elementwise per row, so each lane equals
-        the resident design matrix row-for-row — and the source feature
-        columns then drop to compressed/host residency. The response lanes
-        of a streamed fit are host lanes of the store, built here in numpy;
-        the resident path makes its own on the device."""
+        the resident path. Whether the fit streams is decided by the bytes
+        a row of the design the resident fit would hold (``factored``: 4 a
+        categorical column and 4 a dense one; else 4 a design column); a
+        store that is planned streams dense design blocks, so its geometry
+        is sized by the dense bytes a row. The block transform reuses
+        ``di.transform`` on host-block sub-frames — elementwise per row, so
+        each lane equals the resident design matrix row-for-row — and the
+        source feature columns then drop to compressed/host residency. The
+        response lanes of a streamed fit are host lanes of the store, built
+        here in numpy; the resident path makes its own on the device."""
         from h2o3_tpu.frame import chunkstore as cs
 
         P = di.ncols_expanded
+        if factored:
+            cats = [c.width for c in di.columns if c.kind == "cat"]
+            width = P - sum(cats) + len(cats)
+            if cs.ChunkStore.plan(train.npad, (width + 3) * 4) is None:
+                return None
         store = cs.ChunkStore.plan(train.npad, (P + 3) * 4)
         if store is None:
             return None
@@ -991,8 +1095,10 @@ class GLM(ModelBuilder):
         else:
             fuse_k = _glm_fuse_chunk(p)
         p_pad = _glm_pad_cols(P) if fuse_k else P
+        factored = isinstance(X, FactoredDesign)
         if p_pad > P:
-            X = jnp.pad(X, ((0, 0), (0, p_pad - P)))
+            X = (X.widened(p_pad) if factored
+                 else jnp.pad(X, ((0, 0), (0, p_pad - P))))
 
         beta = np.zeros(P, np.float64)
         if p.intercept:
@@ -1012,6 +1118,8 @@ class GLM(ModelBuilder):
             Returns (G (P,P) f64, b (P,) f64, dev float)."""
             b32 = jnp.asarray(pad_beta(b64), jnp.float32)
             if not streaming:
+                if factored:
+                    _CAT_GRAM_CALLS.inc()
                 G, b, dev = _irls_pass(X, y, w, offset, b32, family, fam_args)
                 return (np.asarray(G, np.float64)[:P, :P],
                         np.asarray(b, np.float64)[:P], float(dev))
@@ -1129,6 +1237,7 @@ class GLM(ModelBuilder):
                     prog = _fused_chunk_program(
                         X.shape[0], p_pad, family, fam_args, l1 > 0,
                         p.non_negative,
+                        layout=X.dense_pos if factored else None,
                     )
                     kmax = min(fuse_k, max_iter - iters_done)
                     _it_t0 = time.perf_counter()
@@ -1155,6 +1264,8 @@ class GLM(ModelBuilder):
                         beta = np.asarray(beta_j, np.float64)[:P]
                         dev_prev = float(devp_j)
                         _IRLS_ITERS.inc(n_done)
+                        if factored:
+                            _CAT_GRAM_CALLS.inc(n_done)
                         for _ in range(n_done):
                             _IRLS_SECONDS.observe(_dt / n_done)
                         for ph, lanes in coll_model.items():

@@ -321,6 +321,73 @@ def test_grouped_kernel_compiles_alone_for_a_described_v5e(
         "hist_pallas_grouped", "hist_pallas_dense"}
 
 
+# the airline GLM's design: six categorical blocks (11 + 30 + 6 + 28 + 349 +
+# 349 indicator columns) as codes, two numerics and the intercept dense
+GLM_P, GLM_DENSE = 776, (773, 774, 775)
+
+
+@pytest.mark.parametrize("rows", [65_536, 50_331_648])
+def test_categorical_gram_kernel_compiles_for_a_described_v5e(
+        monkeypatch, v5e_chip, no_compile_cache, rows):
+    """``cat_gram_pallas`` at p = 776 (a 896-wide indicator tile, the
+    Kahan blocks beside the outputs) fits the chip's VMEM, keeps its name
+    (``glm_cat_gram_roofline_pct`` finds its events by ``^%?cat_gram_pallas``)
+    and, at 50M padded rows (the order of ``glm_airlines``' frame), needs
+    no temporary the size of the rows: the dense block, the weights and the
+    working response go into the kernel as they are."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from h2o3_tpu.ops import gram
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=SingleDeviceSharding(v5e_chip))
+    f = functools.partial(gram.cat_gram_local, p=GLM_P, dense_pos=GLM_DENSE)
+    compiled = jax.jit(f).lower(
+        S((6, rows), jnp.int32), S((3, rows), jnp.float32),
+        S((rows,), jnp.float32), S((rows,), jnp.float32)).compile()
+    assert set(re.findall(r"%(cat_gram_pallas)\.\d+ = ", compiled.as_text())) == {
+        "cat_gram_pallas"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_factored_irls_chunk_compiles_for_a_described_v5e(
+        monkeypatch, v5e_chip, no_compile_cache):
+    """The fused IRLS chunk on a factored design — the gathers for the
+    linear predictor, the categorical Gram kernel, the 776-wide ADMM solve in
+    one while loop — compiles for a v5e."""
+    from jax.sharding import Mesh, SingleDeviceSharding
+
+    import numpy as np
+
+    from h2o3_tpu.models import glm
+    from h2o3_tpu.models.datainfo import FactoredDesign
+    from h2o3_tpu.parallel import mesh as pm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows = 1 << 20
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=SingleDeviceSharding(v5e_chip))
+    f32, vec = S((), jnp.float32), S((rows,), jnp.float32)
+    old = pm._mesh
+    pm.set_mesh(Mesh(np.array([v5e_chip]), (pm.ROWS_AXIS,)))
+    try:
+        prog = glm._fused_chunk_program(
+            rows, GLM_P, "binomial", ("family_default", 1.5, 1.0, 1e-5), True,
+            False, layout=GLM_DENSE)
+        X = FactoredDesign(S((6, rows), jnp.int32), S((3, rows), jnp.float32),
+                           GLM_DENSE, GLM_P)
+        text = prog.lower(
+            S((GLM_P,), jnp.float32), f32, X, vec, vec, vec, S((), jnp.int32),
+            f32, f32, f32, f32, S((), jnp.int32), S((GLM_P,), jnp.float32),
+            f32).compile().as_text()
+    finally:
+        pm.set_mesh(old)
+        for k in [k for k in glm._GLM_PROGRAMS if k[-1] == "tpu"]:
+            del glm._GLM_PROGRAMS[k]  # built for the described chip's mesh
+    assert text.count("cat_gram_pallas") >= 1 and "gather" in text
+
+
 def _hlo_computation(text: str, name: str) -> list:
     """The instruction lines of one computation of a compiled module."""
     out, inside = [], False
